@@ -2,7 +2,7 @@
 # by the artifact tee
 SHELL := /bin/bash
 
-.PHONY: check fix test analyze sanitize bench-ingest bench-residency bench-observability bench-workload bench-profile bench-cache bench-multiproc bench-resize
+.PHONY: check fix test analyze sanitize chip-smoke bench-ingest bench-residency bench-observability bench-workload bench-profile bench-cache bench-multiproc bench-resize
 
 # the same gate CI runs: repo analyzer, then ruff/mypy when installed
 check:
@@ -18,6 +18,12 @@ analyze:
 # tier-1 test suite (see ROADMAP.md for the exact CI invocation)
 test:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow'
+
+# the served path on the chip, end to end (docs: PERF.md, the verify
+# skill's entry 10): run it through the chip tool; exits non-zero with no
+# accelerator. `python chip_smoke.py --mesh` is the four-chip variant.
+chip-smoke:
+	python chip_smoke.py
 
 # tier-1 under the runtime concurrency sanitizer (docs/concurrency.md):
 # every make_lock site instrumented, the observed holds-while-acquiring

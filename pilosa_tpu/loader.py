@@ -11,9 +11,10 @@ Retry-After compaction-debt admission gate (the retry IS the protocol:
 the server sheds load when durability can't keep up, the loader paces
 itself to it).
 
-Used by ``pilosa_tpu import --roaring`` and by ``bench_all.py``'s
-sustained-ingest row; the public entry points are ``parse_records`` and
-``bulk_load``.
+Used by ``pilosa_tpu import --roaring``, by ``bench_all.py``'s
+sustained-ingest row and by ``chip_smoke.py`` (which builds its frames
+from dense rows and hands them to ``stream_frames``); the public entry
+points are ``parse_records``, ``bulk_load`` and ``stream_frames``.
 """
 
 from __future__ import annotations
@@ -172,27 +173,22 @@ class _Conn:
                 self._conn = None
 
 
-def stream_load(
+def stream_frames(
     base_uri: str,
     index: str,
-    field: str,
-    batches,
+    frames,
     *,
-    view: str = "standard",
     pipeline: int = DEFAULT_PIPELINE,
-    batch_bits: int = DEFAULT_BATCH_BITS,
     timeout: float = 60.0,
     ssl_context=None,
-    shard_width: int = SHARD_WIDTH,
-    stop=None,
 ) -> dict:
-    """The sustained-ingest pipeline: ``batches`` yields (rows, cols)
-    vector pairs; the calling thread BUILDS per-shard roaring frames
-    (the vectorized columnar passes) while ``pipeline`` keep-alive
-    workers STREAM already-built frames concurrently — construction and
-    delivery overlap, so sustained throughput is bounded by the slower
-    half, not their sum. The bounded queue applies backpressure to the
-    builder when the server is the constraint.
+    """Deliver pre-built frames: ``frames`` yields ``(field, view,
+    shard, frame_bytes, n_bits)``; the calling thread drives the
+    iterator (so frames may be BUILT lazily there) while ``pipeline``
+    keep-alive workers stream already-built frames concurrently —
+    construction and delivery overlap, so sustained throughput is
+    bounded by the slower half, not their sum. The bounded queue applies
+    backpressure to the builder when the server is the constraint.
 
     Returns a stats dict: bits/bytes/posts delivered, elapsed seconds
     (covering build AND delivery), sustained Mbit/s (million set bits
@@ -200,14 +196,12 @@ def stream_load(
     delivered (2xx after the server's durability barrier) or the load
     raises — no silent partial success; 429s back off per the server's
     Retry-After and retry the SAME frame (idempotent: the adopt is a
-    union). ``stop`` (an optional ``threading.Event``) ends the load
-    cleanly between batches — the bench's timed-phase cutoff."""
+    union)."""
     work: queue.Queue = queue.Queue(maxsize=max(4, 4 * pipeline))
     n_workers = max(1, pipeline)
     errors: list[BaseException] = []
     stats_lock = threading.Lock()
     stats = {"bits": 0, "bytes": 0, "posts": 0, "backoffs429": 0, "frames": 0}
-    path_base = f"/index/{index}/field/{field}/import-roaring"
     _DONE = object()
 
     def worker() -> None:
@@ -219,8 +213,11 @@ def stream_load(
                     return
                 if errors:
                     continue  # drain so the producer never blocks
-                shard, frame, n_bits = item
-                path = f"{path_base}/{shard}?view={view}"
+                field, view, shard, frame, n_bits = item
+                path = (
+                    f"/index/{index}/field/{field}/import-roaring/{shard}"
+                    f"?view={view}"
+                )
                 for _retry in range(MAX_RETRIES_429):
                     status, body, retry_after = conn.post(path, frame)
                     if status == 429:
@@ -236,14 +233,15 @@ def stream_load(
                         continue
                     if status != 200:
                         raise LoaderError(
-                            f"import-roaring shard {shard}: HTTP {status} "
-                            f"{body[:200]!r}"
+                            f"import-roaring {field} shard {shard}: HTTP "
+                            f"{status} {body[:200]!r}"
                         )
                     break
                 else:
                     raise LoaderError(
-                        f"import-roaring shard {shard}: still 429 after "
-                        f"{MAX_RETRIES_429} backoffs (compactor wedged?)"
+                        f"import-roaring {field} shard {shard}: still 429 "
+                        f"after {MAX_RETRIES_429} backoffs (compactor "
+                        "wedged?)"
                     )
                 with stats_lock:
                     stats["bits"] += n_bits
@@ -266,14 +264,11 @@ def stream_load(
     for t in threads:
         t.start()
     try:
-        for rows, cols in batches:
-            if errors or (stop is not None and stop.is_set()):
+        for item in frames:
+            if errors:
                 break
-            for shard, frame, n_bits in build_frames(
-                rows, cols, batch_bits, shard_width
-            ):
-                stats["frames"] += 1
-                work.put((shard, frame, n_bits))
+            stats["frames"] += 1
+            work.put(item)
     finally:
         for _ in threads:
             work.put(_DONE)
@@ -286,6 +281,46 @@ def stream_load(
     stats["mbitSetPerS"] = round(stats["bits"] / max(elapsed, 1e-9) / 1e6, 4)
     stats["pipeline"] = n_workers
     return stats
+
+
+def stream_load(
+    base_uri: str,
+    index: str,
+    field: str,
+    batches,
+    *,
+    view: str = "standard",
+    pipeline: int = DEFAULT_PIPELINE,
+    batch_bits: int = DEFAULT_BATCH_BITS,
+    timeout: float = 60.0,
+    ssl_context=None,
+    shard_width: int = SHARD_WIDTH,
+    stop=None,
+) -> dict:
+    """The sustained-ingest pipeline over record batches: ``batches``
+    yields (rows, cols) vector pairs, which the calling thread turns
+    into per-shard roaring frames (the vectorized columnar passes) for
+    ``stream_frames`` to deliver. ``stop`` (an optional
+    ``threading.Event``) ends the load cleanly between batches — the
+    bench's timed-phase cutoff."""
+
+    def frames():
+        for rows, cols in batches:
+            if stop is not None and stop.is_set():
+                return
+            for shard, frame, n_bits in build_frames(
+                rows, cols, batch_bits, shard_width
+            ):
+                yield field, view, shard, frame, n_bits
+
+    return stream_frames(
+        base_uri,
+        index,
+        frames(),
+        pipeline=pipeline,
+        timeout=timeout,
+        ssl_context=ssl_context,
+    )
 
 
 def bulk_load(

@@ -1,14 +1,16 @@
 """ctypes loader for the native host bitmap kernels.
 
 Builds ``native/bitmap_kernels.cpp`` with g++ on first use (cached next to
-the source), binds it via ctypes, and exposes numpy-signature wrappers.
-Every entry point has a numpy fallback so the package works without a
-toolchain; ``AVAILABLE`` reports which path is live.
+the source, with the source's hash beside it), binds it via ctypes, and
+exposes numpy-signature wrappers. Every entry point has a numpy fallback
+so the package works without a toolchain; ``available()`` reports which
+path is live.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,17 +19,27 @@ import numpy as np
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native", "bitmap_kernels.cpp")
 _LIB = os.path.join(os.path.dirname(_SRC), "libbitmap_kernels.so")
+# hash of the source the library was built from: a copied tree keeps no
+# mtimes, so freshness is decided by content
+_LIB_HASH = _LIB + ".sha256"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
-AVAILABLE = False
+_load_tried = False  # one attempt: no source or no compiler stays numpy
 
 
 def _build() -> bool:
-    if not os.path.exists(_SRC):
+    try:
+        with open(_SRC, "rb") as f:
+            src_hash = hashlib.sha256(f.read()).hexdigest()
+    except OSError:
         return False
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return True
+    try:
+        with open(_LIB_HASH) as f:
+            if f.read().strip() == src_hash and os.path.exists(_LIB):
+                return True
+    except OSError:
+        pass
     try:
         subprocess.run(
             [
@@ -43,16 +55,20 @@ def _build() -> bool:
         # the compiler produced the tmp; commit it with the sanctioned
         # rename (durable=False: a lost build artifact just rebuilds)
         durable.replace_durable(_LIB + ".tmp", _LIB, durable=False)
+        durable.atomic_write_file(
+            _LIB_HASH, src_hash, tmp_suffix=".tmp", durable=False
+        )
         return True
     except (subprocess.SubprocessError, OSError, PermissionError):
         return False
 
 
 def _load() -> ctypes.CDLL | None:
-    global _lib, AVAILABLE
+    global _load_tried
     with _lock:
-        if _lib is not None:
+        if _load_tried:
             return _lib
+        _load_tried = True
         if not _build():
             return None
         try:
@@ -62,16 +78,22 @@ def _load() -> ctypes.CDLL | None:
         try:
             return _bind(lib)
         except AttributeError:
-            # a stale prebuilt .so (mtime-preserving deploys) missing a
-            # newer symbol must degrade to the numpy fallbacks, not crash
-            # every native entry point
+            # a library missing a symbol the binding expects must
+            # degrade to the numpy fallbacks, not crash every entry point
             return None
+
+
+def available() -> bool:
+    """Are the native kernels live in this process (built and bound)?
+    False means every entry point runs its numpy fallback — same
+    answers, slower host paths; /info reports it."""
+    return _load() is not None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     """Declare every symbol's signature; AttributeError (stale .so)
     propagates to _load's fallback."""
-    global _lib, AVAILABLE
+    global _lib
     if True:  # keep the binding block's indentation stable
         c_u32p = ctypes.POINTER(ctypes.c_uint32)
         c_u64p = ctypes.POINTER(ctypes.c_uint64)
@@ -113,7 +135,6 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ]
         lib.u32_stack_fill.restype = None
         _lib = lib
-        AVAILABLE = True
         return lib
 
 

@@ -18,20 +18,25 @@ jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: query programs at pod scale take
 # minutes to compile (the gather program at 10k shards); caching them on
-# disk makes server restarts and repeat bench runs skip every compile.
-# An explicit JAX_COMPILATION_CACHE_DIR (or prior jax.config setting)
-# wins; PILOSA_TPU_NO_COMPILE_CACHE=1 opts out.
+# disk makes server restarts skip every compile. Where
+# JAX_COMPILATION_CACHE_DIR is set (or a caller already configured a
+# directory) nothing is set here. Otherwise the cache is ONE fixed
+# directory inside the checkout: the path is part of the cache key, so
+# it must never move, and only a directory under the checkout survives
+# a sealed machine whose home directory is thrown away.
+COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 if (
     not _os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    and _os.environ.get("PILOSA_TPU_NO_COMPILE_CACHE", "").lower()
-    not in ("1", "true", "yes")
     and jax.config.jax_compilation_cache_dir is None
 ):
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        _os.path.expanduser("~/.cache/pilosa_tpu/jax-cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+# wherever the cache lives: low enough that every query program of a cold
+# boot is stored (small ones compile in well under a second on the chip),
+# so a restart compiles nothing
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 from pilosa_tpu.ops import bsi, containers, similarity, topn
 from pilosa_tpu.ops.bitwise import (

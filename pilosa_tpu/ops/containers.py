@@ -28,8 +28,11 @@ All functions are jit/shard_map compatible and pure.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
-_FULL_WORD = jnp.uint32(0xFFFFFFFF)
+# numpy, not jnp: a module-level jnp scalar is a device array, and making
+# one initializes the backend at import
+_FULL_WORD = np.uint32(0xFFFFFFFF)
 
 
 def sparse_plane(ids, n_shards: int, n_words: int):

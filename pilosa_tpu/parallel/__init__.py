@@ -23,38 +23,19 @@ __all__ = ["Node", "Topology", "partition", "PARTITION_N", "shard_map"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
-    """THE repo-wide ``shard_map`` entry — the one compat point between
-    the pinned test env (jax 0.4.37, where only
-    ``jax.experimental.shard_map`` exists) and newer jax (where the API
-    graduated to ``jax.shard_map`` and ``check_rep`` was renamed
-    ``check_vma``). Every mesh program imports it from here so no module
-    carries its own try/except, and a future jax bump edits one site.
+    """THE repo-wide ``shard_map`` entry: every mesh program imports it
+    from here, so a jax bump that moves or renames the API edits one
+    site (``check_rep`` is jax's ``check_vma``).
 
     Lazy jax import: ``pilosa_tpu.parallel`` is imported by topology-only
     consumers (config, the analyzer fixtures) that must not pay — or
     trigger — a jax import."""
     import jax
 
-    graduated = getattr(jax, "shard_map", None)
-    if graduated is not None:
-        try:
-            return graduated(
-                f,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_vma=check_rep,
-            )
-        except TypeError:  # jax 0.5-0.6: graduated API, still check_rep
-            return graduated(
-                f,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                check_rep=check_rep,
-            )
-    from jax.experimental.shard_map import shard_map as _experimental
-
-    return _experimental(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_rep
+    return jax.shard_map(
+        f,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=check_rep,
     )

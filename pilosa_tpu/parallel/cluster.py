@@ -1744,22 +1744,22 @@ class Cluster:
                     node_shards,
                 )
             except PeerError as e:
-                probing = "device probe in progress" in str(e)
-                if not e.retryable and not probing:
+                attaching = "device attach in progress" in str(e)
+                if not e.retryable and not attaching:
                     # the peer ANSWERED with a permanent refusal (4xx):
                     # no replica would answer differently — fail loudly,
                     # and don't dead-mark a peer that is demonstrably up
                     raise ShardUnavailableError(
                         f"shard owner {node_id} failed mid-query: {e}"
                     ) from e
-                # a probe-gate 503 means the peer is ALIVE and serving
-                # (its heartbeats succeed) but its device verdict is
-                # pending — marking it dead would route reads around a
-                # live sole holder for the whole probe window; still
+                # an attach-gate 503 means the peer is ALIVE and serving
+                # (its heartbeats succeed) but still binding its device
+                # executor — marking it dead would route reads around a
+                # live sole holder for the whole attach window; still
                 # fail THIS query's legs over to a surviving replica.
                 # Any other retryable failure: heartbeat state was
                 # stale — mark dead NOW so concurrent queries reroute.
-                if not probing:
+                if not attaching:
                     node.alive = False
                 failed.add(node_id)
                 if stats is not None:
@@ -3072,17 +3072,17 @@ class Cluster:
     def _h_query(self, handler) -> None:
         # body FIRST, gate second: the 503 must not leave unread body
         # bytes on a keep-alive connection (the next request would parse
-        # from the stale body). Same device-probe gate as the client-
-        # facing query route: a coordinator's fan-out must not be the
-        # first JAX use on a node whose backend probe is still running.
-        # wait=False — the coordinator's RPC timeout (30s) is shorter
-        # than the gate wait, so blocking here would turn the probe
-        # window into a client-visible RPC timeout; failing fast maps to
-        # ShardUnavailableError (503 retry) at the coordinator instead.
+        # from the stale body). Same attach gate as the client-facing
+        # query route: a coordinator's fan-out must not race this node's
+        # executor swap. wait=False — the coordinator's RPC timeout
+        # (30s) is shorter than the gate wait, so blocking here would
+        # turn the attach window into a client-visible RPC timeout;
+        # failing fast maps to ShardUnavailableError (503 retry) at the
+        # coordinator instead.
         body = handler._json_body()
-        if not self.server._query_gate(wait=False):
+        if not self.server._attach_gate(wait=False):
             raise ShardUnavailableError(
-                "device probe in progress on this node; retry"
+                "device attach in progress on this node; retry"
             )
         # per-node served-query counter (VERDICT #6): every read leg THIS
         # node executes — whether taken from a coordinator (here) or
@@ -3126,9 +3126,9 @@ class Cluster:
         readback wave.  Per-entry error isolation: a failing query
         yields an ``error`` entry; its RPC-mates answer normally."""
         body = handler._json_body()
-        if not self.server._query_gate(wait=False):
+        if not self.server._attach_gate(wait=False):
             raise ShardUnavailableError(
-                "device probe in progress on this node; retry"
+                "device attach in progress on this node; retry"
             )
         entries = body.get("queries", [])
         stats = self.server.stats
@@ -3390,10 +3390,10 @@ class Cluster:
         return control
 
     def _h_import_bits(self, handler, index: str, field: str) -> None:
-        # deliberately NOT behind the device-probe gate: the import apply
-        # path is numpy/roaring only (JAX is first touched at query
-        # compile), so there is no wedged-backend-init hazard here — and
-        # gating would refuse replica writes for the whole probe window
+        # deliberately NOT behind the attach gate: the import apply path
+        # is numpy/roaring only (the executor is first touched at query
+        # compile), and gating would refuse replica writes for the whole
+        # attach window
         applied_by = self._apply_or_reforward_import(
             index, field, self._import_body(handler), values=False
         )
@@ -3412,7 +3412,7 @@ class Cluster:
         # roaring_router already addressed every owner): adopt the frame
         # via one WAL append, barrier inside api.import_roaring, THEN
         # ack — the coordinator's client acknowledgement is backed by
-        # this replica's durability barrier. Not device-probe gated for
+        # this replica's durability barrier. Not attach-gated for
         # the same reason as _h_import_bits (numpy/roaring only).
         data = handler._body()
         view = handler.query_params.get("view", ["standard"])[0] or "standard"

@@ -9,6 +9,7 @@ from pilosa_tpu.roaring.bitmap import Bitmap
 from pilosa_tpu.roaring.build import (
     bitmap_from_positions,
     payload_from_positions,
+    payload_from_rows,
     shard_payloads,
     split_by_shard,
 )
@@ -53,6 +54,7 @@ __all__ = [
     "OP_UNION",
     "bitmap_from_positions",
     "payload_from_positions",
+    "payload_from_rows",
     "shard_payloads",
     "split_by_shard",
 ]

@@ -49,6 +49,42 @@ def payload_from_positions(positions: np.ndarray) -> bytes:
     return serialize(bitmap_from_positions(positions))
 
 
+def payload_from_rows(
+    rows, shard_width: int = SHARD_WIDTH
+) -> tuple[bytes, int]:
+    """Dense packed rows of ONE shard → ``(frame_bytes, n_bits)``.
+
+    ``rows`` yields ``(row_id, words)`` with ``words`` the row's
+    ``uint32[shard_width // 32]`` packed columns (bit ``c`` of the shard
+    at word ``c // 32``, bit ``c % 32`` — the layout ``pack_positions``
+    and the device stacks use). The bulk lane for data that is born
+    dense (generated columns, bit-sliced integers): each 2^16-column
+    span becomes one bitmap container by reinterpreting its words, and
+    ``serialize``'s optimize pass demotes the sparse ones — no position
+    vector is ever materialized."""
+    if shard_width % (1 << 16):
+        raise ValueError(
+            f"dense rows need whole 2^16-column containers per row; "
+            f"shard width {shard_width} has none"
+        )
+    per_row = shard_width >> 16
+    bm = Bitmap()
+    n_bits = 0
+    for row_id, words in rows:
+        spans = (
+            np.ascontiguousarray(words, dtype="<u4")
+            .view("<u8")
+            .reshape(per_row, 1024)
+        )
+        counts = np.bitwise_count(spans).sum(axis=1)
+        for k in np.flatnonzero(counts).tolist():
+            bm._containers[int(row_id) * per_row + k] = ct.bitmap_container(
+                spans[k]
+            )
+        n_bits += int(counts.sum())
+    return serialize(bm), n_bits
+
+
 def split_by_shard(
     rows: np.ndarray, cols: np.ndarray, shard_width: int = SHARD_WIDTH
 ) -> list[tuple[int, np.ndarray]]:

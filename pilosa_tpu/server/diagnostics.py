@@ -27,11 +27,11 @@ class DiagnosticsCollector:
         self._timer: threading.Timer | None = None
         self._closed = False
         self.last: dict = {}
-        self._backend_cache: str | None = None
+        self._device_cache: dict | None = None
 
     # ------------------------------------------------------------ snapshot
     def snapshot(self) -> dict:
-        from pilosa_tpu import __version__
+        from pilosa_tpu import __version__, native
 
         holder = self.server.holder
         n_fields = 0
@@ -59,7 +59,12 @@ class DiagnosticsCollector:
             "os": platform.system(),
             "arch": platform.machine(),
             "python": platform.python_version(),
-            "backend": self._backend(),
+            **self._device(),
+            # the router serving every read from the host engine is a
+            # configuration (route-mode = "host"), never a fallback
+            "router_pinned_host": self.server.api.executor.router.mode
+            == "host",
+            "native_kernels": native.available(),
             "cluster_size": (
                 len(self.server.cluster.nodes) if self.server.cluster else 1
             ),
@@ -67,19 +72,33 @@ class DiagnosticsCollector:
         self.last = snap
         return snap
 
-    def _backend(self) -> str:
-        # jax.devices() initializes the full backend (seconds on a TPU
-        # host); compute once, off the server-startup path
-        if self._backend_cache is None:
+    def _device(self) -> dict:
+        """Platform, kind and count of this process's local devices as
+        JAX reports them (chip_smoke.py and the benchmark copy these
+        into their results; they never assume them)."""
+        # jax.local_devices() initializes the full backend (seconds on a
+        # TPU host); compute once
+        if self._device_cache is None:
             try:
                 import jax
 
-                self._backend_cache = jax.devices()[0].platform
+                devs = jax.local_devices()
+                self._device_cache = {
+                    "backend": devs[0].platform,
+                    "device_kind": devs[0].device_kind,
+                    "device_count": len(devs),
+                    "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+                }
             except Exception:  # pilosa: allow(broad-except) — backend
                 # init failures are backend-specific (RuntimeError,
-                # OSError, plugin errors); diagnostics must never raise
-                self._backend_cache = "unavailable"
-        return self._backend_cache
+                # OSError); a snapshot reports them, it never raises
+                self._device_cache = {
+                    "backend": "unavailable",
+                    "device_kind": "",
+                    "device_count": 0,
+                    "compile_cache_dir": None,
+                }
+        return self._device_cache
 
     # ------------------------------------------------------------ lifecycle
     def flush(self) -> None:
@@ -106,26 +125,13 @@ class DiagnosticsCollector:
         interval = self.server.config.diagnostics_interval
         if interval <= 0:
             return
-        # first flush off the startup path — and AFTER the mesh-attach
-        # verdict: _backend() initializes the JAX runtime, and doing
-        # that before the server's device probe has decided the platform
-        # would enter a possibly-wedged accelerator init holding jax's
-        # process-global init lock, hanging every later jax call (the
-        # attach thread's own CPU pin included)
-        def first():
-            self._gate_on_device_verdict()
-            self.flush()
-
+        # first flush off the startup path: _device() initializes the
+        # JAX runtime (seconds on a TPU host)
         self._first_flush = threading.Thread(
-            target=first, daemon=True, name="diagnostics-first-flush"
+            target=self.flush, daemon=True, name="diagnostics-first-flush"
         )
         self._first_flush.start()
         self._schedule(interval)
-
-    def _gate_on_device_verdict(self) -> None:
-        wait = getattr(self.server, "wait_mesh", None)
-        if wait is not None:
-            wait(None)
 
     def _schedule(self, interval: float) -> None:
         if self._closed:
@@ -133,10 +139,6 @@ class DiagnosticsCollector:
 
         def tick():
             try:
-                # same gate as the first flush: a periodic flush racing
-                # an undecided device probe would enter the wedged
-                # backend init and hold jax's init lock before the pin
-                self._gate_on_device_verdict()
                 self.flush()
             finally:
                 self._schedule(interval)

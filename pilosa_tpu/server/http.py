@@ -391,12 +391,10 @@ class Handler(BaseHTTPRequestHandler):
 
     # -------------------------------------------------------------- routes
     def _gate(self) -> bool:
-        """Device-probe gate for routes whose work reaches JAX: during
-        the probe window a query must not initialize the (possibly
-        wedged) accelerator backend in-process — that hang is
-        uninterruptible and holds JAX's process-global init lock, so the
-        post-probe CPU pin could never recover (ADVICE r5 medium). The
-        server-side gate waits a bounded slice for the verdict; if it is
+        """Attach gate for routes whose work reaches the executor: the
+        listener serves while Server.open() is still attaching the
+        device, and a query must not race the executor swap. The
+        server-side gate waits a bounded slice for the attach; if it is
         still pending, serve 503 + Retry-After instead of dispatching."""
         if self.server.gate():
             return True
@@ -404,7 +402,7 @@ class Handler(BaseHTTPRequestHandler):
         # same wire-format negotiation as _error(), plus Retry-After — a
         # protobuf client must get a decodable QueryResponse/ImportResponse
         # error envelope, not a JSON body it can't parse
-        msg = "device probe in progress; retry"
+        msg = "device attach in progress; retry"
         headers = {"Retry-After": "2"}
         if self._wants_proto() and self.route_name.startswith("import"):
             self._proto(
@@ -1225,9 +1223,9 @@ class Handler(BaseHTTPRequestHandler):
 
         # device residency: the stack cache's aggregate byte ledger.
         # The budget is read WITHOUT forcing resolution — the HBM query
-        # initializes the JAX backend, and this control-plane route does
-        # not pass the device-probe gate (limit reads None until a
-        # query resolved it)
+        # initializes the JAX backend, which a control-plane scrape
+        # must not be the one to do (limit reads None until a query
+        # resolved it)
         from pilosa_tpu.executor import compile as query_compile
 
         stacks = self.api.executor.compiler.stacks
@@ -1236,6 +1234,7 @@ class Handler(BaseHTTPRequestHandler):
             stacks.resident_bytes,
             query_compile.stack_budget_if_resolved(),
             "bytes",
+            **stacks.placement_snapshot(),
         )
         # WAL / ops-log debt (crash-replay bytes) + compaction queue
         wal = self.api.holder.wal_ledger()
@@ -1595,9 +1594,9 @@ class _ServerCore:
         # ... and its FSFaultInjector (docs/durability.md) so GET
         # /debug/faults reports the armed disk-fault rules too
         self.fs_fault_injector = None
-        # device-probe gate: the runtime Server swaps in a hook that
-        # blocks query/import dispatch (bounded) until the backend probe
-        # verdict lands — True = proceed, False = serve 503 + Retry-After
+        # attach gate: the runtime Server swaps in a hook that blocks
+        # query/import dispatch (bounded) until its device executor is
+        # bound — True = proceed, False = serve 503 + Retry-After
         self.gate = lambda: True
         # cluster layer swaps in a cross-node trace collector:
         # trace_id -> {node_id: [span dicts]} for stitched chrome export

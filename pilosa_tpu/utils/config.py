@@ -63,17 +63,6 @@ class Config:
     # device mesh (serving-path SPMD over all local devices)
     mesh_enabled: bool = True
     mesh_words_axis: int = 1  # >1 splits the packed word dim across devices
-    # seconds to wait for the accelerator backend to prove healthy (a
-    # fresh-subprocess probe) before pinning this process to the CPU
-    # backend: a wedged device transport otherwise hangs the FIRST query
-    # indefinitely inside backend init. 0 disables the probe (trust the
-    # accelerator to come up).
-    device_init_timeout: float = 300.0
-    # seconds a query/import arriving DURING the device probe window
-    # waits for the verdict before being served 503 + Retry-After (the
-    # probe gate keeps first JAX use off a possibly-wedged backend; see
-    # Server._query_gate). 0 = never wait, 503 immediately while probing.
-    query_gate_wait: float = 60.0
     # multi-host process group (jax.distributed; reference analogue:
     # gossip seeds — here membership is static). Setting
     # coordinator_address makes Server.open() join the group before any
@@ -88,15 +77,15 @@ class Config:
     process_id: int = -1  # -1 = let jax.distributed infer
     # query routing (docs/query-routing.md): per-call host/device
     # routing by a calibrated cost model. "auto" compares estimated work
-    # against the online crossover; "host"/"device" pin every read to
-    # one engine (the server also pins "host" when the device probe
-    # fails — the degraded engine must not pay device dispatch).
-    route_mode: str = "auto"  # auto | host | device
+    # against the online crossover; "host"/"device"/"mesh" pin every
+    # read to one engine.
+    route_mode: str = "auto"  # auto | host | device | mesh
     # device stack budget in bytes — the aggregate cap on resident query
     # stacks (dense stacks + hot-row slots + tiered container stores;
     # docs/device-residency.md). 0 = auto: the legacy
     # PILOSA_TPU_STACK_BUDGET env override if set, else 70% of the
-    # device's reported HBM limit, else 2 GiB.
+    # device's reported HBM limit (2 GiB on the CPU backend, which
+    # reports none).
     device_stack_budget_bytes: int = 0
     # >0 pins the crossover (words of packed-bitmap work below which a
     # read runs on the host); 0 derives it from the calibrated model
@@ -111,10 +100,6 @@ class Config:
     # divides by the attached mesh's device count
     route_mesh_dispatch_ms: float = 2.0
     route_mesh_readback_ms: float = 2.0
-    # seconds a persisted device-probe verdict stays valid: within the
-    # TTL the next boot (or bench run) reuses it instead of paying the
-    # full device-init-timeout probe against a known-wedged transport
-    device_probe_ttl: float = 900.0
     # cross-query wave coalescing (docs/query-batching.md): concurrent
     # sync device-routed queries share one dispatch+readback wave.
     # "adaptive" opens a straggler window only under observed
@@ -400,8 +385,6 @@ def config_template() -> str:
         'log-path = ""\n'
         "mesh-enabled = true\n"
         "mesh-words-axis = 1\n"
-        "device-init-timeout = 300.0\n"
-        "query-gate-wait = 60.0\n"
         'coordinator-address = ""\n'
         "num-processes = 0\n"
         "process-id = -1\n"
@@ -413,7 +396,6 @@ def config_template() -> str:
         "route-device-words-per-s = 25e9\n"
         "route-mesh-dispatch-ms = 2.0\n"
         "route-mesh-readback-ms = 2.0\n"
-        "device-probe-ttl = 900.0\n"
         'batch-mode = "adaptive"\n'
         "batch-window-us = 250.0\n"
         "batch-max-queries = 64\n"

@@ -124,7 +124,7 @@ def atomic_write_file(
     A crash at ANY point leaves either the complete old content or the
     complete new content at ``path`` — never a torn mix. ``durable=
     False`` keeps the atomic-replace half but skips both fsyncs, for
-    best-effort caches (probe verdicts, diagnostics) whose loss costs
+    best-effort files (diagnostics snapshots) whose loss costs
     nothing."""
     if isinstance(data, str):
         data = data.encode("utf-8")

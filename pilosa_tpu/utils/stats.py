@@ -19,8 +19,8 @@ from collections import defaultdict
 def _log_buckets() -> tuple[float, ...]:
     """Log-spaced latency boundaries, 1-2.5-5 per decade from 100 µs to
     500 s — ~3 buckets/decade keeps quantile error within the decade
-    step while spanning sub-ms kernel dispatches through wedged-device
-    timeouts. Roughly the Prometheus client default, extended down."""
+    step while spanning sub-ms kernel dispatches through multi-minute
+    cold compiles. Roughly the Prometheus client default, extended down."""
     out = []
     for exp in range(-4, 3):
         for mant in (1.0, 2.5, 5.0):
@@ -57,7 +57,6 @@ _METRIC_HELP = {
     "internal_query_batch_seconds": "serve time of /internal/query/batch",
     "queries_routed": "read calls per engine picked by the cost router",
     "queries_served": "read legs this node executed",
-    "queries_gated": "queries arriving during the device-probe window",
     "queries_deduped": "queries answered by single-flight dedup",
     "queries_partial": "queries answered with partial results",
     "queries_rejected": "requests shed by admission control",

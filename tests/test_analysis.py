@@ -318,8 +318,8 @@ def test_config_drift_undocumented_field_fails(tree_copy):
 
 def test_config_drift_undocumented_env_fails(tree_copy):
     mutate(
-        tree_copy / "pilosa_tpu" / "utils" / "probecache.py",
-        '"PILOSA_TPU_PROBE_CACHE"',
+        tree_copy / "pilosa_tpu" / "executor" / "router.py",
+        '"PILOSA_TPU_ROUTE_MODE"',
         '"PILOSA_TPU_SECRET_KNOB"',
     )
     rc, out = check_tree(tree_copy)
@@ -580,12 +580,12 @@ def test_fix_monotonic_removes_violation_and_is_idempotent(tmp_path):
 
 
 def test_fix_respects_wall_clock_pragmas():
-    # the three intentionally wall-clock sites (persisted TTLs, the
-    # trace epoch anchor) carry pragmas — --fix must not rewrite them
+    # the two intentionally wall-clock sites (the persisted tombstone
+    # TTL, the trace epoch anchor) carry pragmas — --fix must not
+    # rewrite them
     from tools.analysis.fixes import apply_fixes
 
     for rel in (
-        "pilosa_tpu/utils/probecache.py",
         "pilosa_tpu/core/attrstore.py",
         "pilosa_tpu/utils/tracing.py",
     ):
@@ -992,8 +992,8 @@ def test_metric_drift_stale_doc_row_fails(tree_copy):
     # a catalog row whose metric no longer exists anywhere in code
     mutate(
         tree_copy / "docs" / "observability.md",
-        "| `pilosa_tpu_queries_gated` | counter | — |",
-        "| `pilosa_tpu_queries_gated` | counter | — |\n"
+        "| `pilosa_tpu_queries_deduped` | counter | — |",
+        "| `pilosa_tpu_queries_deduped` | counter | — |\n"
         "| `pilosa_tpu_vanished_metric` | counter | — | gone |",
     )
     rc, out = check_tree(tree_copy)

@@ -94,7 +94,7 @@ def test_generate_config_subcommand(capsys):
     assert cfg["bind"] == "127.0.0.1:10101"
     assert cfg["diagnostics-interval"] == 3600.0
     assert cfg["long-query-time"] == 0.0
-    assert cfg["query-gate-wait"] == 60.0
+    assert cfg["route-mode"] == "auto"
 
 
 def test_pprof_profile_endpoint(srv):

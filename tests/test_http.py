@@ -332,25 +332,24 @@ def test_metrics_query_seconds_histogram(srv):
     assert "pilosa_tpu_executor_call_seconds_bucket" in text
 
 
-def test_query_gated_during_device_probe(tmp_path):
-    """A query arriving while the device probe is still deciding must
-    not reach JAX: it waits up to query-gate-wait, then gets 503 with
-    Retry-After, and queries_gated counts the trip (ADVICE r5 medium).
-    The gate is keyed on the _mesh_ready event (unset from construction),
-    so it also covers the window before the attach thread exists."""
+def test_query_waits_for_device_attach(tmp_path):
+    """A query arriving while open() is still attaching the device must
+    not race the executor swap: it waits a bounded time for the attach,
+    then gets 503 with Retry-After. The gate is keyed on the _mesh_ready
+    event (unset from construction), so it also covers the window
+    before the attach thread exists."""
     s = Server(Config(bind="127.0.0.1:0", data_dir=str(tmp_path / "d"),
-                      anti_entropy_interval=0, query_gate_wait=0.1))
+                      anti_entropy_interval=0))
+    s.ATTACH_WAIT_S = 0.1
     s.open()
     try:
-        s.wait_mesh()
-        s._mesh_ready.clear()  # simulate a still-undecided probe
+        s._mesh_ready.clear()  # simulate an attach still in flight
         with pytest.raises(urllib.error.HTTPError) as e:
             call(s, "POST", "/index/x/query", b"Count(Row(f=1))")
         assert e.value.code == 503
         assert e.value.headers.get("Retry-After")
-        assert s.stats.expvar()["counters"]["queries_gated"] == 1
         s._mesh_ready.set()
-        # verdict landed: the same query now dispatches (400 path, not
+        # attached: the same query now dispatches (400 path, not
         # 503 — the index doesn't exist, which is the point: it got
         # PAST the gate)
         with pytest.raises(urllib.error.HTTPError) as e:

@@ -461,6 +461,58 @@ def test_loader_build_frames_chunking(rng):
     assert total == 10_000 and merged.count() == 10_000
 
 
+def test_payload_from_rows_matches_positions_path(rng):
+    """Dense packed rows → the same frame content the position-vector
+    builder gives, with sparse spans demoted out of bitmap containers."""
+    dense = rng.integers(0, 2, SHARD_WIDTH).astype(bool)
+    sparse = np.zeros(SHARD_WIDTH, dtype=bool)
+    sparse[rng.choice(SHARD_WIDTH, 40, replace=False)] = True
+    rows = {3: dense, 9: sparse, 11: np.zeros(SHARD_WIDTH, dtype=bool)}
+    frame, n_bits = roaring.payload_from_rows(
+        (r, np.packbits(m, bitorder="little").view(np.uint32))
+        for r, m in rows.items()
+    )
+    want = np.concatenate(
+        [np.flatnonzero(m).astype(np.uint64) + np.uint64(r * SHARD_WIDTH)
+         for r, m in rows.items()]
+    )
+    got, _ = roaring.deserialize(frame)
+    assert n_bits == want.size == got.count()
+    assert np.array_equal(got.values(), np.sort(want))
+    assert frame == roaring.payload_from_positions(want)
+    with pytest.raises(ValueError):
+        roaring.payload_from_rows([], shard_width=1 << 12)
+
+
+def test_stream_frames_addresses_field_and_view(monkeypatch):
+    """Pre-built frames carry their own field and view: one pipeline
+    loads a whole schema (chip_smoke.py's three fields per shard)."""
+    posts = []
+
+    class OkConn:
+        def __init__(self, *a, **k):
+            pass
+
+        def post(self, path, body):
+            posts.append((path, body))
+            return 200, b"{}", None
+
+        def close(self):
+            pass
+
+    monkeypatch.setattr(loader, "_Conn", OkConn)
+    st = loader.stream_frames(
+        "http://x", "i",
+        [("f", "standard", 0, b"aa", 2), ("v", "bsi", 5, b"bbb", 7)],
+        pipeline=1,
+    )
+    assert st["posts"] == 2 and st["bits"] == 9 and st["bytes"] == 5
+    assert posts == [
+        ("/index/i/field/f/import-roaring/0?view=standard", b"aa"),
+        ("/index/i/field/v/import-roaring/5?view=bsi", b"bbb"),
+    ]
+
+
 def test_loader_429_backoff_then_success(monkeypatch):
     """The loader honors Retry-After and retries the SAME frame; a
     persistent non-429 error raises."""

@@ -1,5 +1,7 @@
 """Native C++ kernel tests — parity with the numpy fallbacks."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -8,9 +10,38 @@ from pilosa_tpu import native
 
 @pytest.fixture(scope="module", autouse=True)
 def require_native():
-    native._load()
-    if not native.AVAILABLE:
+    if not native.available():
         pytest.skip("native toolchain unavailable; numpy fallback covered elsewhere")
+
+
+def test_freshness_is_decided_by_source_hash(tmp_path, monkeypatch):
+    """A copied tree keeps no mtimes: the library is rebuilt when the
+    source's content differs from the hash stored beside it, and only
+    then."""
+    import shutil
+    import subprocess
+
+    src = tmp_path / "bitmap_kernels.cpp"
+    shutil.copy(native._SRC, src)
+    lib = str(tmp_path / "libbitmap_kernels.so")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_LIB", lib)
+    monkeypatch.setattr(native, "_LIB_HASH", lib + ".sha256")
+    builds = []
+    real_run = subprocess.run
+
+    def counting_run(*a, **k):
+        builds.append(a)
+        return real_run(*a, **k)
+
+    monkeypatch.setattr(subprocess, "run", counting_run)
+    assert native._build() and len(builds) == 1
+    os.utime(src, (1, 1))  # older than the library: mtimes say nothing
+    os.utime(lib, (2, 2))
+    assert native._build() and len(builds) == 1
+    src.write_text(src.read_text() + "\n// edited\n")
+    os.utime(src, (1, 1))  # still "older" than the library
+    assert native._build() and len(builds) == 2
 
 
 def test_popcounts(rng):

@@ -1,0 +1,298 @@
+"""The programs chip_smoke.py runs, compiled for a DESCRIBED TPU v5e at
+the smoke's real shapes (256 shards × 32768 words) — no chip attached,
+nothing executes (on-chip-measurement guide §2, rehearsal 3).
+
+Every program is the query compiler's own: the smoke's queries run once
+here on the CPU at a tiny size while a recorder keeps each jitted program
+the compiler built together with the arguments it was called with; the
+same program objects are then lowered for the described chip with those
+arguments scaled to the real size. XLA's TPU compiler raises here what it
+would raise on the chip (unsupported ops, programs that do not fit HBM).
+
+The topology is described inside a module-scoped fixture, never at
+import: only the xdist worker that runs this file loads libtpu, and it
+keeps it until it exits — so every compile happens in this process, and
+all of them live in this one file.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from pilosa_tpu import ops
+from pilosa_tpu.core import Holder
+from pilosa_tpu.core.field import FieldOptions
+from pilosa_tpu.executor import Executor, compile as query_compile, executor as executor_mod
+from pilosa_tpu.executor.compile import QueryCompiler
+from pilosa_tpu.executor.residency import RUN_MAX_INTERVALS, SPARSE_MAX_IDS
+from pilosa_tpu.parallel.mesh import MeshQueryEngine
+from pilosa_tpu.pql import parse
+from pilosa_tpu.shardwidth import SHARD_WIDTH, WORDS_PER_SHARD
+
+# chip_smoke.py's size: SHARDS, RESIDENCY_SHARDS, the production width
+S, W, S_RARE = 256, 1 << 15, 64
+S_TINY = 3  # shards of the CPU run; differs from every other dimension
+HBM_BYTES = 16 * 10**9  # one v5e chip
+# dense stacks the smoke keeps resident beside any one program's inputs:
+# cab_type, passenger_count, existence at 8 padded rows and fare at 32
+RESIDENT_BYTES = (8 + 8 + 8 + 32) * S * W * 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    """The (shards × words) serving mesh over the four described chips."""
+    return Mesh(np.array(topo.devices).reshape(4, 1), ("shards", "words"))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one warns and
+    recompiles), so the cache is off around this file's compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def rig():
+    """The smoke's schema at a tiny size on the CPU, device route pinned."""
+    h = Holder(None)
+    idx = h.create_index("taxi")
+    cab = idx.create_field("cab_type")
+    pas = idx.create_field("passenger_count")
+    fare = idx.create_field(
+        "fare", FieldOptions(field_type="int", min=0, max=65535)
+    )
+    rng = np.random.default_rng(0)
+    n = 4000
+    cols = rng.choice(S_TINY * SHARD_WIDTH, n, replace=False).astype(np.uint64)
+    cab.import_bulk(rng.integers(0, 5, n).astype(np.uint64), cols)
+    pas.import_bulk(rng.integers(0, 8, n).astype(np.uint64), cols)
+    fare.import_values(cols, rng.integers(0, 1 << 16, n))
+    idx.mark_columns_exist(cols)
+    e = Executor(h, route_mode="device")
+    # GroupBy chunks its [G, S, W] group masks to an eighth of the stack
+    # budget (70 % of HBM): hold the tiny run to as many PLANES as the
+    # chip's budget holds at the real size, so it chunks the same way
+    real_planes = int(HBM_BYTES * 0.7) // 8 // (S * W * 4)
+    e.GROUPBY_MASK_BUDGET = real_planes * S_TINY * WORDS_PER_SHARD * 4
+    return h, idx, e
+
+
+def record(monkeypatch, executor, pql: str) -> list[tuple]:
+    """Run ``pql`` on the CPU; → [(jitted program, args)] for every
+    program the query compiler built or reused for it."""
+    calls: list[tuple] = []
+    original = QueryCompiler.program
+
+    def program(self, key, build):
+        prog = original(self, key, build)
+
+        def recorder(*args):
+            calls.append((prog, args))
+            return prog(*args)
+
+        return recorder
+
+    monkeypatch.setattr(QueryCompiler, "program", program)
+    executor.execute("taxi", pql)
+    monkeypatch.setattr(QueryCompiler, "program", original)
+    assert calls, f"{pql} ran no compiled program"
+    return calls
+
+
+def real_size(args, sharding_of):
+    """The recorded arguments as shapes at the smoke's real size:
+    trailing [S_TINY, W_test] plane dimensions become [S, W]."""
+
+    def one(x):
+        if not isinstance(x, (np.ndarray, jax.Array)):
+            return x
+        shape = tuple(x.shape)
+        if shape[-2:] == (S_TINY, WORDS_PER_SHARD):
+            shape = shape[:-2] + (S, W)
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=sharding_of(shape))
+
+    return jax.tree_util.tree_map(one, args)
+
+
+def shapes_on(sharding):
+    """(shape, dtype) → an abstract argument placed with ``sharding``."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def compile_and_fit(prog, args, devices: int = 1):
+    """Compile for the described chip(s); the program's own arguments,
+    temporaries and outputs must fit HBM beside the resident stacks."""
+    compiled = prog.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    need = (
+        mem.argument_size_in_bytes
+        + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes
+        + RESIDENT_BYTES // devices
+    )
+    assert need < HBM_BYTES, f"needs {need / 2**30:.1f} GiB of a 16 GB chip: {mem}"
+    return compiled
+
+
+@pytest.mark.parametrize(
+    "pql",
+    [
+        "Count(Intersect(Row(cab_type=0), Row(passenger_count=1)))",
+        "Count(Union(Row(cab_type=1), Row(cab_type=3), Row(passenger_count=4)))",
+        "Count(Difference(Row(cab_type=0), Row(passenger_count=1)))",
+        "Count(Not(Row(cab_type=0)))",
+    ],
+)
+def test_count_over_set_op_tree(rig, one_chip, monkeypatch, pql):
+    _h, _idx, e = rig
+    for prog, args in record(monkeypatch, e, pql):
+        compile_and_fit(prog, real_size(args, lambda _s: one_chip))
+
+
+def test_topn_with_filter(rig, one_chip, monkeypatch):
+    _h, _idx, e = rig
+    for prog, args in record(
+        monkeypatch, e, "TopN(passenger_count, Row(cab_type=1), n=4)"
+    ):
+        compile_and_fit(prog, real_size(args, lambda _s: one_chip))
+
+
+@pytest.mark.parametrize(
+    "pql",
+    [
+        "Sum(Row(cab_type=2), field=fare)",
+        "Min(field=fare)",
+        "Count(Row(fare > 20000))",
+        "Count(Row(1000 <= fare <= 30000))",
+    ],
+)
+def test_bsi_sum_minmax_and_range(rig, one_chip, monkeypatch, pql):
+    """int64 reductions and the bit-sliced compares over [32, S, W]."""
+    _h, _idx, e = rig
+    for prog, args in record(monkeypatch, e, pql):
+        compile_and_fit(prog, real_size(args, lambda _s: one_chip))
+
+
+def test_groupby_level_with_sum(rig, one_chip, monkeypatch):
+    """One GroupBy level: the count pass, the surviving-mask pass and
+    aggregate=Sum over the 5 × 8 group masks, chunked as the mask
+    budget chunks them at the real size."""
+    _h, _idx, e = rig
+    calls = record(
+        monkeypatch, e,
+        "GroupBy(Rows(cab_type), Rows(passenger_count), "
+        "aggregate=Sum(field=fare))",
+    )
+    for prog, args in calls:  # gb_sums: (slices [D,S,W], masks [G,S,W])
+        compile_and_fit(prog, real_size(args, lambda _s: one_chip))
+    sds = shapes_on(one_chip)
+    masks, matrix = sds((8, S, W), np.uint32), sds((8, S, W), np.uint32)
+    compile_and_fit(
+        executor_mod._gb_counts, (masks, matrix, sds((8,), np.int32))
+    )
+    compile_and_fit(
+        executor_mod._gb_masks,
+        (masks, matrix, sds((16,), np.int32), sds((16,), np.int32)),
+    )
+
+
+def test_stack_delta_and_store_scatters(one_chip):
+    """The write path: dirty rows scattered into the resident fare stack,
+    and promoted rows scattered into the tiered container stores at the
+    sizes the default budget gives them (70 % of 16 GB)."""
+    sds = shapes_on(one_chip)
+    compile_and_fit(
+        query_compile._apply_stack_delta,
+        (sds((32, S, W), np.uint32), sds((16, 2), np.int32),
+         sds((16, W), np.uint32)),
+    )
+    stores = [
+        ((512, S_RARE, W), np.uint32),  # dense planes, half the budget
+        ((131072, SPARSE_MAX_IDS), np.int32),  # sparse ids, an eighth
+        ((524288, RUN_MAX_INTERVALS, 2), np.int32),  # runs, a sixteenth
+    ]
+    for shape, dtype in stores:
+        compile_and_fit(
+            query_compile._scatter_rows,
+            (sds(shape, dtype), sds((4,), np.int32),
+             sds((4,) + shape[1:], dtype)),
+        )
+
+
+def test_tiered_container_decode(one_chip):
+    """Sparse and run payloads decoded to [S, W] planes inside the
+    consuming program, and the payload-only direct counts."""
+    sds = shapes_on(one_chip)
+
+    @jax.jit
+    def intersect_count(ids, runs):
+        words = ops.containers.sparse_plane(
+            ids, S_RARE, W
+        ) & ops.containers.run_plane(runs, S_RARE, W)
+        return jax.numpy.sum(ops.popcount_rows(words).astype(jax.numpy.int64))
+
+    ids = sds((SPARSE_MAX_IDS,), np.int32)
+    runs = sds((RUN_MAX_INTERVALS, 2), np.int32)
+    compile_and_fit(intersect_count, (ids, runs))
+    compile_and_fit(jax.jit(ops.containers.sparse_count), (ids,))
+    compile_and_fit(jax.jit(ops.containers.run_count), (runs,))
+
+
+def test_mesh_count_and_topn(rig, mesh):
+    """The shard_map Count and filtered-TopN builders on a 2×2 v5e mesh,
+    stacks partitioned along the shards axis."""
+    _h, idx, e = rig
+    engine = MeshQueryEngine(mesh)
+    shards = list(range(S_TINY))
+
+    def placed(shape):
+        spec = P(*(None,) * (len(shape) - 2), "shards", "words") if len(shape) > 1 else P()
+        return NamedSharding(mesh, spec)
+
+    def plan(pql):
+        planner = query_compile._Planner(
+            idx, shards, e.compiler.stacks, block_shape=(S // 4, W)
+        )
+        run, _skey = planner.plan(parse(pql)[0])
+        arrays = planner.materialize()
+        scalars = np.asarray(planner.scalar_values(), dtype=np.int32)
+        return run, real_size((arrays, scalars), placed)
+
+    run, args = plan("Intersect(Row(cab_type=0), Row(passenger_count=1))")
+    compiled = compile_and_fit(engine.count_tree(run, "grid"), args, devices=4)
+    assert "all-reduce" in compiled.as_text()  # the psum tree over chips
+
+    frun, (farrays, fscalars) = plan("Row(cab_type=1)")
+    matrix = jax.ShapeDtypeStruct((8, S, W), np.uint32, sharding=placed((8, S, W)))
+    compile_and_fit(
+        engine.topn_tree("grid", True, False, frun=frun),
+        (matrix, farrays, fscalars),
+        devices=4,
+    )
