@@ -46,6 +46,7 @@ from pilosa_tpu.core.timequantum import views_by_time_range
 from pilosa_tpu.pql import Call, Condition, coerce_timestamp
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD
 from pilosa_tpu.utils import saturation
+from pilosa_tpu.utils.tracing import GLOBAL_TRACER
 
 
 class PlanError(ValueError):
@@ -69,6 +70,25 @@ class StackOverBudget(Exception):
             f"{budget / 2**20:.0f} MiB); high-cardinality fields answer "
             "Row/Count/TopN via the hot-row path"
         )
+
+
+def named_jit(name: str, fn: Callable) -> Callable:
+    """``jax.jit(fn)`` under a program NAME: the XLA module is
+    ``jit_<name>``, which is what the device trace's ``XLA Modules``
+    line, ``JAX_LOG_COMPILES`` lines and the compile counter's
+    ``program`` label (utils/xlaevents.py) then say ran or compiled —
+    not ``jit__lambda`` for every program of the engine. Every program
+    built under executor/ goes through here (tests/test_trace_clock.py
+    walks the tree for a bare ``jax.jit``). ``name`` comes from the call
+    type alone (``pilosa_topn``), never from data, so the label set
+    stays small; program-cache keys are unchanged."""
+
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    program.__wrapped__ = fn  # argument names and source line stay fn's
+    return jax.jit(program)
 
 
 # --------------------------------------------------------------- stacking
@@ -184,7 +204,6 @@ def _stack_budget() -> int:
     return budget
 
 
-@jax.jit
 def _scatter_rows(store, idx, rows):
     """Functional row scatter for the tiered container stores:
     ``store[idx[k]] = rows[k]`` for dense [H,S,W], sparse [H,K] and run
@@ -193,7 +212,9 @@ def _scatter_rows(store, idx, rows):
     return store.at[idx].set(rows, mode="drop")
 
 
-@jax.jit
+_scatter_rows = named_jit("pilosa_scatter_rows", _scatter_rows)
+
+
 def _apply_stack_delta(matrix, idx, rows):
     """Scatter ``rows[k]`` into ``matrix[idx[k,0], idx[k,1]]`` on device
     (row-major stacks: idx columns are (row, shard)). Padding entries use
@@ -202,6 +223,9 @@ def _apply_stack_delta(matrix, idx, rows):
     stack; the device-to-device copy rides HBM bandwidth, which is the
     point — the host→device upload is what O(dirty rows) avoids."""
     return matrix.at[idx[:, 0], idx[:, 1]].set(rows, mode="drop")
+
+
+_apply_stack_delta = named_jit("pilosa_stack_delta", _apply_stack_delta)
 
 
 class StackCache:
@@ -396,11 +420,21 @@ class StackCache:
             if cached is not None:
                 entry = self._try_delta(cached, view, shards, versions, view_ver)
             if entry is None:
-                stacked, max_rows = stack_view_matrices(view, shards)
-                if self.mesh_ctx is not None:
-                    dev = self.mesh_ctx.place_stack(stacked)
-                else:
-                    dev = jnp.asarray(stacked)
+                with GLOBAL_TRACER.span(
+                    "stack.pack", field=field.name, shards=len(shards)
+                ) as packed:
+                    stacked, max_rows = stack_view_matrices(view, shards)
+                    packed.tags.update(rows=max_rows, bytes=int(stacked.nbytes))
+                with GLOBAL_TRACER.span("stack.upload", **packed.tags) as uploaded:
+                    if self.mesh_ctx is not None:
+                        dev = self.mesh_ctx.place_stack(stacked)
+                    else:
+                        dev = jnp.asarray(stacked)
+                if self.stats is not None:
+                    # set-up lies outside the benchmark's traced slice,
+                    # so packing and upload are also read as counters
+                    self.stats.timing("stack_pack_seconds", packed.duration)
+                    self.stats.timing("stack_upload_seconds", uploaded.duration)
                 with self._lock:
                     self.full_restacks += 1
                 entry = (versions, dev, max_rows, view_ver)
@@ -459,15 +493,22 @@ class StackCache:
         if not updates:
             return (versions, dev, max_rows, view_ver)
         k_pad = 1 << (len(updates) - 1).bit_length()
-        idx_arr = np.full((k_pad, 2), _OOB, dtype=np.int32)  # OOB ⇒ drop
-        row_arr = np.zeros((k_pad, WORDS_PER_SHARD), dtype=np.uint32)
-        for k, (i, r, words) in enumerate(updates):
-            idx_arr[k] = (r, i)
-            row_arr[k] = words
-        new_dev = _apply_stack_delta(dev, idx_arr, row_arr)
-        if new_dev.sharding != dev.sharding:
-            # the scatter must not silently demote the stack's SPMD layout
-            new_dev = jax.device_put(new_dev, dev.sharding)
+        with GLOBAL_TRACER.span(
+            "stack.delta",
+            field=view.field,
+            shards=len(shards),
+            rows=len(updates),
+            bytes=k_pad * WORDS_PER_SHARD * 4,
+        ):
+            idx_arr = np.full((k_pad, 2), _OOB, dtype=np.int32)  # OOB ⇒ drop
+            row_arr = np.zeros((k_pad, WORDS_PER_SHARD), dtype=np.uint32)
+            for k, (i, r, words) in enumerate(updates):
+                idx_arr[k] = (r, i)
+                row_arr[k] = words
+            new_dev = _apply_stack_delta(dev, idx_arr, row_arr)
+            if new_dev.sharding != dev.sharding:
+                # the scatter must not silently demote the stack's SPMD layout
+                new_dev = jax.device_put(new_dev, dev.sharding)
         with self._lock:
             self.delta_updates += 1
             self.delta_rows_uploaded += len(updates)
@@ -1630,9 +1671,7 @@ class QueryCompiler:
         device uint32[S, W]."""
         planner, run, skey = self._plan(idx, call, shards)
         key = (idx.name, len(shards), skey, "words")
-        prog = self.program(
-            key, lambda: jax.jit(lambda arrays, scalars: run(arrays, scalars))
-        )
+        prog = self.program(key, lambda: named_jit("pilosa_words", run))
         arrays = planner.materialize()
         return prog(arrays, self.device_scalars(planner.scalar_values()))
 
@@ -1659,14 +1698,13 @@ class QueryCompiler:
 
         def build():
             if direct is not None:
-                return jax.jit(direct)
+                return named_jit("pilosa_count_direct", direct)
 
-            @jax.jit
-            def prog(arrays, scalars):
+            def count(arrays, scalars):
                 words = run(arrays, scalars)
                 return jnp.sum(ops.popcount_rows(words).astype(jnp.int64))
 
-            return prog
+            return named_jit("pilosa_count", count)
 
         prog = self.program(key, build)
         arrays = planner.materialize()
@@ -1680,7 +1718,7 @@ class QueryCompiler:
         planner = _Planner(idx, shards, self.stacks)
         run, skey = planner._bsi(field)
         key = (idx.name, len(shards), skey, "bsi_block")
-        prog = self.program(key, lambda: jax.jit(run))
+        prog = self.program(key, lambda: named_jit("pilosa_bsi_block", run))
         arrays = planner.materialize()
         return prog(arrays, self.device_scalars(planner.scalar_values()))
 
@@ -1715,8 +1753,6 @@ class QueryCompiler:
     def _mesh_dispatch(self, name: str, prog, *args):
         """Issue one mesh program: spanned per program (the
         ``mesh.dispatch`` trace surface) and counted for /debug/vars."""
-        from pilosa_tpu.utils.tracing import GLOBAL_TRACER
-
         eng = self.mesh_engine
         eng.note_call(name)
         with GLOBAL_TRACER.span(
@@ -1745,8 +1781,6 @@ class QueryCompiler:
         """Synchronous mesh bitmap: the gather of the sharded result IS a
         collective readback — spanned as ``mesh.collective`` so the query
         trace shows where the cross-chip transfer happened."""
-        from pilosa_tpu.utils.tracing import GLOBAL_TRACER
-
         dev = self.mesh_bitmap_device(idx, call, shards)
         with GLOBAL_TRACER.span(
             "mesh.collective", program="bitmap",

@@ -47,6 +47,7 @@ from pilosa_tpu.executor.compile import (
     QueryCompiler,
     StackOverBudget,
     _stack_budget,
+    named_jit,
 )
 from pilosa_tpu.executor.hostpath import HostPlanError
 from pilosa_tpu.executor.router import QueryRouter, estimate_words
@@ -173,7 +174,6 @@ class _Pending:
         return self.value
 
 
-@jax.jit
 def _gb_counts(masks, matrix, rows):
     """GroupBy level counts: [G,S,W] masks × K candidate rows (gathered
     from the [R,S,W] row-major stack) → int64[G,K] in one dispatch
@@ -188,7 +188,9 @@ def _gb_counts(masks, matrix, rows):
     return jax.lax.map(per_row, gathered).T
 
 
-@jax.jit
+_gb_counts = named_jit("pilosa_groupby_counts", _gb_counts)
+
+
 def _gb_masks(masks, matrix, g_idx, row_sel):
     """Materialize surviving groups' masks: gather parent masks and
     candidate rows (axis 0 of the row-major stack), AND them — one
@@ -196,6 +198,9 @@ def _gb_masks(masks, matrix, g_idx, row_sel):
     sel = jnp.take(masks, g_idx, axis=0)
     rows = jnp.take(matrix, row_sel, axis=0, mode="fill", fill_value=0)
     return sel & rows
+
+
+_gb_masks = named_jit("pilosa_groupby_masks", _gb_masks)
 
 
 class SumCount(dict):
@@ -1080,14 +1085,17 @@ class Executor:
 
     def _sum_program(self, field: Field, n_shards: int):
         return self.compiler.program(
-            ("sum", n_shards, field.bit_depth), lambda: jax.jit(self._sum_fn)
+            ("sum", n_shards, field.bit_depth),
+            lambda: named_jit("pilosa_sum", self._sum_fn),
         )
 
     def _grouped_sum_program(self, field: Field, n_shards: int):
         """(slices [D,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G])."""
         return self.compiler.program(
             ("gb_sums", n_shards, field.bit_depth),
-            lambda: jax.jit(jax.vmap(self._sum_fn, in_axes=(None, 0))),
+            lambda: named_jit(
+                "pilosa_sum_groups", jax.vmap(self._sum_fn, in_axes=(None, 0))
+            ),
         )
 
     def _execute_sum(
@@ -1126,8 +1134,9 @@ class Executor:
                 frun, farrays, fscalars, fskey = fplan
                 pos, neg, n = self.compiler.run_program(
                     ("sum", len(shards), field.bit_depth, fskey),
-                    lambda: jax.jit(
-                        lambda s, fa, fs: self._sum_fn(s, frun(fa, fs))
+                    lambda: named_jit(
+                        "pilosa_sum_filtered",
+                        lambda s, fa, fs: self._sum_fn(s, frun(fa, fs)),
                     ),
                     slices,
                     farrays,
@@ -1197,8 +1206,9 @@ class Executor:
                 frun, farrays, fscalars, fskey = fplan
                 values, counts = self.compiler.run_program(
                     ("minmax", len(shards), field.bit_depth, want_max, fskey),
-                    lambda: jax.jit(
-                        lambda s, fa, fs: vmapped(s, frun(fa, fs))
+                    lambda: named_jit(
+                        "pilosa_minmax_filtered",
+                        lambda s, fa, fs: vmapped(s, frun(fa, fs)),
                     ),
                     slices,
                     farrays,
@@ -1207,7 +1217,7 @@ class Executor:
             else:
                 values, counts = self.compiler.run_program(
                     ("minmax", len(shards), field.bit_depth, want_max),
-                    lambda: jax.jit(lambda s, f: vmapped(s, f)),
+                    lambda: named_jit("pilosa_minmax", vmapped),
                     slices,
                     self.compiler.ones(len(shards)),
                 )
@@ -1301,12 +1311,13 @@ class Executor:
                 frun, farrays, fscalars, fskey = fplan
                 counts = self.compiler.run_program(
                     ("topn_ids", len(shards), fskey),
-                    lambda: jax.jit(
+                    lambda: named_jit(
+                        "pilosa_topn_ids_filtered",
                         lambda m, r, fa, fs: jax.vmap(
                             ops.topn.candidate_counts, in_axes=(1, None, 0)
                         )(m, r, frun(fa, fs))
                         .astype(jnp.int64)
-                        .sum(axis=0)
+                        .sum(axis=0),
                     ),
                     matrix,
                     row_ids,
@@ -1316,7 +1327,8 @@ class Executor:
             else:
                 counts = self.compiler.run_program(
                     ("topn_ids", len(shards)),
-                    lambda: jax.jit(
+                    lambda: named_jit(
+                        "pilosa_topn_ids",
                         lambda m, r: jnp.sum(
                             ops.popcount_rows(
                                 jnp.take(
@@ -1324,7 +1336,7 @@ class Executor:
                                 )
                             ).astype(jnp.int64),
                             axis=1,
-                        )
+                        ),
                     ),
                     matrix,
                     row_ids,
@@ -1368,12 +1380,13 @@ class Executor:
                 # dispatch, no [S, W] HBM round trip
                 counts = self.compiler.run_program(
                     ("topn", len(shards), fskey),
-                    lambda: jax.jit(
+                    lambda: named_jit(
+                        "pilosa_topn_filtered",
                         lambda m, fa, fs: ops.popcount_rows(
                             m & frun(fa, fs)[None]
                         )
                         .astype(jnp.int64)
-                        .sum(axis=1)
+                        .sum(axis=1),
                     ),
                     matrix,
                     farrays,
@@ -1384,10 +1397,11 @@ class Executor:
                 # materialized all-ones array — pure HBM traffic)
                 counts = self.compiler.run_program(
                     ("topn", len(shards)),
-                    lambda: jax.jit(
+                    lambda: named_jit(
+                        "pilosa_topn",
                         lambda m: ops.popcount_rows(m)
                         .astype(jnp.int64)
-                        .sum(axis=1)
+                        .sum(axis=1),
                     ),
                     matrix,
                 )
@@ -1442,12 +1456,13 @@ class Executor:
         frags = [view.fragment(s) if view else None for s in shards]
         prog = self.compiler.program(
             ("topn_chunk", len(shards)),
-            lambda: jax.jit(
+            lambda: named_jit(
+                "pilosa_topn_chunk",
                 # g [C,S,W] row-major chunk, f [S,W] → int64[C]
                 lambda g, f: jnp.sum(
                     ops.popcount_rows(g & f[None]).astype(jnp.int64),
                     axis=1,
-                )
+                ),
             ),
         )
         pairs: list = []

@@ -48,6 +48,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from pilosa_tpu.executor.compile import named_jit
 from pilosa_tpu.executor.executor import (
     WRITE_CALLS,
     ExecutionError,
@@ -67,6 +68,12 @@ BATCH_MODES = ("off", "adaptive", "always")
 _SOLO_OCCUPANCY = 1.25
 
 
+# the wave's join as a NAMED program (jit_pilosa_wave_join on the device
+# trace and in the compile counter's ``program`` label); like the eager
+# concatenate it replaces, it compiles per sequence of result sizes
+_wave_join = named_jit("pilosa_wave_join", lambda *flat: jnp.concatenate(flat))
+
+
 def fetch_wave(pending: "list[_Pending]") -> None:
     """THE settlement layer — the one sanctioned device→host readback
     site (the analyzer's readback rule names this function, not the
@@ -74,16 +81,25 @@ def fetch_wave(pending: "list[_Pending]") -> None:
     wave, ravel to int64, concatenate, and cross the transport in ONE
     transfer.  Host arrays land on ``p.fetched`` (original shapes);
     resolving finish() is the caller's job so per-query error isolation
-    stays possible."""
-    flat = [jnp.ravel(a).astype(jnp.int64) for p in pending for a in p.arrays]
-    if len(flat) == 1:
-        host = [np.asarray(flat[0])]
-    else:
-        joined = np.asarray(jnp.concatenate(flat))
-        host, off = [], 0
-        for a in flat:
-            host.append(joined[off : off + a.size])
-            off += a.size
+    stays possible.
+
+    Two spans split what the readback histogram times as one:
+    ``readback.join`` is the enqueue of the join (and, on a new sequence
+    of sizes, its trace + lower + compile), ``readback.transfer`` the
+    wait for the device and the copy."""
+    with GLOBAL_TRACER.span("readback.join") as sp:
+        flat = [
+            jnp.ravel(a).astype(jnp.int64) for p in pending for a in p.arrays
+        ]
+        joined = flat[0] if len(flat) == 1 else _wave_join(*flat)
+        sp.set_tag("arrays", len(flat))
+    with GLOBAL_TRACER.span("readback.transfer") as sp:
+        joined = np.asarray(joined.block_until_ready())
+        sp.set_tag("bytes", int(joined.nbytes))
+    host, off = [], 0
+    for a in flat:
+        host.append(joined[off : off + a.size])
+        off += a.size
     i = 0
     for p in pending:
         args = []
@@ -450,21 +466,22 @@ class WaveScheduler:
         else's waves while its own finished response sat undelivered —
         measured as c8 throughput BELOW c1 on the first cut of this
         scheduler."""
-        while True:
-            with self._cond:
-                while not item.done.is_set() and (
-                    self._leader_active or not self._queue
-                ):
-                    self._cond.wait()
-                if item.done.is_set():
-                    return
-                self._leader_active = True
-            try:
-                self._run_one_wave()
-            finally:
+        with GLOBAL_TRACER.span("scheduler.await"):
+            while True:
                 with self._cond:
-                    self._leader_active = False
-                    self._cond.notify_all()
+                    while not item.done.is_set() and (
+                        self._leader_active or not self._queue
+                    ):
+                        self._cond.wait()
+                    if item.done.is_set():
+                        return
+                    self._leader_active = True
+                try:
+                    self._run_one_wave()
+                finally:
+                    with self._cond:
+                        self._leader_active = False
+                        self._cond.notify_all()
 
     def _run_one_wave(self) -> None:
         # resolve the executor AT WAVE TIME, not from whatever instance
@@ -481,7 +498,9 @@ class WaveScheduler:
         if len(batch) >= self.max_queries:
             reason = "full"
         else:
-            reason = self._wait_window(executor, batch)
+            with GLOBAL_TRACER.span("scheduler.window") as sp:
+                reason = self._wait_window(executor, batch)
+                sp.set_tag("reason", reason)
         try:
             self._execute_wave(executor, batch, reason)
         except Exception as e:  # noqa: BLE001 — harness backstop: a
@@ -595,7 +614,9 @@ class WaveScheduler:
             fetch_seconds = 0.0
             if all_pending:
                 try:
-                    fetch_seconds = executor.fetch(all_pending)
+                    fetch_seconds = self._readback(
+                        executor, all_pending, wave_span.span_id
+                    )
                 except Exception:  # noqa: BLE001 — a poisoned joint
                     # readback falls back to per-query fetches below so
                     # only the poisoned query errors
@@ -603,7 +624,9 @@ class WaveScheduler:
             for it in settled:
                 try:
                     if not joint_ok and it.pendings:
-                        fetch_seconds = executor.fetch(it.pendings)
+                        fetch_seconds = self._readback(
+                            executor, it.pendings, wave_span.span_id
+                        )
                     for p in it.pendings:
                         p.resolve_fetched()
                     wave_info = {
@@ -640,6 +663,17 @@ class WaveScheduler:
         if self.stats is not None:
             self.stats.observe("queries_per_wave", float(n))
             self.stats.count("wave_flush_reason", tags={"reason": reason})
+
+    @staticmethod
+    def _readback(executor, pendings: "list[_Pending]", wave_id: str) -> float:
+        """executor.fetch under the ``scheduler.readback`` span (the
+        joint call and the per-query fall-back alike)."""
+        with GLOBAL_TRACER.span(
+            "scheduler.readback",
+            wave=wave_id,
+            arrays=sum(len(p.arrays) for p in pendings),
+        ):
+            return executor.fetch(pendings)
 
     def _finish(
         self,

@@ -71,6 +71,10 @@ def popcount(words) -> jax.Array:
     return jnp.sum(popcount_rows(words).astype(jnp.int64))
 
 
+# Kernels carry a ``pilosa.<kernel>`` named scope: inside a program that
+# holds more than one (a filter's mask, then the popcount under it; the
+# BSI planes' sum) the device trace's op names say which is which.
+@jax.named_scope("pilosa.popcount_rows")
 def popcount_rows(matrix) -> jax.Array:
     """Reduce the trailing word axis: ``uint32[..., W] → int32[...]``.
 
@@ -99,6 +103,7 @@ def count_andnot(a, b) -> jax.Array:
     return popcount(jnp.bitwise_and(a, jnp.bitwise_not(b)))
 
 
+@jax.named_scope("pilosa.filter_counts")
 def matrix_filter_counts(matrix, filt) -> jax.Array:
     """Per-row filtered counts: ``uint32[R, W] & uint32[W] → int32[R]``.
 

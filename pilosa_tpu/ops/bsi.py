@@ -57,6 +57,7 @@ def _magnitude_cmp(mag: jax.Array, c_abs: int) -> tuple[jax.Array, jax.Array, ja
     return eq, lt, gt
 
 
+@jax.named_scope("pilosa.bsi_compare")
 def compare(slices: jax.Array, op: str, value: int) -> jax.Array:
     """Columns whose stored value ⟨op⟩ ``value`` → uint32[W] mask.
 
@@ -110,6 +111,7 @@ def between(slices: jax.Array, lo: int, hi: int) -> jax.Array:
     return compare(slices, ">=", lo) & compare(slices, "<=", hi)
 
 
+@jax.named_scope("pilosa.bsi_sum")
 def sum_counts(slices: jax.Array, filt: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Per-magnitude-bit signed counts for Sum.
 
@@ -149,6 +151,7 @@ def sum_device(slices: jax.Array, filt: jax.Array) -> tuple[jax.Array, jax.Array
     return jnp.sum(diff * weights), n
 
 
+@jax.named_scope("pilosa.bsi_minmax")
 def min_max(slices: jax.Array, filt: jax.Array, want_max: bool) -> tuple[jax.Array, jax.Array]:
     """(value int64, count int64) of the min/max stored value among
     filtered, existing columns. count==0 ⇒ no value (result undefined).

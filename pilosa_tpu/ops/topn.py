@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from pilosa_tpu.ops.bitwise import matrix_filter_counts
 
 
+@jax.named_scope("pilosa.topn_rows")
 def top_rows(matrix: jax.Array, filt: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     """(counts int32[k], row_ids int32[k]) of the k largest filtered row
     counts in one fragment. Rows with zero count still appear if k exceeds
@@ -26,6 +27,7 @@ def top_rows(matrix: jax.Array, filt: jax.Array, k: int) -> tuple[jax.Array, jax
     return vals, idx.astype(jnp.int32)
 
 
+@jax.named_scope("pilosa.topn_candidates")
 def candidate_counts(
     matrix: jax.Array, row_ids: jax.Array, filt: jax.Array
 ) -> jax.Array:

@@ -326,15 +326,22 @@ class MeshQueryEngine:
         then shards — the multi-node merge transforms' order, intra-mesh."""
         return jax.lax.psum(jax.lax.psum(v, AXIS_WORDS), AXIS_SHARDS)
 
-    def _spmd(self, local, in_specs, out_specs, check_rep: bool = True):
-        prog = jax.jit(
+    def _spmd(
+        self, kind: str, local, in_specs, out_specs, check_rep: bool = True
+    ):
+        """One serving-path shard_map program, named ``pilosa_mesh_<kind>``
+        on the device trace and in the compile counter (named_jit)."""
+        from pilosa_tpu.executor.compile import named_jit
+
+        prog = named_jit(
+            f"pilosa_mesh_{kind}",
             shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
                 check_rep=check_rep,
-            )
+            ),
         )
         with self._stats_lock:
             self.programs_built += 1
@@ -378,6 +385,7 @@ class MeshQueryEngine:
             return run(arrays, scalars)
 
         return self._spmd(
+            "bitmap",
             local,
             (self._arr_spec(1, mode), P()),
             self.row_spec(mode),
@@ -392,7 +400,7 @@ class MeshQueryEngine:
                 jnp.sum(ops.popcount_rows(words).astype(jnp.int64))
             )
 
-        return self._spmd(local, (self._arr_spec(1, mode), P()), P())
+        return self._spmd("count", local, (self._arr_spec(1, mode), P()), P())
 
     def topn_tree(self, mode: str, filtered: bool, ids: bool, frun=None):
         """Per-row global counts int64[R] (or [K] for ids=), replicated:
@@ -412,7 +420,7 @@ class MeshQueryEngine:
                 return self._psum_both(row_counts(g, frun(farrays, fscalars)))
 
             return self._spmd(
-                local, (spec3, P(), spec3, P()), P()
+                "topn", local, (spec3, P(), spec3, P()), P()
             )
         if ids:
 
@@ -420,7 +428,7 @@ class MeshQueryEngine:
                 g = jnp.take(matrix, row_ids, axis=0, mode="fill", fill_value=0)
                 return self._psum_both(row_counts(g, None))
 
-            return self._spmd(local, (spec3, P()), P())
+            return self._spmd("topn", local, (spec3, P()), P())
         if filtered:
 
             def local(matrix, farrays, fscalars):
@@ -428,12 +436,12 @@ class MeshQueryEngine:
                     row_counts(matrix, frun(farrays, fscalars))
                 )
 
-            return self._spmd(local, (spec3, spec3, P()), P())
+            return self._spmd("topn", local, (spec3, spec3, P()), P())
 
         def local(matrix):
             return self._psum_both(row_counts(matrix, None))
 
-        return self._spmd(local, (spec3,), P())
+        return self._spmd("topn", local, (spec3,), P())
 
     def sum_tree(self, sum_fn, mode: str, frun=None):
         """BSI Sum: (slices [D,S,W], filter) → (pos[D], neg[D], n),
@@ -451,7 +459,7 @@ class MeshQueryEngine:
                 )
 
             return self._spmd(
-                local, (spec3, spec3, P()), (P(), P(), P())
+                "sum", local, (spec3, spec3, P()), (P(), P(), P())
             )
 
         def local(slices, filt):
@@ -463,7 +471,7 @@ class MeshQueryEngine:
             )
 
         return self._spmd(
-            local, (spec3, self.row_spec(mode)), (P(), P(), P())
+            "sum", local, (spec3, self.row_spec(mode)), (P(), P(), P())
         )
 
     def grouped_sum_tree(self, sum_fn, mode: str):
@@ -479,7 +487,9 @@ class MeshQueryEngine:
                 self._psum_both(n),
             )
 
-        return self._spmd(local, (spec3, spec3), (P(), P(), P()))
+        return self._spmd(
+            "sum_groups", local, (spec3, spec3), (P(), P(), P())
+        )
 
     def minmax_tree(self, want_max: bool, mode: str, frun=None):
         """BSI Min/Max: per-device per-shard extremes, all-gathered to a
@@ -509,13 +519,18 @@ class MeshQueryEngine:
                 return body(slices, frun(farrays, fscalars))
 
             return self._spmd(
-                local, (spec3, spec3, P()), (P(), P()), check_rep=False
+                "minmax",
+                local,
+                (spec3, spec3, P()),
+                (P(), P()),
+                check_rep=False,
             )
 
         def local(slices, filt):
             return body(slices, filt)
 
         return self._spmd(
+            "minmax",
             local,
             (spec3, self.row_spec(mode)),
             (P(), P()),
@@ -535,7 +550,7 @@ class MeshQueryEngine:
             )
             return self._psum_both(jax.lax.map(per_row, gathered).T)
 
-        return self._spmd(local, (spec3, spec3, P()), P())
+        return self._spmd("groupby_counts", local, (spec3, spec3, P()), P())
 
     def groupby_masks_tree(self, mode: str):
         """(masks, matrix, g_idx, row_sel) → sharded [P,S,W] surviving
@@ -547,7 +562,9 @@ class MeshQueryEngine:
             rows = jnp.take(matrix, row_sel, axis=0, mode="fill", fill_value=0)
             return sel & rows
 
-        return self._spmd(local, (spec3, spec3, P(), P()), spec3)
+        return self._spmd(
+            "groupby_masks", local, (spec3, spec3, P(), P()), spec3
+        )
 
     # ------------------------------------------------------------ placement
     def spec_matrix(self) -> NamedSharding:

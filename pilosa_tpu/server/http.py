@@ -580,16 +580,20 @@ class Handler(BaseHTTPRequestHandler):
                 f" trace={prof.trace_id} fp={fp} rank={rank}{cache_tag}"
                 f"{where}: {pql[:200]}"
             )
-        if proto:
-            self._proto(encoding.protoser.response_to_bytes(resp))
-        else:
-            if want_profile:
-                resp = dict(resp)
-                resp["profile"] = prof.to_json()
-            if analyze:
-                resp = dict(resp)
-                resp["explain"] = self._merge_explain_actuals(plan, prof)
-            self._json(resp)
+        # encode-and-write, spanned apart from pql.query (which closed
+        # with the executor's answer): on a profiler trace the reply's
+        # share of a request is its own slice
+        with GLOBAL_TRACER.span("pql.reply", index=index):
+            if proto:
+                self._proto(encoding.protoser.response_to_bytes(resp))
+            else:
+                if want_profile:
+                    resp = dict(resp)
+                    resp["profile"] = prof.to_json()
+                if analyze:
+                    resp = dict(resp)
+                    resp["explain"] = self._merge_explain_actuals(plan, prof)
+                self._json(resp)
         # recorded AFTER the response ships so the capture carries the
         # real result size (send_header stashed Content-Length)
         self._workload_record(

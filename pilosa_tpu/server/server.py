@@ -243,10 +243,13 @@ class Server:
         # the module-level metrics sink (hot locks are constructed deep
         # inside core/executor where no client is in scope) and its GIL
         # probe thread
-        from pilosa_tpu.utils import saturation
+        from pilosa_tpu.utils import saturation, xlaevents
         from pilosa_tpu.utils.profiler import SamplingProfiler
 
         saturation.set_stats(self.stats)
+        # compile counter with its site (docs/observability.md): jax
+        # reports each compile on the thread that asked for it
+        xlaevents.set_stats(self.stats)
         self.profiler = SamplingProfiler(
             hz=self.config.profiler_hz,
             segment_s=self.config.profiler_segment_s,

@@ -98,6 +98,11 @@ _METRIC_HELP = {
     "workload_spill_segments": "workload capture spill segments on disk",
     "slo_burn_rate": "error-budget burn rate per call type and window (1.0 = spending exactly the budget)",
     "slo_budget_remaining": "fraction of the error budget left over the longest SLO window",
+    "xla_compile_seconds": "XLA backend compile requests, by the span that paid and the program",
+    "xla_lower_seconds": "jaxpr trace plus lowering to MLIR, by the span that paid",
+    "xla_cache_lookups": "persistent compilation cache lookups by result (hit / miss)",
+    "stack_pack_seconds": "host time packing one dense stack from fragment matrices",
+    "stack_upload_seconds": "host-to-device placement of one packed stack",
 }
 
 

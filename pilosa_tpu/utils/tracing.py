@@ -16,7 +16,14 @@ the API matches so an OTLP adapter can slot in later. What IS wire-real:
 - ``chrome_trace_stitched`` merges per-node span sets into one Chrome
   trace-event JSON (one pid per node) for Perfetto/chrome://tracing —
   the export story, with the coordinator fetching remote spans via
-  ``GET /internal/trace``.
+  ``GET /internal/trace``;
+- a SECOND sink: while a ``jax.profiler`` session is recording, every
+  span is also a ``TraceAnnotation`` in the trace's ``/host:CPU`` plane
+  (its own thread's line; ``trace_id``/``span_id``/scalar tags as event
+  stats), so the host's timeline sits on the clock the device planes
+  use. jax is never imported from here — the sink exists only in a
+  process that already imported it, and with no session open it costs
+  one flag test per span.
 
 The module also hosts the per-query profile collector (``profile_query``
 / ``current_profile``): a thread-local sink the executor and cluster
@@ -28,6 +35,7 @@ through every router signature.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -110,6 +118,27 @@ class Span:
         }
 
 
+_SCALARS = (str, int, float, bool)
+
+
+def _annotation(s: Span):
+    """The span as an ENTERED profiler annotation, or None while no
+    profiler session records (one flag test) and in a process that has
+    not imported jax (looked up, never imported: the jax-free parents of
+    chip_smoke.py and the benchmark import this module). The getattr
+    chain tolerates a jax still half-imported on another thread."""
+    cls = getattr(
+        getattr(sys.modules.get("jax"), "profiler", None), "TraceAnnotation", None
+    )
+    if cls is None or not cls.is_enabled():
+        return None
+    stats = {k: v for k, v in s.tags.items() if isinstance(v, _SCALARS)}
+    stats.update(trace_id=s.trace_id, span_id=s.span_id)
+    ann = cls(s.name, **stats)
+    ann.__enter__()
+    return ann
+
+
 class Tracer:
     def __init__(self):
         self._lock = threading.Lock()
@@ -136,9 +165,21 @@ class Tracer:
                 s = Span(name)
         s.tags.update(tags)
         self._local.current = s
+        ann = _annotation(s)
         try:
             yield s
         finally:
+            if ann is not None:
+                # tags set inside the body (a wave's flush reason) reach
+                # the trace too; the entry tags are already on the event
+                late = {
+                    k: v
+                    for k, v in s.tags.items()
+                    if k not in tags and isinstance(v, _SCALARS)
+                }
+                if late:
+                    ann.set_metadata(**late)
+                ann.__exit__(None, None, None)
             # same sample as the exported ts — ts and dur must share one
             # clock origin or child slices cross parent edges in viewers
             s.duration = time.perf_counter() - s.start_perf
@@ -194,6 +235,12 @@ class Tracer:
         if remote is not None and remote[0]:
             return (remote[0], remote[1] or "")
         return None
+
+    def current_name(self) -> str | None:
+        """Name of the innermost span open on this thread (the ``site``
+        label of the compile counter, utils/xlaevents.py)."""
+        cur = getattr(self._local, "current", None)
+        return cur.name if cur is not None else None
 
     def current_trace_id(self) -> str | None:
         ctx = self.current_context()
