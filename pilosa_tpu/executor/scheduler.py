@@ -10,7 +10,9 @@ items, one of them becomes the wave leader, drains the queue (plus a
 short adaptive window for stragglers), dispatches every query through
 the existing compile/dispatch layer (``Executor.dispatch`` — the
 parity-covered entry), and settles ALL queries' pending aggregates in
-ONE device→host transfer (``fetch_wave``).  Under sustained concurrency
+ONE settlement (``fetch_wave``: every result array's device→host copy
+started together, awaited once, joined on the host — no device program,
+so nothing to compile per wave).  Under sustained concurrency
 the group-commit effect alone coalesces waves (while one wave executes,
 the next one's queries accumulate); the window only adds burst
 alignment.
@@ -46,9 +48,8 @@ from typing import Any, Callable
 
 import numpy as np
 
-import jax.numpy as jnp
+import jax
 
-from pilosa_tpu.executor.compile import named_jit
 from pilosa_tpu.executor.executor import (
     WRITE_CALLS,
     ExecutionError,
@@ -68,45 +69,40 @@ BATCH_MODES = ("off", "adaptive", "always")
 _SOLO_OCCUPANCY = 1.25
 
 
-# the wave's join as a NAMED program (jit_pilosa_wave_join on the device
-# trace and in the compile counter's ``program`` label); like the eager
-# concatenate it replaces, it compiles per sequence of result sizes
-_wave_join = named_jit("pilosa_wave_join", lambda *flat: jnp.concatenate(flat))
-
-
 def fetch_wave(pending: "list[_Pending]") -> None:
     """THE settlement layer — the one sanctioned device→host readback
     site (the analyzer's readback rule names this function, not the
     whole file): every pending's device arrays, across every query of a
-    wave, ravel to int64, concatenate, and cross the transport in ONE
-    transfer.  Host arrays land on ``p.fetched`` (original shapes);
-    resolving finish() is the caller's job so per-query error isolation
-    stays possible.
+    wave, start their device→host copies together and are awaited once,
+    so a transport with a long round trip pays about one, not one per
+    array — and no XLA program runs, so a new sequence of result sizes
+    compiles nothing.  The join is on the host: each array lands on
+    ``p.fetched`` as int64 in its original shape; resolving finish() is
+    the caller's job so per-query error isolation stays possible.  A
+    sharded or replicated mesh result comes back whole (jax gathers its
+    shards; a replicated one crosses once), a host array passes through
+    the same cast.
 
     Two spans split what the readback histogram times as one:
-    ``readback.join`` is the enqueue of the join (and, on a new sequence
-    of sizes, its trace + lower + compile), ``readback.transfer`` the
-    wait for the device and the copy."""
+    ``readback.join`` starts the copies, ``readback.transfer`` is the
+    wait for the device and the gather (``bytes``: what crossed, in the
+    arrays' own dtypes)."""
+    arrays = [a for p in pending for a in p.arrays]
     with GLOBAL_TRACER.span("readback.join") as sp:
-        flat = [
-            jnp.ravel(a).astype(jnp.int64) for p in pending for a in p.arrays
-        ]
-        joined = flat[0] if len(flat) == 1 else _wave_join(*flat)
-        sp.set_tag("arrays", len(flat))
+        on_device = [a for a in arrays if isinstance(a, jax.Array)]
+        for a in on_device:
+            a.copy_to_host_async()
+        sp.set_tag("arrays", len(arrays))
     with GLOBAL_TRACER.span("readback.transfer") as sp:
-        joined = np.asarray(joined.block_until_ready())
-        sp.set_tag("bytes", int(joined.nbytes))
-    host, off = [], 0
-    for a in flat:
-        host.append(joined[off : off + a.size])
-        off += a.size
-    i = 0
+        # device_get: the explicit transfer (a transfer guard lets it
+        # through) and the sync the analyzer's rule sees by name
+        host = iter(jax.device_get(arrays))
+        sp.set_tag("bytes", sum(a.nbytes for a in on_device))
     for p in pending:
-        args = []
-        for a in p.arrays:
-            args.append(host[i].reshape(np.shape(a)))
-            i += 1
-        p.fetched = args
+        p.fetched = [
+            np.asarray(next(host)).astype(np.int64, copy=False)
+            for _ in p.arrays
+        ]
 
 
 def stack_token(idx) -> tuple:

@@ -120,9 +120,13 @@ def test_groupby_fused_matches_level_synchronous():
 
 def test_mixed_aggregate_wave_single_transfer(monkeypatch):
     """A request mixing Count/TopN/Sum/Min/Max/GroupBy resolves every
-    deferred aggregate in ONE device→host transfer (the _Pending wave):
-    each np.asarray is a full device round trip, so the wave count IS
-    the latency model."""
+    deferred aggregate in ONE readback wave (the _Pending wave): all
+    its arrays cross in one ``jax.device_get`` inside fetch_wave, whose
+    copies are in flight together, and no array crosses anywhere else
+    (a stray np.asarray on a device array is a round trip of its own),
+    so the wave count IS the latency model."""
+    import jax
+
     import pilosa_tpu.executor.executor as ex_mod
 
     h, cols, arows, brows, vals = _setup()
@@ -131,16 +135,22 @@ def test_mixed_aggregate_wave_single_transfer(monkeypatch):
          "Max(field=v) GroupBy(Rows(a), Rows(b))")
     expected = e.execute("g", q)
 
-    transfers = {"n": 0}
-    orig = ex_mod.np.asarray
+    stray, waves = {"n": 0}, []
+    orig, orig_get = ex_mod.np.asarray, jax.device_get
 
     def counting(x, *a, **k):
         if hasattr(x, "devices"):  # jax array -> host transfer
-            transfers["n"] += 1
+            stray["n"] += 1
         return orig(x, *a, **k)
 
+    def counting_get(x):
+        waves.append(len(x))
+        return orig_get(x)
+
     monkeypatch.setattr(ex_mod.np, "asarray", counting)
+    monkeypatch.setattr(jax, "device_get", counting_get)
     got = e.execute("g", q)
-    monkeypatch.setattr(ex_mod.np, "asarray", orig)
+    monkeypatch.undo()
     assert got == expected
-    assert transfers["n"] == 1, f"expected 1 readback wave, saw {transfers['n']}"
+    assert len(waves) == 1, f"expected 1 readback wave, saw {len(waves)}"
+    assert waves[0] >= 1 and stray["n"] == 0, (waves, stray)

@@ -276,13 +276,20 @@ def test_wave_readback_is_split_under_the_scheduler(stats):
         assert len(new["scheduler.readback"]) == len(new["readback.join"]) == 2
 
 
-def test_fetch_wave_two_pendings_one_join_one_transfer():
-    class Pending:
-        def __init__(self, arrays):
-            self.arrays, self.fetched = arrays, None
+class _FakePending:
+    def __init__(self, arrays):
+        self.arrays, self.fetched = arrays, None
 
-    a = Pending([jnp.arange(6, dtype=jnp.int32).reshape(2, 3)])
-    b = Pending([jnp.ones(4, jnp.int64), jnp.asarray(7, jnp.int64)])
+
+def test_fetch_wave_two_pendings_one_join_one_transfer():
+    big = 2**31 + 5  # a uint32 count the host cast must not wrap
+    a = _FakePending([jnp.arange(6, dtype=jnp.int32).reshape(2, 3)])
+    b = _FakePending([
+        jnp.ones(4, jnp.int64),
+        jnp.asarray(7, jnp.int64),
+        jnp.asarray([1, big], jnp.uint32),
+        np.arange(3, dtype=np.uint16),  # already on the host: nothing crosses
+    ])
     marks = len(_ring("readback.join")), len(_ring("readback.transfer"))
     with GLOBAL_TRACER.span("scheduler.readback") as rb:
         fetch_wave([a, b])
@@ -290,15 +297,105 @@ def test_fetch_wave_two_pendings_one_join_one_transfer():
     transfers = _ring("readback.transfer")[marks[1]:]
     assert len(joins) == len(transfers) == 1
     assert joins[0]["parentSpanID"] == transfers[0]["parentSpanID"] == rb.span_id
-    assert joins[0]["tags"]["arrays"] == 3
-    assert transfers[0]["tags"]["bytes"] == (6 + 4 + 1) * 8
+    assert joins[0]["tags"]["arrays"] == 5
+    # what crossed, in the arrays' own dtypes: int32[2,3], int64[4], int64[], uint32[2]
+    assert transfers[0]["tags"]["bytes"] == 6 * 4 + 4 * 8 + 8 + 2 * 4
+    for p in (a, b):
+        assert [f.shape for f in p.fetched] == [np.shape(x) for x in p.arrays]
+        assert all(type(f) is np.ndarray and f.dtype == np.int64 for f in p.fetched)
     assert a.fetched[0].tolist() == [[0, 1, 2], [3, 4, 5]]
     assert b.fetched[0].tolist() == [1] * 4 and int(b.fetched[1]) == 7
-    # one pending with one array: the same two spans, no join program
-    c = Pending([jnp.arange(3)])
+    assert b.fetched[2].tolist() == [1, big] and b.fetched[3].tolist() == [0, 1, 2]
+    # one pending with one array: the same two spans
+    c = _FakePending([jnp.arange(3)])
     fetch_wave([c])
     assert len(_ring("readback.join")) == marks[0] + 2
     assert c.fetched[0].tolist() == [0, 1, 2]
+
+
+def test_fetch_wave_compiles_nothing_for_new_sequences_of_sizes(stats):
+    """Settling a wave runs no XLA program: twenty sequences of result
+    sizes never seen before leave the compile counter (all sites) where
+    it was — the parent compiled one join per sequence."""
+    rng = np.random.default_rng(27)
+    waves = []
+    for k in range(20):
+        sizes = rng.integers(1, 40, size=2 + k % 5).tolist() + [100 + k]
+        waves.append([
+            _FakePending([jnp.arange(n, dtype=(jnp.int32, jnp.int64)[n % 2])])
+            for n in sizes
+        ])
+    jax.block_until_ready([p.arrays for w in waves for p in w])
+    before = _count(stats, "xla_compile_seconds"), _count(stats, "xla_lower_seconds")
+    for w in waves:
+        with GLOBAL_TRACER.span("scheduler.readback"):
+            fetch_wave(w)
+    assert (_count(stats, "xla_compile_seconds"), _count(stats, "xla_lower_seconds")) == before
+    assert 'site="readback.join"' not in stats.prometheus()
+    for w in waves:
+        for p in w:
+            assert p.fetched[0].dtype == np.int64
+            assert p.fetched[0].tolist() == list(range(p.arrays[0].size))
+
+
+def test_wave_of_two_queries_settles_without_a_compile(stats):
+    """Through the scheduler: one wave, one ``scheduler.readback`` with one
+    ``readback.join`` and one ``readback.transfer`` under it, and between
+    the dispatches' end and the answers no compile at any site."""
+    e, _ = _rig(stats)
+    sched = WaveScheduler(lambda: e, stats=stats, mode="always", window_us=200000,
+                          max_queries=2)
+    queries = ["TopN(f, n=3)", "Sum(Row(f=1), field=v)"]
+    want = [e.execute("t", q) for q in queries]  # compiles the dispatch programs
+    names = ("scheduler.wave", "scheduler.readback", "readback.join", "readback.transfer")
+    marks = {n: len(_ring(n)) for n in names}
+    before = _count(stats, "xla_compile_seconds")
+    got = [None, None]
+
+    def run(i):
+        got[i] = sched.execute("t", queries[i])
+
+    threads = [threading.Thread(target=run, args=(i,)) for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert got == want
+    assert _count(stats, "xla_compile_seconds") == before
+    new = {n: _ring(n)[marks[n]:] for n in names}
+    (wave,), (rb,) = new["scheduler.wave"], new["scheduler.readback"]
+    (join,), (transfer,) = new["readback.join"], new["readback.transfer"]
+    assert wave["tags"]["queries"] == 2 and wave["tags"]["reason"] == "full"
+    assert rb["tags"]["wave"] == wave["spanID"]
+    assert join["parentSpanID"] == transfer["parentSpanID"] == rb["spanID"]
+    assert join["tags"]["arrays"] == rb["tags"]["arrays"] == 4  # 1 + 3
+
+
+def test_fetch_wave_brings_mesh_results_back_whole():
+    """The mesh route's arrays live on several devices: a result sharded
+    over the mesh and one replicated on it come back whole, int64, and a
+    replicated one crosses once."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from pilosa_tpu.parallel.mesh import AXIS_SHARDS, make_mesh
+
+    devices = jax.devices()[:2]
+    assert len(devices) == 2  # conftest forces eight host devices
+    mesh = make_mesh(devices)
+    rows = np.arange(8 * 3, dtype=np.int32).reshape(8, 3)
+    sharded = jax.device_put(rows, NamedSharding(mesh, PartitionSpec(AXIS_SHARDS)))
+    replicated = jax.device_put(
+        np.asarray([5, 2**31 + 9], np.uint32), NamedSharding(mesh, PartitionSpec())
+    )
+    assert not sharded.is_fully_replicated and replicated.is_fully_replicated
+    assert len(sharded.addressable_shards) == len(replicated.addressable_shards) == 2
+    p = _FakePending([sharded, replicated, jnp.asarray(3, jnp.int64)])
+    mark = len(_ring("readback.transfer"))
+    fetch_wave([p])
+    assert [f.dtype for f in p.fetched] == [np.int64] * 3
+    assert p.fetched[0].tolist() == rows.tolist()
+    assert p.fetched[1].tolist() == [5, 2**31 + 9] and int(p.fetched[2]) == 3
+    assert _ring("readback.transfer")[mark]["tags"]["bytes"] == rows.nbytes + 2 * 4 + 8
 
 
 # --------------------------------------------------- (f) the programs' names
@@ -343,12 +440,11 @@ def test_module_level_programs_lower_under_their_names(prog, args, want):
 
 
 def test_wave_join_and_mesh_programs_lower_under_their_names():
+    """The wave's join is no program any more (ISSUE 27): the scheduler
+    has nothing to build one with; the mesh route's programs keep their names."""
     from pilosa_tpu.executor import scheduler
 
-    assert (
-        _module_name(scheduler._wave_join, np.zeros(2, np.int64), np.zeros(3, np.int64))
-        == "jit_pilosa_wave_join"
-    )
+    assert not {"_wave_join", "named_jit", "jnp"} & set(vars(scheduler))
     from pilosa_tpu.parallel.mesh import MeshQueryEngine, make_mesh
 
     eng = MeshQueryEngine(make_mesh(jax.devices()[:2]))

@@ -13,9 +13,10 @@ Sanctioned readback layer: modules under ``executor/`` and
 gather paths) — EXCEPT ``executor/scheduler.py``: the cross-query wave
 scheduler coordinates many requests' results, which is exactly where an
 accidental early sync would silently serialize every wave, so only its
-settlement function (``fetch_wave``, the one transfer a wave pays) is
-sanctioned, explicitly by name rather than by the directory it lives
-in.  Everywhere else, in any module that imports jax:
+settlement function (``fetch_wave``, the one settlement a wave pays:
+every result's copy started together, awaited once) is sanctioned,
+explicitly by name rather than by the directory it lives in.
+Everywhere else, in any module that imports jax:
 
 - ``.block_until_ready()`` and ``jax.device_get(...)`` are flagged
   unconditionally (they have no host-side meaning);
@@ -153,7 +154,7 @@ def check_readback(project: Project) -> list[Violation]:
                 and fn.name in SCHEDULER_SANCTIONED_FUNCS
             ):
                 # the named settlement layer: its syncs ARE the wave's
-                # one transfer. Mark its nodes seen so the module-scope
+                # one settlement. Mark its nodes seen so the module-scope
                 # walk doesn't re-report them.
                 seen.update(
                     id(n) for n in ast.walk(fn) if isinstance(n, ast.Call)
