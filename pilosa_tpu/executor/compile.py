@@ -425,7 +425,11 @@ class StackCache:
                 ) as packed:
                     stacked, max_rows = stack_view_matrices(view, shards)
                     packed.tags.update(rows=max_rows, bytes=int(stacked.nbytes))
-                with GLOBAL_TRACER.span("stack.upload", **packed.tags) as uploaded:
+                with GLOBAL_TRACER.span(
+                    "stack.upload",
+                    devices=self.mesh_ctx.n_devices if self.mesh_ctx else 1,
+                    **packed.tags,
+                ) as uploaded:
                     if self.mesh_ctx is not None:
                         dev = self.mesh_ctx.place_stack(stacked)
                     else:
@@ -1601,7 +1605,7 @@ class QueryCompiler:
         if mesh_ctx is not None and getattr(mesh_ctx, "n_devices", 1) > 1:
             from pilosa_tpu.parallel.mesh import MeshQueryEngine
 
-            self.mesh_engine = MeshQueryEngine(mesh_ctx.mesh)
+            self.mesh_engine = MeshQueryEngine(mesh_ctx.mesh, stats=stats)
 
     def device_scalars(self, values: list[int]):
         """Device-resident int32 operand vector, cached by VALUE.

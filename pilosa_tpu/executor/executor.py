@@ -391,6 +391,14 @@ class Executor:
             elapsed = time.perf_counter() - t0
             if route in ("host", "device", "mesh"):
                 self.router.record(route)
+                if (
+                    route == "device"
+                    and self.router.mode == "mesh"
+                    and self.compiler.mesh_engine is not None
+                ):
+                    # a pinned mesh route reports every read it handed
+                    # to the device path (mesh_fallbacks_total)
+                    self.compiler.mesh_engine.note_fallback()
                 if work > 0:
                     # feed the calibration: host samples refine host
                     # throughput/overhead, device/mesh samples their
@@ -530,12 +538,12 @@ class Executor:
         if self.router.mode != "auto":
             mode = self.router.mode
             if mode == "mesh" and not mesh_ok:
-                # fallback-annotated call type (parallel.mesh) or a
-                # replicate-only shape: the single-program device path
-                # serves it (still SPMD via the stacks' NamedSharding)
+                # fallback-annotated call type (parallel.mesh), a
+                # replicate-only shape or a tiered (over-budget) field:
+                # the single-program device path serves it (still SPMD
+                # via the stacks' NamedSharding). dispatch() counts each
+                # read served so; this spec may be cached
                 mode = "device"
-                if self.compiler.mesh_engine is not None:
-                    self.compiler.mesh_engine.note_fallback()
             return mode, work, mesh_ok, cold_words
         return (
             self.router.decide(
@@ -793,8 +801,7 @@ class Executor:
         # the route the router takes RIGHT NOW — same decision inputs
         # and memo path as _route, but WITHOUT re-running the residency
         # and mesh-supportability walks this function already did (and
-        # without _route's fallback-counter side effect, which counts
-        # real serving fallbacks only)
+        # nothing is counted: dispatch() counts real serving fallbacks)
         if self.router.mode != "auto":
             route = self.router.mode
             if route == "mesh" and not mesh_ok:
@@ -1275,7 +1282,10 @@ class Executor:
             # streamed (over-budget) path: chunk readbacks are the
             # streaming discipline itself, so it stays synchronous; the
             # filter materializes ONCE and is reused across every chunk
-            # (mesh route included — the stream IS the fallback)
+            # (mesh route included — the stream IS the fallback, and is
+            # counted as one)
+            if mesh:
+                self.compiler.mesh_engine.note_fallback()
             filt = self._filter_device(idx, call, shards)
             pairs = self._topn_chunked(
                 idx, field, shards, filt, ids=ids

@@ -260,7 +260,7 @@ class MeshQueryEngine:
       programs per structural key like every other program.
     """
 
-    def __init__(self, mesh: Mesh):
+    def __init__(self, mesh: Mesh, stats=None):
         self.mesh = mesh
         # observability (/debug/vars meshExecution): program builds and
         # per-program-family call counts; a plain dict under a lock —
@@ -269,6 +269,12 @@ class MeshQueryEngine:
         self.programs_built = 0
         self.calls: dict[str, int] = {}
         self.fallbacks = 0
+        # the same counts on /metrics (docs/spmd.md): the fallback
+        # family starts at 0 so a scrape shows it before the first one
+        self.stats = stats
+        if stats is not None:
+            stats.gauge("mesh_devices", self.n_devices)
+            stats.count("mesh_fallbacks_total", 0)
 
     @property
     def n_devices(self) -> int:
@@ -323,8 +329,11 @@ class MeshQueryEngine:
     @staticmethod
     def _psum_both(v):
         """The cross-chip reduction tree: words (minor/ICI) hop first,
-        then shards — the multi-node merge transforms' order, intra-mesh."""
-        return jax.lax.psum(jax.lax.psum(v, AXIS_WORDS), AXIS_SHARDS)
+        then shards — the multi-node merge transforms' order, intra-mesh.
+        Scoped ``pilosa.mesh_psum`` so the collectives carry a name in
+        the compiled program's op metadata and on the device trace."""
+        with jax.named_scope("pilosa.mesh_psum"):
+            return jax.lax.psum(jax.lax.psum(v, AXIS_WORDS), AXIS_SHARDS)
 
     def _spmd(
         self, kind: str, local, in_specs, out_specs, check_rep: bool = True
@@ -350,10 +359,15 @@ class MeshQueryEngine:
     def note_call(self, name: str) -> None:
         with self._stats_lock:
             self.calls[name] = self.calls.get(name, 0) + 1
+        if self.stats is not None:
+            self.stats.count("mesh_program_calls_total", tags={"program": name})
 
     def note_fallback(self) -> None:
+        """One read that a mesh route handed to the device path."""
         with self._stats_lock:
             self.fallbacks += 1
+        if self.stats is not None:
+            self.stats.count("mesh_fallbacks_total")
 
     def snapshot(self) -> dict:
         """Live view for /debug/vars (meshExecution)."""

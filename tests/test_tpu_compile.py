@@ -126,16 +126,16 @@ def record(monkeypatch, executor, pql: str) -> list[tuple]:
     return calls
 
 
-def real_size(args, sharding_of):
+def real_size(args, sharding_of, shards: int = S):
     """The recorded arguments as shapes at the smoke's real size:
-    trailing [S_TINY, W_test] plane dimensions become [S, W]."""
+    trailing [S_TINY, W_test] plane dimensions become [shards, W]."""
 
     def one(x):
         if not isinstance(x, (np.ndarray, jax.Array)):
             return x
         shape = tuple(x.shape)
         if shape[-2:] == (S_TINY, WORDS_PER_SHARD):
-            shape = shape[:-2] + (S, W)
+            shape = shape[:-2] + (shards, W)
         return jax.ShapeDtypeStruct(shape, x.dtype, sharding=sharding_of(shape))
 
     return jax.tree_util.tree_map(one, args)
@@ -265,34 +265,79 @@ def test_tiered_container_decode(one_chip):
     compile_and_fit(jax.jit(ops.containers.run_count), (runs,))
 
 
-def test_mesh_count_and_topn(rig, mesh):
-    """The shard_map Count and filtered-TopN builders on a 2×2 v5e mesh,
-    stacks partitioned along the shards axis."""
-    _h, idx, e = rig
-    engine = MeshQueryEngine(mesh)
-    shards = list(range(S_TINY))
+def placed_on(mesh):
+    """shape → the sharding the stack cache gives it on ``mesh``: planes
+    split along the shards axis, vectors of scalars replicated."""
 
     def placed(shape):
         spec = P(*(None,) * (len(shape) - 2), "shards", "words") if len(shape) > 1 else P()
         return NamedSharding(mesh, spec)
 
-    def plan(pql):
-        planner = query_compile._Planner(
-            idx, shards, e.compiler.stacks, block_shape=(S // 4, W)
-        )
-        run, _skey = planner.plan(parse(pql)[0])
-        arrays = planner.materialize()
-        scalars = np.asarray(planner.scalar_values(), dtype=np.int32)
-        return run, real_size((arrays, scalars), placed)
+    return placed
 
-    run, args = plan("Intersect(Row(cab_type=0), Row(passenger_count=1))")
+
+def mesh_plan(rig, placed, pql: str, real_shards: int):
+    """(planner closure traced against a chip's block, its arguments as
+    shapes at ``real_shards`` shards over the four chips)."""
+    _h, idx, e = rig
+    planner = query_compile._Planner(
+        idx, list(range(S_TINY)), e.compiler.stacks, block_shape=(real_shards // 4, W)
+    )
+    run, _skey = planner.plan(parse(pql)[0])
+    arrays = planner.materialize()
+    scalars = np.asarray(planner.scalar_values(), dtype=np.int32)
+    return run, real_size((arrays, scalars), placed, shards=real_shards)
+
+
+def test_mesh_count_and_topn(rig, mesh):
+    """The shard_map Count and filtered-TopN builders on a 2×2 v5e mesh,
+    stacks partitioned along the shards axis."""
+    engine = MeshQueryEngine(mesh)
+    placed = placed_on(mesh)
+
+    run, args = mesh_plan(rig, placed, "Intersect(Row(cab_type=0), Row(passenger_count=1))", S)
     compiled = compile_and_fit(engine.count_tree(run, "grid"), args, devices=4)
     assert "all-reduce" in compiled.as_text()  # the psum tree over chips
 
-    frun, (farrays, fscalars) = plan("Row(cab_type=1)")
+    frun, (farrays, fscalars) = mesh_plan(rig, placed, "Row(cab_type=1)", S)
     matrix = jax.ShapeDtypeStruct((8, S, W), np.uint32, sharding=placed((8, S, W)))
     compile_and_fit(
         engine.topn_tree("grid", True, False, frun=frun),
         (matrix, farrays, fscalars),
         devices=4,
     )
+
+
+# the cell taxi-512x4.four_queries (benchmark/configs/taxi-512x4.json):
+# 512 shards over the four chips, dist_miles a 32-row stack, the amount's
+# BSI block 19 slices after _bsi_stacked's pad
+S_CELL = 512
+
+
+@pytest.mark.parametrize("program", ["topn", "sum", "count"])
+def test_mesh_programs_at_the_four_chip_cells_shapes(rig, mesh, program):
+    """Q4's TopN over [32, 512, W] under a two-row filter, Q2's Sum over
+    [19, 512, W] under a one-row filter and Q3's Count of an Intersect
+    compile for the 4 x 1 v5e mesh, 128 shards a chip, and their psum
+    trees carry the scope the device trace is read by."""
+    engine = MeshQueryEngine(mesh)
+    placed = placed_on(mesh)
+    plan = lambda pql: mesh_plan(rig, placed, pql, S_CELL)
+
+    def stack(rows):
+        shape = (rows, S_CELL, W)
+        return jax.ShapeDtypeStruct(shape, np.uint32, sharding=placed(shape))
+
+    if program == "topn":
+        frun, (farrays, fscalars) = plan("Intersect(Row(cab_type=1), Row(passenger_count=2))")
+        prog = engine.topn_tree("grid", True, False, frun=frun)
+        args = (stack(32), farrays, fscalars)
+    elif program == "sum":
+        frun, (farrays, fscalars) = plan("Row(passenger_count=2)")
+        prog = engine.sum_tree(Executor._sum_fn, "grid", frun=frun)
+        args = (stack(19), farrays, fscalars)
+    else:
+        run, args = plan("Intersect(Row(cab_type=1), Row(passenger_count=2))")
+        prog = engine.count_tree(run, "grid")
+    text = compile_and_fit(prog, args, devices=4).as_text()
+    assert "all-reduce" in text and "pilosa.mesh_psum" in text
