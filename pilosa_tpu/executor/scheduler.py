@@ -68,6 +68,8 @@ BATCH_MODES = ("off", "adaptive", "always")
 # pure added latency (the c1-p50 guard bench.py enforces)
 _SOLO_OCCUPANCY = 1.25
 
+WAKEUP_REASONS = ("done", "lead", "again")
+
 
 def fetch_wave(pending: "list[_Pending]") -> None:
     """THE settlement layer — the one sanctioned device→host readback
@@ -161,6 +163,20 @@ def dedup_key(index: str, calls, shards, idx) -> tuple:
     )
 
 
+class _Waiter:
+    """What ONE submitting call sleeps on (``execute``: one item;
+    ``execute_many``: all its items, one thread).  ``event`` is set for
+    exactly two reasons, both under the scheduler's lock: the call's
+    last item completed (``pending`` reached 0), or the call was handed
+    the leadership of the next wave (``WaveScheduler._heir``)."""
+
+    __slots__ = ("event", "pending")
+
+    def __init__(self) -> None:
+        self.event = threading.Event()
+        self.pending = 0  # items of this call not completed yet
+
+
 class _WorkItem:
     __slots__ = (
         "index",
@@ -168,6 +184,7 @@ class _WorkItem:
         "shards",
         "routes",
         "key",
+        "waiter",
         "done",
         "raw",
         "pendings",
@@ -179,13 +196,16 @@ class _WorkItem:
         "sealed",
     )
 
-    def __init__(self, index: str, calls: list[Call], shards, routes=None):
+    def __init__(
+        self, index: str, calls: list[Call], shards, waiter, routes=None
+    ):
         self.index = index
         self.calls = calls
         self.shards = shards
         self.routes = routes  # per-call (route, work) from _batchable
         self.key: tuple | None = None
-        self.done = threading.Event()
+        self.waiter = waiter
+        self.done = False
         self.raw: list[Any] = []
         self.pendings: list[_Pending] = []
         self.results: list[Any] | None = None
@@ -232,18 +252,19 @@ class WaveScheduler:
         self.stats = stats
         self._clock = clock
         # contention-counted (docs/profiling.md): /debug/saturation's
-        # "scheduler" lock family.  NOTE: Condition.wait's re-acquire
-        # after notify counts as contention — that is real time a woken
-        # wave-mate spends waiting for the queue lock, not noise.
+        # "scheduler" lock family.
         self._lock = sanitize.make_lock(
             "WaveScheduler._lock", inner=saturation.ContendedLock("scheduler")
         )
-        # one condition over the queue/leadership state: enqueues and
-        # wave completions notify; waiting submitters contend to lead
-        self._cond = threading.Condition(self._lock)
+        # the ONE thread that ever waits for arrivals is the leader in
+        # its window; every other waiter sleeps on its own _Waiter.event
+        self._arrival = threading.Condition(self._lock)
         self._queue: deque[_WorkItem] = deque()
         self._inflight: dict[tuple, _WorkItem] = {}
+        # True while a leader runs its wave AND across a hand-over (the
+        # heir is woken, not yet running): queue non-empty ⇒ True
         self._leader_active = False
+        self._heir: _Waiter | None = None
         # lazy pool for execute_many's DIRECT (host-routed) entries:
         # the multi-query RPC coalesces legs before the remote routing
         # decision is known, so host-routed legs that used to arrive as
@@ -254,6 +275,13 @@ class WaveScheduler:
         self.batched_queries = 0
         self.deduped_queries = 0
         self.direct_queries = 0
+        # returns of a waiter's event.wait(), by what it found: its call
+        # done, the leadership handed to it, or neither ("again": must
+        # stay 0 — nothing wakes a thread that has nothing to do)
+        self.wakeups = dict.fromkeys(WAKEUP_REASONS, 0)
+        if stats is not None:
+            for why in WAKEUP_REASONS:
+                stats.count("scheduler_wakeups_total", 0, tags={"why": why})
 
     # ------------------------------------------------------------- entry
     def execute(
@@ -288,24 +316,16 @@ class WaveScheduler:
             with self._lock:
                 self.direct_queries += 1
             return executor.execute(index, calls, shards=shards, routes=routes)
-        item = _WorkItem(index, calls, shards, routes=routes)
+        waiter = _Waiter()
+        item = _WorkItem(index, calls, shards, waiter, routes=routes)
         item.key = dedup_key(index, calls, shards, idx)
         item.trace_ctx = GLOBAL_TRACER.current_context()
         item.profile = tracing.current_profile()
-        joined = False
-        with self._cond:
-            prime = self._inflight.get(item.key)
-            if prime is not None and not prime.sealed:
-                prime.followers.append(item)
-                self.deduped_queries += 1
-                joined = True
-            else:
-                self._inflight[item.key] = item
-                self._queue.append(item)
-                self._cond.notify_all()
-        if joined and self.stats is not None:
+        with self._lock:
+            lead, deduped = self._enqueue([item])
+        if deduped and self.stats is not None:
             self.stats.count("queries_deduped")
-        self._await(item)
+        self._await(waiter, lead)
         if item.error is not None:
             raise item.error
         return item.results  # type: ignore[return-value]
@@ -325,6 +345,7 @@ class WaveScheduler:
         every leg)."""
         executor = self._executor_fn()
         out: list[Any] = [None] * len(requests)
+        waiter = _Waiter()
         wave_items: list[tuple[int, _WorkItem]] = []
         futures: list[tuple[int, Any]] = []
 
@@ -370,7 +391,7 @@ class WaveScheduler:
                             )
                         )
                     continue
-                item = _WorkItem(index, calls, shards, routes=_routes)
+                item = _WorkItem(index, calls, shards, waiter, routes=_routes)
                 item.key = dedup_key(index, calls, shards, idx)
                 item.trace_ctx = ctx
                 wave_items.append((i, item))
@@ -378,22 +399,12 @@ class WaveScheduler:
                 # a parse/validation failure answers its own slot only
                 out[i] = e
         if wave_items:
-            deduped = 0
-            with self._cond:
-                for _i, item in wave_items:
-                    prime = self._inflight.get(item.key)
-                    if prime is not None and not prime.sealed:
-                        prime.followers.append(item)
-                        self.deduped_queries += 1
-                        deduped += 1
-                    else:
-                        self._inflight[item.key] = item
-                        self._queue.append(item)
-                self._cond.notify_all()
+            with self._lock:
+                lead, deduped = self._enqueue([it for _i, it in wave_items])
             if deduped and self.stats is not None:
                 self.stats.count("queries_deduped", deduped)
+            self._await(waiter, lead)
             for i, item in wave_items:
-                self._await(item)
                 out[i] = item.error if item.error is not None else item.results
         for i, fut in futures:
             out[i] = fut.result()  # run_direct never raises
@@ -454,30 +465,97 @@ class WaveScheduler:
         # share, so queueing would only add latency (docs/query-batching.md)
         return any_device, routes
 
-    def _await(self, item: _WorkItem) -> None:
-        """Block until ``item`` completes — contending for wave
-        leadership while waiting.  A leader runs exactly ONE wave and
-        then releases leadership (waking the next contender): without
-        the handoff, the first arrival would keep serving everyone
+    def _enqueue(self, items: "list[_WorkItem]") -> "tuple[bool, int]":
+        """Called holding ``_lock``, with all the items of one call
+        (one waiter).  Each item joins an identical unsealed in-flight
+        prime as its follower, or goes on the queue.  Returns (lead,
+        deduped): ``lead`` says the caller found no leader and IS now
+        the leader — it runs the next wave at once, with no wake-up in
+        between (the solo path never sleeps).  With a leader there, the
+        only thread an arrival can matter to is that leader in its
+        window: one notify, for at most one sleeper."""
+        waiter = items[0].waiter
+        waiter.pending += len(items)
+        deduped = 0
+        for item in items:
+            prime = self._inflight.get(item.key)
+            if prime is not None and not prime.sealed:
+                prime.followers.append(item)
+                deduped += 1
+            else:
+                self._inflight[item.key] = item
+                self._queue.append(item)
+        self.deduped_queries += deduped
+        if deduped == len(items):
+            return False, deduped  # nothing queued: its primes' waves serve it
+        if self._leader_active:
+            self._arrival.notify()
+            return False, deduped
+        self._leader_active = True
+        return True, deduped
+
+    def _await(self, waiter: _Waiter, lead: bool) -> None:
+        """Block until every item of ``waiter``'s call completed,
+        leading a wave whenever the leadership is this call's.
+
+        Who sleeps on what: every submitting call on its own
+        ``waiter.event``; the leader in its window, alone, on
+        ``_arrival``.  Who wakes whom: ``_finish`` sets the event of a
+        call whose last item it completed; a leader that releases with
+        work queued sets the event of the queue's head, the oldest
+        waiter, and of nobody else.  So an ``event.wait()`` returns
+        exactly when there is something for this thread to do.
+
+        A leader runs exactly ONE wave and then hands the leadership
+        on: if it kept it, the first arrival would serve everyone
         else's waves while its own finished response sat undelivered —
         measured as c8 throughput BELOW c1 on the first cut of this
-        scheduler."""
+        scheduler.  (It leads again only while it heads the queue
+        itself: an ``execute_many`` call larger than a wave.)"""
         with GLOBAL_TRACER.span("scheduler.await"):
             while True:
-                with self._cond:
-                    while not item.done.is_set() and (
-                        self._leader_active or not self._queue
-                    ):
-                        self._cond.wait()
-                    if item.done.is_set():
-                        return
-                    self._leader_active = True
-                try:
-                    self._run_one_wave()
-                finally:
-                    with self._cond:
-                        self._leader_active = False
-                        self._cond.notify_all()
+                while lead:
+                    try:
+                        self._run_one_wave()
+                    finally:
+                        lead = self._release(waiter)
+                if not waiter.pending:
+                    return
+                waiter.event.wait()
+                # under the lock, which every set() is made under too:
+                # a set between the wake-up and this clear is not lost,
+                # because what it announced is read after the clear
+                with self._lock:
+                    waiter.event.clear()
+                    lead = self._heir is waiter
+                    if lead:
+                        self._heir = None
+                        why = "lead"
+                    else:
+                        why = "again" if waiter.pending else "done"
+                    self.wakeups[why] += 1
+                if self.stats is not None:
+                    self.stats.count(
+                        "scheduler_wakeups_total", tags={"why": why}
+                    )
+
+    def _release(self, waiter: _Waiter) -> bool:
+        """End of the leader's one wave.  Nothing queued: nobody leads,
+        the next enqueuer will.  Else the leadership goes to the call
+        of the queue's head — the oldest item no wave has taken — by
+        setting that one event; ``_leader_active`` stays set across the
+        hand-over, so no enqueuer takes the lead meanwhile.  True when
+        the head is the releasing call's own."""
+        with self._lock:
+            if not self._queue:
+                self._leader_active = False
+                return False
+            heir = self._queue[0].waiter
+            if heir is waiter:
+                return True
+            self._heir = heir
+            heir.event.set()
+            return False
 
     def _run_one_wave(self) -> None:
         # resolve the executor AT WAVE TIME, not from whatever instance
@@ -485,9 +563,9 @@ class WaveScheduler:
         # attach swaps API.executor, and a wave led across the swap must
         # dispatch on the NEW engine (the whole point of executor_fn)
         executor = self._executor_fn()
-        with self._cond:
-            if not self._queue:
-                return
+        with self._lock:
+            # never empty: a leader is made by its own enqueue, or by a
+            # hand-over to the head of a queue only leaders drain
             batch = [self._queue.popleft()]
             while self._queue and len(batch) < self.max_queries:
                 batch.append(self._queue.popleft())
@@ -502,11 +580,11 @@ class WaveScheduler:
         except Exception as e:  # noqa: BLE001 — harness backstop: a
             # failure OUTSIDE the per-query isolation paths must
             # still wake every waiter, or their HTTP threads hang
-            for it in batch:
-                if not it.done.is_set():
-                    self._finish(
-                        it, error=ExecutionError(f"wave failed: {e!r}")
-                    )
+            # (_finish passes over what is already completed)
+            error = ExecutionError(f"wave failed: {e!r}")
+            with self._lock:
+                for it in batch:
+                    self._finish(it, error=error)
 
     def _wait_window(self, executor, batch: list[_WorkItem]) -> str:
         """First-arrival opened the window when the leader drained it;
@@ -519,7 +597,7 @@ class WaveScheduler:
             return "drain" if len(batch) > 1 else "solo"
         deadline = self._clock() + eff
         while len(batch) < self.max_queries:
-            with self._cond:
+            with self._lock:
                 if not self._queue:
                     remaining = deadline - self._clock()
                     if remaining <= 0:
@@ -534,8 +612,9 @@ class WaveScheduler:
     def _wait_arrival(self, timeout: float) -> None:
         """Injectable for tests (fake clocks drive the window loop
         deterministically by pairing a scripted clock with a no-op
-        wait).  Called holding ``_cond``; woken by enqueues."""
-        self._cond.wait(timeout)
+        wait).  Called holding ``_lock``, by the leader in its window
+        alone; woken by the next enqueue."""
+        self._arrival.wait(timeout)
 
     def _window_seconds(self, executor, have: int) -> float:
         from pilosa_tpu.parallel.resilience import current_deadline
@@ -604,7 +683,8 @@ class WaveScheduler:
                     settled.append(it)
                 except Exception as e:  # noqa: BLE001 — error isolation:
                     # one bad query errors alone; wave-mates proceed
-                    self._finish(it, error=e)
+                    with self._lock:
+                        self._finish(it, error=e)
             all_pending = [p for it in settled for p in it.pendings]
             joint_ok = True
             fetch_seconds = 0.0
@@ -617,6 +697,11 @@ class WaveScheduler:
                     # readback falls back to per-query fetches below so
                     # only the poisoned query errors
                     joint_ok = False
+            # settle every query outside the lock (its own work, its
+            # own errors), then complete them all in ONE pass under one
+            # acquisition: the wave's waiters are woken together, by one
+            # holder of the lock instead of one a query in turn
+            outcomes: list[tuple] = []
             for it in settled:
                 try:
                     if not joint_ok and it.pendings:
@@ -625,31 +710,33 @@ class WaveScheduler:
                         )
                     for p in it.pendings:
                         p.resolve_fetched()
-                    wave_info = {
-                        "queries": n,
-                        "shared": 1 + len(it.followers),
-                        "flushReason": reason,
-                    }
-                    if it.profile is not None:
-                        if it.pendings:
-                            # the shared transfer's cost, attributed to
-                            # every sharing query (?profile=true keeps
-                            # its _readback line; the wave dict tells
-                            # the reader it was amortized)
-                            it.profile.add_call(
-                                "_readback", fetch_seconds, None
-                            )
-                        it.profile.wave = wave_info
-                    self._finish(
-                        it,
-                        results=finalize(it.raw),
-                        readback=fetch_seconds if it.pendings else None,
-                        wave=wave_info,
+                    outcomes.append(
+                        (
+                            it,
+                            finalize(it.raw),
+                            None,
+                            fetch_seconds if it.pendings else None,
+                        )
                     )
                 except Exception as e:  # noqa: BLE001 — per-query
                     # isolation at settle: a finish() failure (bad
                     # attr, overflow) errors its own query only
-                    self._finish(it, error=e)
+                    outcomes.append((it, None, e, None))
+            with self._lock:
+                for it, results, error, readback in outcomes:
+                    self._finish(
+                        it,
+                        results=results,
+                        error=error,
+                        readback=readback,
+                        # under the lock that seals it: "shared" counts
+                        # every follower this execution answers
+                        wave={
+                            "queries": n,
+                            "shared": 1 + len(it.followers),
+                            "flushReason": reason,
+                        },
+                    )
         # final occupancy: every prime plus every follower it fanned
         # out to (followers can no longer join — all items sealed)
         n = len(batch) + sum(len(it.followers) for it in batch)
@@ -679,29 +766,36 @@ class WaveScheduler:
         readback: float | None = None,
         wave: dict | None = None,
     ) -> None:
-        with self._cond:
-            item.sealed = True
-            if self._inflight.get(item.key) is item:
-                del self._inflight[item.key]
-            followers = list(item.followers)
-            item.results = results
-            item.error = error
-            item.done.set()
-            for f in followers:
-                if f.profile is not None:
-                    # dedup followers shared the prime's execution: their
-                    # ?profile=true response still documents the wave
-                    # (the docs promise the wave section for every
-                    # sharing query) — stamped BEFORE done.set(), which
-                    # releases the follower's thread to serialize it
-                    if readback is not None:
-                        f.profile.add_call("_readback", readback, None)
-                    if wave is not None:
-                        f.profile.wave = dict(wave)
-                f.results = results
-                f.error = error
-                f.done.set()
-            self._cond.notify_all()
+        """Called holding ``_lock``: seal ``item`` (no follower joins
+        from here on) and complete it and its dedup followers, each
+        once — an item already completed is passed over, so a second
+        call (the backstop, a failure half-way) reaches only what the
+        first left.  Wakes the call whose last item this was, and
+        nobody else."""
+        item.sealed = True
+        if self._inflight.get(item.key) is item:
+            del self._inflight[item.key]
+        for it in (item, *item.followers):
+            if it.done:
+                continue
+            if it.profile is not None:
+                # every sharing query's ?profile=true response documents
+                # the wave (docs/query-batching.md), dedup followers
+                # too: the shared transfer's cost on a _readback line,
+                # and the wave dict that tells the reader it was
+                # amortized — stamped BEFORE the waiter is released to
+                # serialize it
+                if readback is not None:
+                    it.profile.add_call("_readback", readback, None)
+                if wave is not None:
+                    it.profile.wave = dict(wave)
+            it.results = results
+            it.error = error
+            it.done = True
+            waiter = it.waiter
+            waiter.pending -= 1
+            if not waiter.pending:
+                waiter.event.set()
 
     # ------------------------------------------------------ observability
     def snapshot(self) -> dict:
@@ -709,6 +803,7 @@ class WaveScheduler:
         with self._lock:
             waves, batched = self.waves, self.batched_queries
             deduped, direct = self.deduped_queries, self.direct_queries
+            wakeups = dict(self.wakeups)
         return {
             "mode": self.mode,
             "windowUs": self.window_s * 1e6,
@@ -718,4 +813,5 @@ class WaveScheduler:
             "dedupedQueries": deduped,
             "directQueries": direct,
             "meanQueriesPerWave": (batched / waves) if waves else 0.0,
+            "wakeups": wakeups,
         }

@@ -58,6 +58,7 @@ _METRIC_HELP = {
     "queries_routed": "read calls per engine picked by the cost router",
     "queries_served": "read legs this node executed",
     "queries_deduped": "queries answered by single-flight dedup",
+    "scheduler_wakeups_total": "wake-ups of the wave scheduler's waiters, by reason",
     "queries_partial": "queries answered with partial results",
     "queries_rejected": "requests shed by admission control",
     "queries_per_wave": "occupancy of cross-query device waves",

@@ -16,6 +16,7 @@ Pillars:
 """
 
 import json
+import sys
 import threading
 import time
 
@@ -537,3 +538,280 @@ def test_cluster_concurrent_queries_coalesce_legs(tmp_path):
         assert got == [expect] * 12
     finally:
         shutdown(servers)
+
+
+# ------------------------------------------------- the waiting protocol
+# (ISSUE 29) every submitting call sleeps on its own event; _finish
+# wakes the call it completed, a releasing leader wakes the queue's
+# head, an enqueue wakes at most the leader in its window.  The stub
+# stands in for the Executor so the tests count wake-ups and plant
+# failures at no JAX cost.
+class _StubRouter:
+    wave_occupancy = None
+
+    class readback_s:  # noqa: N801 — mirrors the router's EWMA attribute
+        value = 0.001
+
+    def observe_wave(self, n):
+        pass
+
+
+class StubExecutor:
+    """Exactly what WaveScheduler touches of an Executor.  A query
+    answers with its own text; one that names the field ``boom`` fails
+    at dispatch.  ``dispatch_s`` sleeps with the interpreter lock
+    released, as a device launch does."""
+
+    class _Index:
+        fields: dict = {}
+
+    def __init__(self, dispatch_s=0.0002):
+        self.holder = self
+        self.router = _StubRouter()
+        self.dispatch_s = dispatch_s
+        self.led_by: list[str] = []  # the thread of every dispatch
+
+    def index(self, name):
+        return self._Index
+
+    def _route(self, idx, call, shards):
+        return ("device", 1)
+
+    def dispatch(self, index, calls, shards, routes=None):
+        self.led_by.append(threading.current_thread().name)
+        if self.dispatch_s:
+            time.sleep(self.dispatch_s)
+        text = [str(c) for c in calls]
+        if "boom" in text[0]:
+            raise ValueError(f"planted: {text[0]}")
+        return text
+
+
+def stub_rig(**sched_kw):
+    e = StubExecutor()
+    stats = StatsClient()
+    sched = WaveScheduler(lambda: e, stats=stats, **sched_kw)
+    return e, sched, stats
+
+
+def run_threads(n, body, limit_s=60.0):
+    """n threads, each with a time limit of its own: a thread still
+    alive after it is a lost wake-up."""
+    errors: list = []
+
+    def guarded(k):
+        try:
+            body(k)
+        except Exception as exc:  # noqa: BLE001 — surfaced below
+            errors.append((k, exc))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # more threads than cores, switching often
+    try:
+        ts = [
+            threading.Thread(target=guarded, args=(k,), daemon=True, name=f"c{k}")
+            for k in range(n)
+        ]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(limit_s)
+        hung = [t.name for t in ts if t.is_alive()]
+    finally:
+        sys.setswitchinterval(old)
+    assert not hung, f"calls that never returned: {hung}"
+    assert not errors, errors
+
+
+@pytest.mark.parametrize(
+    "threads,per_thread,max_queries", [(16, 40, 64), (8, 60, 3), (32, 15, 8)]
+)
+def test_wakeup_budget(threads, per_thread, max_queries):
+    """Nothing wakes a thread that has nothing to do: no wake-up finds
+    neither its call done nor the leadership (``again`` 0), and there
+    are at most one a query (``done``) and one a wave (``lead``)."""
+    _e, sched, stats = stub_rig(max_queries=max_queries)
+
+    def body(k):
+        for j in range(per_thread):
+            pql = f"Count(Row(f={k * 1000 + j}))"  # all distinct: no dedup
+            assert sched.execute("b", pql) == [pql]
+
+    run_threads(threads, body)
+    snap = sched.snapshot()
+    wakeups = snap["wakeups"]
+    queries = threads * per_thread
+    assert snap["batchedQueries"] == queries
+    assert wakeups["again"] == 0, wakeups
+    assert wakeups["done"] <= queries
+    assert wakeups["lead"] <= snap["waves"]
+    assert wakeups["done"] + wakeups["lead"] <= queries + snap["waves"]
+    # the leader of a wave completes its own query without sleeping
+    assert wakeups["done"] >= queries - snap["waves"]
+    counters = stats.expvar()["counters"]
+    for why, n in wakeups.items():
+        assert counters.get(f"scheduler_wakeups_total{{why={why}}}") == n
+
+
+@pytest.mark.parametrize(
+    "case", ["plain", "dedup", "one_query_raises", "wave_raises", "execute_many"]
+)
+def test_no_lost_wakeup(case):
+    """32 threads: every call returns its own answer or raises its own
+    error, inside its time limit, whatever fails around it."""
+    _e, sched, _stats = stub_rig(
+        max_queries=3 if case == "execute_many" else 8
+    )
+    per_thread = 12
+    if case == "wave_raises":
+        real = sched._execute_wave
+        waves = []
+
+        def every_third(executor, batch, reason):
+            waves.append(1)
+            if len(waves) % 3 == 0:
+                raise RuntimeError("planted wave failure")
+            return real(executor, batch, reason)
+
+        sched._execute_wave = every_third
+    outcomes = {"ok": 0, "raised": 0}
+    tally = threading.Lock()
+
+    def note(got, pql):
+        if isinstance(got, Exception):
+            assert "planted" in str(got), got
+            # a planted query fails alone; a planted wave fails its batch
+            assert "boom" in pql or case == "wave_raises", (pql, got)
+            key = "raised"
+        else:
+            assert got == [pql], (got, pql)
+            key = "ok"
+        with tally:
+            outcomes[key] += 1
+
+    def body(k):
+        for j in range(per_thread):
+            if case == "dedup":
+                pqls = [f"Count(Row(f={j % 2}))"]  # 32 threads, 2 texts
+            elif case == "one_query_raises" and (k + j) % 5 == 0:
+                pqls = [f"Count(Row(boom={k * 100 + j}))"]
+            elif case == "execute_many":
+                # five items on one thread, over two waves of three,
+                # two of them identical (a follower on its own waiter)
+                pqls = [f"Count(Row(f={k * 1000 + j * 10 + i % 4}))" for i in range(5)]
+                pqls[3] = f"Count(Row(boom={k * 1000 + j}))"
+            else:
+                pqls = [f"Count(Row(f={k * 100 + j}))"]
+            if case == "execute_many":
+                out = sched.execute_many([("b", p, None, None) for p in pqls])
+            else:
+                try:
+                    out = [sched.execute("b", pqls[0])]
+                except Exception as exc:  # noqa: BLE001 — the call's answer
+                    out = [exc]
+            assert len(out) == len(pqls)
+            for got, pql in zip(out, pqls):
+                note(got, pql)
+
+    run_threads(32, body)
+    items = 5 if case == "execute_many" else 1
+    assert sum(outcomes.values()) == 32 * per_thread * items
+    assert outcomes["ok"] > 0
+    if case in ("one_query_raises", "wave_raises", "execute_many"):
+        assert outcomes["raised"] > 0
+    snap = sched.snapshot()
+    assert snap["wakeups"]["again"] == 0, snap
+    if case == "dedup":
+        assert snap["dedupedQueries"] > 0
+    # nothing left behind: no queue, no in-flight prime, no leader
+    assert not sched._queue and not sched._inflight
+    assert not sched._leader_active and sched._heir is None
+
+
+@pytest.mark.parametrize(
+    "max_queries,want",
+    [
+        (1, ["A", "B", "C", "D"]),  # each heir leads its own wave
+        (2, ["A", "B", "B", "D"]),  # B takes C along; D is the next head
+        (64, ["A", "B", "B", "B"]),
+    ],
+)
+def test_handoff_goes_to_the_oldest_queued(max_queries, want):
+    """A releases with B, C, D queued in that order: the leadership
+    goes to B, the head, and a wave's dispatches run on its leader."""
+    e, sched, _stats = stub_rig(max_queries=max_queries)
+    e.dispatch_s = 0
+    gate = threading.Event()
+    entered = threading.Event()
+    plain = e.dispatch
+
+    def gated(index, calls, shards, routes=None):
+        if threading.current_thread().name == "A" and not entered.is_set():
+            entered.set()
+            assert gate.wait(30)
+        return plain(index, calls, shards, routes=routes)
+
+    e.dispatch = gated
+    got: dict = {}
+
+    def call(name):
+        got[name] = sched.execute("b", f"Count(Row(f={ord(name)}))")
+
+    ts = {n: threading.Thread(target=call, args=(n,), daemon=True, name=n) for n in "ABCD"}
+    ts["A"].start()
+    assert entered.wait(30)  # A leads, mid-dispatch
+    for depth, n in enumerate("BCD", start=1):
+        ts[n].start()
+        deadline = time.monotonic() + 30
+        while len(sched._queue) < depth:  # queued before the next starts
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+    gate.set()
+    for t in ts.values():
+        t.join(30)
+        assert not t.is_alive()
+    assert e.led_by == want
+    assert got == {n: [f"Count(Row(f={ord(n)}))"] for n in "ABCD"}
+    wakeups = sched.snapshot()["wakeups"]
+    # one hand-over a wave after the first; a led query needs no "done"
+    waves = len(set(want))
+    assert wakeups == {"lead": waves - 1, "done": 4 - waves, "again": 0}
+
+
+@pytest.mark.parametrize(
+    "entry,items", [("execute", 1), ("execute_many", 1), ("execute_many", 3)]
+)
+def test_solo_client_never_sleeps(entry, items, monkeypatch):
+    """One client: its call finds no leader, leads at once and returns,
+    with no wait on its event and no wake-up counted (and, one query a
+    call, no window: the c1 latency guard)."""
+    from pilosa_tpu.executor import scheduler as sched_mod
+
+    class NoSleep(threading.Event):
+        def wait(self, timeout=None):
+            raise AssertionError("a solo client slept on its event")
+
+    class Waiter(sched_mod._Waiter):
+        __slots__ = ()
+
+        def __init__(self):
+            super().__init__()
+            self.event = NoSleep()
+
+    monkeypatch.setattr(sched_mod, "_Waiter", Waiter)
+    _e, sched, _stats = stub_rig()
+    windows: list = []
+    sched._wait_arrival = windows.append
+    for j in range(5):
+        pqls = [f"Count(Row(f={j * 10 + i}))" for i in range(3)]
+        if entry == "execute":
+            assert sched.execute("b", pqls[0]) == [pqls[0]]
+        else:
+            out = sched.execute_many(
+                [("b", p, None, None) for p in pqls[:items]]
+            )
+            assert out == [[p] for p in pqls[:items]]
+    assert items > 1 or windows == []
+    snap = sched.snapshot()
+    assert snap["wakeups"] == {"done": 0, "lead": 0, "again": 0}
+    assert snap["waves"] == 5

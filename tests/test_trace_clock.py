@@ -99,6 +99,16 @@ def _ring(name):
     return [s for s in GLOBAL_TRACER.recent(4096) if s["name"] == name]
 
 
+@pytest.fixture(autouse=True)
+def empty_ring():
+    """The tests below count a span's occurrences in the ring before and
+    after.  A ring that earlier tests of this worker filled (4096 spans)
+    drops an old span for every new one, and the counts stand still: so
+    every test starts with room."""
+    with GLOBAL_TRACER._lock:
+        GLOBAL_TRACER._spans.clear()
+
+
 @pytest.fixture
 def stats():
     """A fresh registry behind the process-wide compile listener."""
