@@ -59,7 +59,7 @@ class Holder:
         # fragment-count floor below which open() loads serially even
         # with workers configured: at small counts the pool's thread
         # spin-up + future machinery COSTS more than it overlaps
-        # (BENCH_INGEST_r08 measured parallel 0.159s vs serial 0.066s
+        # (a 1-core CPU run measured parallel 0.159s vs serial 0.066s
         # over 12 fragments)
         self.load_min_fragments = load_min_fragments
         self.compactor = Compactor(workers=compaction_workers, stats=stats)

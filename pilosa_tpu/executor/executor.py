@@ -738,8 +738,8 @@ class Executor:
         query: "str | Call | list[Call]",
         shards: list[int] | None = None,
     ) -> str:
-        """The route a query's first call would take right now — the
-        reporting hook bench.py/bench_all.py stamp into their rows."""
+        """The route ("host", "device", "mesh" or "write") a query's
+        first call would take right now, without running it."""
         idx = self.holder.index(index_name)
         if idx is None:
             raise ExecutionError(f"index {index_name!r} not found")

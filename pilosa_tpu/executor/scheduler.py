@@ -65,7 +65,7 @@ BATCH_MODES = ("off", "adaptive", "always")
 
 # adaptive window opens only once waves actually coalesce: below this
 # occupancy EWMA the traffic is effectively solo and the window would be
-# pure added latency (the c1-p50 guard bench.py enforces)
+# pure added latency at c1
 _SOLO_OCCUPANCY = 1.25
 
 WAKEUP_REASONS = ("done", "lead", "again")

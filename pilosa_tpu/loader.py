@@ -11,9 +11,9 @@ Retry-After compaction-debt admission gate (the retry IS the protocol:
 the server sheds load when durability can't keep up, the loader paces
 itself to it).
 
-Used by ``pilosa_tpu import --roaring``, by ``bench_all.py``'s
-sustained-ingest row and by ``chip_smoke.py`` (which builds its frames
-from dense rows and hands them to ``stream_frames``); the public entry
+Used by ``pilosa_tpu import --roaring`` and by ``chip_smoke.py`` (which
+builds its frames from dense rows and hands them to ``stream_frames``);
+the public entry
 points are ``parse_records``, ``bulk_load`` and ``stream_frames``.
 """
 

@@ -1,9 +1,8 @@
 """Event-driven HTTP front end: asyncio accept/read/write loop.
 
-Replaces the thread-per-request ``ThreadingHTTPServer`` stack that
-plateaued at c32 (BENCH_SWEEP_r06_cpu: sync_count_qps_c32 = 0.88x c1 —
-parked OS threads + a connect-storm-sized accept backlog).  Design
-(docs/serving.md):
+A thread-per-request listener plateaued at c32 (a 1-core CPU run:
+sync_count_qps_c32 = 0.88x c1 — parked OS threads + a connect-storm-
+sized accept backlog).  Design (docs/serving.md):
 
 - ONE event-loop thread owns all socket I/O: accept, HTTP/1.1 head/body
   reads with keep-alive multiplexing, slow-client timeouts, and response
@@ -26,8 +25,8 @@ parked OS threads + a connect-storm-sized accept backlog).  Design
 
 The event loop itself must never block: no socket/file I/O, no
 ``time.sleep``, no thread spawns inside coroutines — the ``asyncpurity``
-analyzer rule enforces this, with ``run_in_executor`` as the one
-sanctioned hand-off to blocking code.
+analyzer rule enforces this, with the worker pool as the one sanctioned
+hand-off to blocking code (the callable is passed, not called).
 """
 
 from __future__ import annotations
@@ -40,7 +39,7 @@ import re
 import socket
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor
 
 from pilosa_tpu import __version__
 from pilosa_tpu.parallel import resilience
@@ -60,6 +59,11 @@ STREAM_BUFFER_BYTES = 1 << 20
 # this needs no per-deployment knob — the PR 4 request_queue_size=128
 # band-aid is gone
 LISTEN_BACKLOG = 1024
+
+# seconds a shutdown gives connections that owe a reply (``_drain``);
+# ``shutdown()`` raises when it was not enough.  A constant, not an
+# option: a healthy close never comes near it
+SHUTDOWN_GRACE_S = 10.0
 
 _CLASS_QUERY = "query"
 _CLASS_WRITE = "write"
@@ -128,15 +132,37 @@ class _ConnState:
         self.since = time.monotonic()
 
 
+_PHASES = ("idle", "head", "body", "busy")  # by _ConnState.phase, for logs
+
+
+def _flushed(transport) -> bool:
+    try:
+        return transport.get_write_buffer_size() == 0
+    except AttributeError:
+        return True  # a TLS transport already torn down has no buffer
+
+
+def _hang_up(transport) -> None:
+    """Close a connection that is owed no new reply.  What is flushed
+    goes at once, by ``abort()``: a TLS ``close()`` waits for the peer's
+    close_notify, which an idle client never sends.  The tail of a reply
+    that a slow reader has not taken yet is left to ``close()``, which
+    flushes it first."""
+    if _flushed(transport):
+        transport.abort()
+    else:
+        transport.close()
+
+
 class _BufferedHandler(Handler):
     """One fully-read request executed against in-memory files.
 
     The event loop owns the real socket; a worker thread runs this shim,
     which re-parses the raw request through ``BaseHTTPRequestHandler``
-    machinery (one parser, identical semantics to the threaded path) and
-    dispatches through the unchanged ``Handler`` route table.  The
-    response accumulates in ``wfile`` (a BytesIO) for the loop to write
-    back; ``close_connection`` reports the keep-alive decision."""
+    machinery and dispatches through the unchanged ``Handler`` route
+    table.  The response accumulates in ``wfile`` (a BytesIO) for the
+    loop to write back; ``close_connection`` reports the keep-alive
+    decision."""
 
     def __init__(self, server, raw: bytes, client_address, deadline=None,
                  admission_wait: float | None = None,
@@ -158,7 +184,7 @@ class _BufferedHandler(Handler):
         # monotonic instant the request HEAD started arriving: the
         # workload capture stamps records with it so replayed arrival
         # spacing reflects offered load, not settle times
-        # (docs/workload.md; None on the threaded listener)
+        # (docs/workload.md)
         self.arrival_monotonic = arrival
         self.close_connection = True
         self.requestline = ""
@@ -190,14 +216,13 @@ class _BufferedHandler(Handler):
 
 
 class EventHTTPServer(_ServerCore):
-    """HTTP front end bound to an API façade — the event-driven default.
+    """HTTP front end bound to an API façade: the one listener.
 
-    Same attribute surface as the legacy ``ThreadedHTTPServer``
-    (``query_router`` / ``import_router`` hooks, ``extra_routes``,
-    ``ssl_context``, ``serve_background``/``shutdown``/``server_close``)
-    so the runtime Server and the cluster layer wire either
-    interchangeably; the listener internals are an asyncio loop on one
-    background thread."""
+    The runtime Server and the cluster layer wire it through the
+    ``_ServerCore`` attribute surface (``query_router`` /
+    ``import_router`` hooks, ``extra_routes``, ``ssl_context``) and
+    ``serve_background``/``shutdown``/``server_close``; the listener
+    internals are an asyncio loop on one background thread."""
 
     def __init__(self, addr: tuple[str, int], api, stats: StatsClient | None = None):
         # bind in the constructor (like socketserver) so server_address
@@ -231,6 +256,9 @@ class EventHTTPServer(_ServerCore):
         self._conn_count = 0
         self._started = threading.Event()
         self._closed = False
+        # requests _drain cut at its bound whose workers were still
+        # running: shutdown() raises while any of them is
+        self._cut: list[Future] = []
         # multi-process serving (docs/multiprocess.md): extra listeners
         # added AFTER boot — the SO_REUSEPORT shared public socket a
         # supervised child binds once its cluster join completes — and
@@ -258,12 +286,40 @@ class EventHTTPServer(_ServerCore):
         return t
 
     def shutdown(self) -> None:
+        """Stop the loop (``_drain`` has the order) and return only with
+        its thread dead and no admitted request still running in a
+        worker.  Anything else is a fault, and the caller must not go on
+        to close the holder under it: logged, then raised.  The fault is
+        read from live state, so a later call raises again for as long
+        as it lasts and returns once it is gone."""
         self._closed = True
         loop, stop = self._loop, self._stop
         if loop is not None and stop is not None and loop.is_running():
             loop.call_soon_threadsafe(stop.set)
-        if self._thread is not None:
-            self._thread.join(timeout=10.0)
+        t = self._thread
+        if t is None:
+            return
+        # _drain ends a second after the grace at the latest; the rest
+        # is for the loop to unwind
+        bound = SHUTDOWN_GRACE_S + 5.0
+        t.join(timeout=bound)
+        if t.is_alive():
+            # the loop still owns the set: tuple() copies it in one step
+            phases = sorted(_PHASES[c.phase] for c in tuple(self._conns))
+            fault = (
+                f"event loop thread still alive after {bound:g}s; "
+                f"connections by phase: {phases}"
+            )
+        elif running := sum(not work.done() for work in self._cut):
+            fault = (
+                f"{running} admitted request(s), cut after "
+                f"{SHUTDOWN_GRACE_S:g}s, still running in their workers"
+            )
+        else:
+            return
+        msg = f"http shutdown error: {fault}"
+        self.log(msg)
+        raise RuntimeError(msg)
 
     def server_close(self) -> None:
         self._closed = True
@@ -349,31 +405,75 @@ class EventHTTPServer(_ServerCore):
             backlog=LISTEN_BACKLOG,
             **kwargs,
         )
-        sweeper = asyncio.ensure_future(self._sweep_slow_clients())
-        lag_probe = None
+        # held here because the loop keeps only weak references to its
+        # tasks; _drain ends them with everything else on the loop
+        background = [asyncio.ensure_future(self._sweep_slow_clients())]
         if self.saturation is not None and self.saturation.enabled:
-            lag_probe = asyncio.ensure_future(self._lag_probe())
+            background.append(asyncio.ensure_future(self._lag_probe()))
         self._started.set()
         try:
             await self._stop.wait()
         finally:
-            if lag_probe is not None:
-                lag_probe.cancel()
-            sweeper.cancel()
-            server.close()
-            await server.wait_closed()
-            for extra in self._extra_servers:
-                extra.close()
-            if self._extra_servers:
-                await asyncio.gather(
-                    *(s.wait_closed() for s in self._extra_servers),
-                    return_exceptions=True,
-                )
-            self._close_fd_plumbing(loop)
-            for t in list(self._conn_tasks):
-                t.cancel()
-            if self._conn_tasks:
-                await asyncio.gather(*self._conn_tasks, return_exceptions=True)
+            await self._drain(loop, [server, *self._extra_servers])
+
+    async def _drain(self, loop, servers: list) -> None:
+        """The shutdown, in the order that lets it end (docs/serving.md
+        "Shutdown"): stop accepting; drop every connection that owes
+        nothing; let BUSY connections write the reply they owe, up to
+        SHUTDOWN_GRACE_S; cut what is left; only then wait for the
+        listeners.  ``Server.wait_closed()`` waits for every connection
+        the listener accepted (Python 3.12.1 on), and a peer's idle
+        keep-alive connection never closes by itself, so the wait comes
+        last."""
+        grace_ends = loop.time() + SHUTDOWN_GRACE_S
+        for s in servers:
+            s.close()
+        self._close_fd_plumbing(loop)
+        # the transports, kept past their tasks: a task that ends
+        # leaves its transport closing, not closed
+        transports = [c.writer.transport for c in self._conns]
+        for conn in self._conns:
+            if conn.phase != _ConnState.BUSY:
+                # IDLE, or a request not yet admitted: never
+                # acknowledged, so nothing new is owed
+                conn.aborted = True
+                _hang_up(conn.writer.transport)
+        # a BUSY connection's task writes its reply, sees _stop, ends
+        if self._conn_tasks:
+            await asyncio.wait(
+                self._conn_tasks, timeout=max(0.0, grace_ends - loop.time())
+            )
+        if self._conn_tasks:
+            phases = sorted(_PHASES[c.phase] for c in self._conns)
+            self.log(  # pilosa: allow(loop-purity) — shutdown only
+                f"http shutdown: {len(phases)} connections cut after "
+                f"{SHUTDOWN_GRACE_S:g}s, by phase: {phases}"
+            )
+        # everything else on the loop ends as asyncio.run() would end
+        # it: those connections' tasks, the sweeper, the lag probe, and
+        # asyncio's own task behind a TLS handshake in flight, which
+        # closes a connection this class never saw
+        rest = asyncio.all_tasks() - {asyncio.current_task()}
+        for t in rest:
+            t.cancel()
+        await asyncio.gather(*rest, return_exceptions=True)
+        # every task has closed its writer by now; a reply's tail still
+        # on its way to a slow reader has the rest of the grace
+        owed = sum(not _flushed(tr) for tr in transports)
+        for tr in transports:
+            _hang_up(tr)
+        bound = 1.0 + (max(0.0, grace_ends - loop.time()) if owed else 0.0)
+        try:
+            await asyncio.wait_for(
+                asyncio.gather(*(s.wait_closed() for s in servers)), bound
+            )
+        except TimeoutError:
+            for tr in transports:
+                tr.abort()
+            self.log(  # pilosa: allow(loop-purity) — shutdown only
+                f"http shutdown: {owed} replies not read to their end "
+                f"after {bound:.1f}s, cut"
+            )
 
     # ------------------------------------------------- shared public port
     def add_shared_listener(self, host: str, port: int) -> None:
@@ -648,6 +748,11 @@ class EventHTTPServer(_ServerCore):
 
     async def _handle_conn(self, reader: asyncio.StreamReader,
                            writer: asyncio.StreamWriter) -> None:
+        assert self._stop is not None
+        if self._stop.is_set():
+            # accepted as the listener closed: _drain never saw it
+            writer.transport.abort()
+            return
         task = asyncio.current_task()
         if task is not None:
             self._conn_tasks.add(task)
@@ -711,12 +816,12 @@ class EventHTTPServer(_ServerCore):
                 # the budget clock starts NOW — admission-queue wait and
                 # body-read time both spend it (acceptance: a query that
                 # exhausts its budget while queued never executes).
-                # QUERY class only: on the threaded path the deadline
-                # governed query routes alone (_query_context), so an
-                # import or /status probe queued past query-timeout-ms
-                # must not start 504ing — a busy-but-alive node's
-                # heartbeats dying at admission is the dead-marking the
-                # dedicated control lane exists to prevent
+                # QUERY class only: the deadline governs query routes
+                # alone (_query_context), so an import or /status probe
+                # queued past query-timeout-ms must not start 504ing — a
+                # busy-but-alive node's heartbeats dying at admission is
+                # the dead-marking the dedicated control lane exists to
+                # prevent
                 deadline = None
                 if cls == _CLASS_QUERY:
                     deadline = resilience.deadline_from_header(
@@ -1040,7 +1145,6 @@ class EventHTTPServer(_ServerCore):
                     close=False,
                 )
                 return True
-            loop = asyncio.get_running_loop()
             # the worker may ship bytes straight to the socket ONLY when
             # nothing is queued in the transport: drain() waits for the
             # high-water mark, not empty, so a slow-reading client can
@@ -1052,10 +1156,15 @@ class EventHTTPServer(_ServerCore):
                 self.ssl_context is None
                 and writer.transport.get_write_buffer_size() == 0
             )
-            payload, close = await loop.run_in_executor(
-                self._pool, self._run_request, raw, writer, deadline,
+            work = self._pool.submit(
+                self._run_request, raw, writer, deadline,
                 direct_ok, wait_s, arrival,
             )
+            try:
+                payload, close = await asyncio.wrap_future(work)
+            except asyncio.CancelledError:
+                self._cut.append(work)  # by _drain; its worker runs on
+                raise
         finally:
             adm.in_flight -= 1
             adm.sem.release()

@@ -29,7 +29,7 @@ import re
 import threading
 import time
 from datetime import datetime, timezone
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from http.server import BaseHTTPRequestHandler
 from urllib.parse import parse_qs, urlparse
 
 from pilosa_tpu import __version__, encoding
@@ -1576,12 +1576,10 @@ class Handler(BaseHTTPRequestHandler):
 
 
 class _ServerCore:
-    """Front-end-independent server state: the API binding, the router
-    hooks the cluster layer swaps in, and the /internal extra-route
-    table.  Shared by the event-driven listener (server/eventloop.py —
-    the default) and the legacy thread-per-request listener below, so
-    the cluster layer and the runtime Server wire one attribute surface
-    regardless of serving mode."""
+    """What the routes need of their server, apart from the listener
+    (server/eventloop.py): the API binding, the router hooks the cluster
+    layer swaps in, and the /internal extra-route table — the attribute
+    surface ``Handler``, the cluster layer and the runtime Server wire."""
 
     def _init_core(self, api, stats: StatsClient | None) -> None:
         self.ssl_context = None  # set by Server.open() for TLS serving
@@ -1706,77 +1704,9 @@ class _ServerCore:
                     return True
         return False
 
-    def serving_snapshot(self) -> dict:
-        """Serving-front-end state for /debug/vars (docs/serving.md);
-        the event-driven listener overrides with live admission state."""
-        return {"mode": "threaded"}
 
-
-class ThreadedHTTPServer(_ServerCore, ThreadingHTTPServer):
-    """Legacy thread-per-request front end (config serving-mode =
-    "threaded"): one OS thread parks per in-flight request, so cheap
-    queries regress under fan-in (BENCH_SWEEP_r06_cpu: c32 = 0.88x c1)
-    and connect storms exhaust the accept backlog.  Kept as a rollback
-    path and as the latency baseline the event-driven front end is
-    benchmarked against (bench_all config8); it has no admission
-    control — do not put it in front of high-fan-in traffic."""
-
-    daemon_threads = True
-
-    def handle_error(self, request, client_address):
-        import sys
-
-        exc = sys.exc_info()[1]
-        if isinstance(
-            exc,
-            (ConnectionResetError, BrokenPipeError, TimeoutError,
-             ConnectionAbortedError),
-        ):
-            return  # routine client teardown, not a server fault
-        if self.ssl_context is not None:
-            import ssl
-
-            if isinstance(exc, ssl.SSLError):
-                # failed/aborted client handshake (plaintext speaker on
-                # the TLS port, cert rejected by a strict client): the
-                # client's problem, logged by the client — a per-event
-                # server traceback would spray the log under portscans
-                return
-        super().handle_error(request, client_address)
-
-    def __init__(self, addr: tuple[str, int], api, stats: StatsClient | None = None):
-        super().__init__(addr, Handler)
-        self._init_core(api, stats)
-
-    def get_request(self):
-        """Accept, then wrap per-connection for TLS with the handshake
-        DEFERRED (do_handshake_on_connect=False): get_request runs on the
-        single accept thread, so an inline handshake would let one stalled
-        client (TCP open, no ClientHello) wedge every other request; the
-        deferred handshake happens on first recv in the handler's thread."""
-        sock, addr = super().get_request()
-        if self.ssl_context is not None:
-            sock = self.ssl_context.wrap_socket(
-                sock, server_side=True, do_handshake_on_connect=False
-            )
-        return sock, addr
-
-    def process_request_thread(self, request, client_address):
-        # name the per-connection thread so profiler samples attribute
-        # to the listener subsystem instead of "Thread-12"
-        threading.current_thread().name = "http-threaded-conn"
-        super().process_request_thread(request, client_address)
-
-    def serve_background(self) -> threading.Thread:
-        t = threading.Thread(
-            target=self.serve_forever, daemon=True, name="http-accept"
-        )
-        t.start()
-        return t
-
-
-# the default front end: the asyncio accept/read/write loop with
-# keep-alive multiplexing and bounded admission (docs/serving.md).
+# the front end: the asyncio accept/read/write loop with keep-alive
+# multiplexing and bounded admission (docs/serving.md).
 # Imported at the bottom so eventloop.py can subclass Handler above;
 # the name HTTPServer stays here because the runtime Server, the
 # cluster tests, and the package __init__ all import it from this

@@ -14,7 +14,7 @@ import threading
 
 from pilosa_tpu.core import Holder
 from pilosa_tpu.server.api import API
-from pilosa_tpu.server.http import HTTPServer, ThreadedHTTPServer
+from pilosa_tpu.server.http import HTTPServer
 from pilosa_tpu.utils.config import Config
 
 
@@ -123,21 +123,11 @@ class Server:
     def open(self) -> None:
         """holder load → HTTP up → cluster join → background loops
         (reference: Server.Open). The listener must serve BEFORE the
-        cluster join: socketserver binds in the constructor, so a peer
+        cluster join: the listener binds in its constructor, so a peer
         that probed a bound-but-not-serving node would hang in the accept
         backlog for the full client timeout instead of getting an instant
         connection-refused — concurrent cold starts then stack 30s
         timeouts on each other."""
-        if (
-            self.config.shared_bind or self.config.fd_pass_socket
-        ) and self.config.serving_mode == "threaded":
-            # refuse BEFORE any background thread starts: a misconfig
-            # raising mid-open would leak profiler/saturation threads
-            raise ValueError(
-                "multi-process serving (shared-bind / fd-pass-socket) "
-                "requires serving-mode = \"event\" — the threaded "
-                "listener has no shared-listener support"
-            )
         if self.fs_fault_injector.armed:
             # before holder.open(): crash-recovery rehearsals target the
             # load path (snapshot reads, torn-tail truncation) too
@@ -145,36 +135,24 @@ class Server:
 
             durable.install_fs_hook(self.fs_fault_injector)
         self.holder.open()
-        # event-driven front end by default (docs/serving.md); the
-        # legacy thread-per-request listener stays as a rollback knob
-        # and as the latency baseline the bench sweep compares against
-        server_cls = (
-            ThreadedHTTPServer
-            if self.config.serving_mode == "threaded"
-            else HTTPServer
-        )
-        self.http = server_cls(
+        self.http = HTTPServer(
             (self.config.host, self.config.port), self.api, stats=self.stats
         )
-        if server_cls is HTTPServer:
-            # admission/backpressure knobs (docs/serving.md): these
-            # replace the old fixed request_queue_size accept backlog
-            self.http.max_connections = self.config.max_connections
-            self.http.admission_queue_depth = self.config.admission_queue_depth
-            self.http.keepalive_idle_s = self.config.keepalive_idle_s
-            self.http.request_read_timeout_s = self.config.request_read_timeout_s
-            self.http.worker_threads = self.config.http_worker_threads
-            # write-class backpressure tied to compaction debt
-            # (docs/durability.md): past the limit, imports get 429 +
-            # Retry-After instead of growing ops logs without bound
-            self.http.compaction_max_debt = self.config.compaction_max_debt
-            self.http.compaction_debt = self.holder.compactor.debt
+        # admission/backpressure knobs (docs/serving.md)
+        self.http.max_connections = self.config.max_connections
+        self.http.admission_queue_depth = self.config.admission_queue_depth
+        self.http.keepalive_idle_s = self.config.keepalive_idle_s
+        self.http.request_read_timeout_s = self.config.request_read_timeout_s
+        self.http.worker_threads = self.config.http_worker_threads
+        # write-class backpressure tied to compaction debt
+        # (docs/durability.md): past the limit, imports get 429 +
+        # Retry-After instead of growing ops logs without bound
+        self.http.compaction_max_debt = self.config.compaction_max_debt
+        self.http.compaction_debt = self.holder.compactor.debt
         if self.config.tls_certificate:
             # serve HTTPS (reference: tls.certificate/tls.key). The context
-            # is handed to the listener, which wraps each accepted
-            # connection with a deferred handshake — see HTTPServer.
-            # get_request for why the listening socket itself must NOT be
-            # wrapped (handshake would run on the accept thread).
+            # is handed to the listener, whose loop runs each accepted
+            # connection's handshake, bounded by request-read-timeout-s.
             import ssl
 
             ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)
@@ -427,12 +405,26 @@ class Server:
         return f"{self.config.scheme}://{self.config.host}:{self.port}"
 
     def close(self) -> None:
+        """Stop the listener, then close everything under it.  The
+        listener goes first and raises while a request is still running
+        in a worker (``EventHTTPServer.shutdown``): nothing else has
+        been closed then, so the node is whole but for its listener,
+        and a later ``close()`` goes through once the worker has
+        returned."""
+        if self.http is not None:
+            self.http.shutdown()
         self._closed = True
         # reap the attach thread (bounded): it must not swap an
         # executor into an API whose holder is closing
         t = self._mesh_attach_thread
         if t is not None:
             t.join(timeout=10.0)
+            if t.is_alive():
+                # it checks _closed before it binds; what is left is a
+                # backend that hangs in its own start-up
+                self.logger.log(
+                    "close error: device attach still running after 10s"
+                )
         if self.diagnostics is not None:
             self.diagnostics.close()
         if self._anti_entropy_timer is not None:
@@ -444,10 +436,9 @@ class Server:
             self.profiler.stop()
         if self.http is not None:
             self.http.saturation.stop()
-            # flush the open workload spill segment before the listener
-            # dies — a capture cut off mid-segment replays short
+            # flush the open workload spill segment: a capture cut off
+            # mid-segment replays short
             self.http.workload.close()
-            self.http.shutdown()
             self.http.server_close()
         self.stats.close()
         self.holder.close()

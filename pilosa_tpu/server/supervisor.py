@@ -1,6 +1,6 @@
 """Multi-process serving supervisor (docs/multiprocess.md).
 
-BENCH_PROFILE_r12 measured the one-process ceiling directly: past c32
+A 1-core CPU run measured the one-process ceiling directly: past c32
 the query lane's worker-pool utilization p95 pins at 1.0 and the GIL
 wait p99 reaches ~51ms — more threads cannot help, because the binding
 resources are per-interpreter.  This module treats one box like a

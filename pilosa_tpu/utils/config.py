@@ -29,14 +29,9 @@ class Config:
     anti_entropy_interval: float = 600.0  # seconds; 0 disables
     heartbeat_interval: float = 2.0  # peer liveness probe period
     diagnostics_interval: float = 3600.0  # snapshot period; 0 disables
-    # serving front end (docs/serving.md): "event" = the asyncio
-    # accept/read/write loop with keep-alive multiplexing and bounded
-    # admission (the default); "threaded" = the legacy thread-per-
-    # request listener (rollback / latency-baseline only — no admission
-    # control)
-    serving_mode: str = "event"
-    # open-connection cap for the event front end (0 = unlimited);
-    # connections past it get 503 + Retry-After at accept
+    # serving front end (docs/serving.md): open-connection cap
+    # (0 = unlimited); connections past it get 503 + Retry-After at
+    # accept
     max_connections: int = 0
     # bounded admission wait queue PER CLASS (query/write/control); a
     # request arriving with the class queue full gets 429 + Retry-After
@@ -172,7 +167,7 @@ class Config:
     holder_load_workers: int = 8
     # fragment-count floor below which Holder.open loads serially even
     # with workers configured: at small counts pool spin-up costs more
-    # than it overlaps (BENCH_INGEST_r08: parallel 0.159s vs serial
+    # than it overlaps (a 1-core CPU run: parallel 0.159s vs serial
     # 0.066s over 12 fragments). 0 always parallelizes.
     holder_load_min_fragments: int = 32
     # flight recorder (docs/observability.md): always-on tail-based
@@ -374,7 +369,6 @@ def config_template() -> str:
         "anti-entropy-interval = 600.0\n"
         "heartbeat-interval = 2.0\n"
         "diagnostics-interval = 3600.0\n"
-        'serving-mode = "event"\n'
         "max-connections = 0\n"
         "admission-queue-depth = 256\n"
         "keepalive-idle-s = 75.0\n"

@@ -1,9 +1,9 @@
 """Saturation probes: which in-process resource binds, measured.
 
-BENCH_SWEEP_r07's loudest signal — ``sync_count_qps_c64`` collapsing to
-0.96x c1 after scaling to 1.76x at c32 — was *asserted* to be "one event
-loop + one GIL-bound worker pool" with no measured evidence for which
-resource actually binds.  This module is that evidence, USE-style
+A 1-core CPU sweep's loudest signal — ``sync_count_qps_c64`` collapsing
+to 0.96x c1 after scaling to 1.76x at c32 — was *asserted* to be "one
+event loop + one GIL-bound worker pool" with no measured evidence for
+which resource actually binds.  This module is that evidence, USE-style
 (utilization / saturation / errors), feeding ``GET /debug/saturation``:
 
 - **event-loop lag** — a periodic callback scheduled on the asyncio loop
@@ -337,7 +337,7 @@ class SaturationMonitor:
         # cannot help, more processes can.  Name the remedy and size it
         # from the host's cores; on a core-starved box the suggestion
         # is recorded but waived, since N processes would time-share
-        # the same core (the bench's MULTICHIP_r06 waiver precedent).
+        # the same core.
         recommendation = None
         if binding in ("worker-pool", "gil"):
             cores = os.cpu_count() or 1

@@ -155,3 +155,23 @@ def rng():
 @pytest.fixture
 def tmp_holder_path(tmp_path):
     return str(tmp_path / "holder")
+
+
+@pytest.fixture(scope="session")
+def certpair(tmp_path_factory):
+    """(cert, key) paths of a self-signed localhost certificate."""
+    import subprocess
+
+    d = tmp_path_factory.mktemp("tls")
+    cert, key = d / "node.crt", d / "node.key"
+    subprocess.run(
+        [
+            "openssl", "req", "-x509", "-newkey", "rsa:2048", "-nodes",
+            "-keyout", str(key), "-out", str(cert), "-days", "2",
+            "-subj", "/CN=127.0.0.1",
+            "-addext", "subjectAltName=IP:127.0.0.1,DNS:localhost",
+        ],
+        check=True,
+        capture_output=True,
+    )
+    return str(cert), str(key)

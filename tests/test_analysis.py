@@ -526,15 +526,15 @@ def test_asyncpurity_thread_spawn_in_coroutine_fails(tree_copy):
     # thread-per-request model the bounded worker pool replaced
     mutate(
         tree_copy / "pilosa_tpu" / "server" / "eventloop.py",
-        "payload, close = await loop.run_in_executor(\n"
-        "                self._pool, self._run_request, raw, writer, deadline,\n"
+        "work = self._pool.submit(\n"
+        "                self._run_request, raw, writer, deadline,\n"
         "                direct_ok, wait_s, arrival,\n"
         "            )",
         "_t = threading.Thread(\n"
         "                target=self._run_request, args=(raw, writer, deadline)\n"
         "            )\n"
         "            _t.start()\n"
-        "            payload, close = b\"\", True",
+        "            work = concurrent.futures.Future()",
     )
     rc, out = check_tree(tree_copy)
     assert rc != 0

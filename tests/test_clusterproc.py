@@ -58,6 +58,27 @@ def wait_ready(port, deadline=360.0):
     raise TimeoutError(f"server on :{port} did not come up NORMAL")
 
 
+def first_count(port, within=5.0):
+    """A just-booted node's first read.  NORMAL is its own state; its
+    view of its peers' liveness is up to one heartbeat (2 s) behind
+    (``Cluster.join()`` ends NORMAL whatever its first heartbeat found:
+    ROADMAP debt (l2)), and until then a read that needs a peer is the
+    labeled 503 that tells the client to come again.  Only that reply,
+    and only for two heartbeats: anything else fails the test."""
+    t0 = time.time()
+    while True:
+        try:
+            return call(port, "POST", "/index/i/query",
+                        b"Count(Row(f=1))")["results"]
+        except urllib.error.HTTPError as e:
+            body = e.read().decode(errors="replace")
+            assert e.code == 503 and "no alive owner for shard" in body, (
+                e.code, body)
+            assert time.time() - t0 < within, (
+                f"still {body!r} {within:g}s after every node was NORMAL")
+            time.sleep(0.2)
+
+
 @pytest.fixture
 def procs(tmp_path):
     """3 real server processes in one cluster, replica_n=2."""
@@ -111,8 +132,7 @@ def test_subprocess_cluster_end_to_end(procs):
     call(ports[1], "POST", "/index/i/field/f/import",
          {"rowIDs": [1, 1, 1, 1], "columnIDs": cols})
     for p in ports:
-        r = call(p, "POST", "/index/i/query", b"Count(Row(f=1))")
-        assert r["results"] == [4]
+        assert first_count(p) == [4]
 
     # kill node 2 with replica_n=2: remaining nodes serve the full data.
     # Each survivor's FIRST query that routes to the dead peer fails 503

@@ -254,22 +254,6 @@ def test_fd_pass_adoption(tmp_path):
         s.close()
 
 
-def test_threaded_mode_rejects_multiproc_listeners(tmp_path):
-    """The shared listener rides the event loop; the threaded
-    front end must refuse the knobs loudly instead of silently serving
-    only the private bind."""
-    from pilosa_tpu.server import Server
-
-    cfg = Config(
-        bind="127.0.0.1:0",
-        data_dir=str(tmp_path / "t"),
-        serving_mode="threaded",
-        shared_bind="127.0.0.1:1",
-    )
-    with pytest.raises(ValueError, match="serving-mode"):
-        Server(cfg).open()
-
-
 # ------------------------------------------------ fleet observability
 
 

@@ -299,6 +299,69 @@ def test_internal_status_checksums_converge_across_replicas(tmp_path):
         shutdown(servers)
 
 
+def test_reads_exact_and_unfailing_across_grow_and_shrink(tmp_path):
+    """2→3→2 under fire: a client reading from the coordinator the
+    whole time sees no failed and no wrong answer through the join's
+    hydration pulls and the re-pulls after the remove, and the two
+    survivors' fragment checksums agree afterwards."""
+    servers, ports, seeds = make_cluster(tmp_path, n=2, replica_n=2)
+    try:
+        call(ports[0], "POST", "/index/i", {})
+        call(ports[0], "POST", "/index/i/field/f", {})
+        n_shards = 12
+        cols = [s * SHARD_WIDTH + 7 for s in range(n_shards)]
+        call(ports[0], "POST", "/index/i/field/f/import",
+             {"rowIDs": [1] * n_shards, "columnIDs": cols})
+        want = {"results": [n_shards, [{"id": 1, "count": n_shards}]]}
+        stop = threading.Event()
+        answers, failures = [], []
+
+        def reader():
+            while not stop.is_set():
+                try:
+                    answers.append(call(ports[0], "POST", "/index/i/query",
+                                        b"Count(Row(f=1)) TopN(f, n=1)"))
+                except Exception as e:  # noqa: BLE001 — the gate counts them
+                    failures.append(repr(e))
+
+        t = threading.Thread(target=reader, daemon=True)
+        t.start()
+        new_srv, _ = grow(tmp_path, servers, ports, seeds)
+        servers.append(new_srv)
+        for s in servers[:2]:
+            s.cluster.wait_rebalanced(30)
+        at_three = len(answers)
+        for attempt in range(40):
+            try:
+                call(ports[0], "POST", "/internal/cluster/resize/remove-node",
+                     {"id": new_srv.cluster.me.id})
+                break
+            except urllib.error.HTTPError as e:
+                if e.code != 409 or attempt == 39:
+                    raise  # only a pull still in flight is expected
+                time.sleep(0.25)
+        for s in servers[:2]:
+            s.cluster.wait_rebalanced(30)
+        after = len(answers)
+        while len(answers) < after + 5 and not failures:
+            time.sleep(0.01)  # a few reads at two nodes again
+        stop.set()
+        t.join(30)
+        assert not t.is_alive()
+        assert not failures, failures[:3]
+        assert at_three > 0 and all(a == want for a in answers)
+        for _ in range(2):
+            for s in servers[:2]:
+                s.cluster.sync_holder()
+        a, b = (
+            call(p, "GET", "/internal/status")["checksums"].get("i", {})
+            for p in ports
+        )
+        assert a and a == b
+    finally:
+        shutdown(servers)
+
+
 def test_checksum_mismatch_repaired_by_anti_entropy(tmp_path):
     """Satellite 3: a replica whose fragment content diverges (checksum
     mismatch) is repaired by the anti-entropy pass, after which the

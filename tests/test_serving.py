@@ -15,6 +15,7 @@ from __future__ import annotations
 import http.client
 import json
 import socket
+import ssl
 import threading
 import time
 import urllib.error
@@ -440,6 +441,213 @@ def test_breaker_open_evicts_peer_pool():
             rc.query_node(uri, "i", "Count(Row(f=1))", None)
     assert breakers.get(uri).state == BREAKER_OPEN
     assert inner.evicted == [uri]
+
+
+# ---------------------------------------------------------------- shutdown
+def _http_threads(s) -> list:
+    """This server's live listener threads: its loop and its workers."""
+    pool = s.http._pool
+    threads = [s.http._thread, *(pool._threads if pool is not None else ())]
+    return [t for t in threads if t is not None and t.is_alive()]
+
+
+def _assert_closes(s, within: float = 2.0) -> None:
+    port = s.port
+    t0 = time.monotonic()
+    s.close()
+    took = time.monotonic() - t0
+    assert took < within, f"Server.close() took {took:.2f}s"
+    deadline = time.monotonic() + 1.0
+    while _http_threads(s) and time.monotonic() < deadline:
+        time.sleep(0.01)  # an idle worker leaves on its pool's signal
+    assert not _http_threads(s)
+    with pytest.raises(ConnectionRefusedError):
+        socket.create_connection(("127.0.0.1", port), timeout=2)
+
+
+def _idle_keepalive(port, wrap=lambda sock: sock):
+    conn = wrap(socket.create_connection(("127.0.0.1", port), timeout=5))
+    conn.sendall(b"GET /status HTTP/1.1\r\nHost: x\r\nContent-Length: 0\r\n\r\n")
+    assert b"200" in conn.recv(65536)
+    return conn
+
+
+@pytest.mark.filterwarnings("error::pytest.PytestUnhandledThreadExceptionWarning")
+@pytest.mark.parametrize(
+    "case",
+    ["no_connection", "idle_keepalive", "half_head", "in_flight",
+     "tls_idle_keepalive", "cluster_peers", "handler_never_returns",
+     "slow_reader", "reader_never_reads", "tls_handshake_in_flight"],
+)
+def test_close_stops_the_listener(tmp_path, certpair, monkeypatch, case):
+    """``Server.close()`` returns at once with the loop thread and the
+    workers dead and the port refusing, whatever the clients hold open
+    (docs/serving.md "Shutdown"): a connection that owes nothing is
+    dropped, a request in flight gets its whole reply first.  The one
+    thing that may not be waited out, a handler that never returns,
+    makes it raise instead of closing the holder under a live worker."""
+    from pilosa_tpu.server import eventloop
+
+    if case == "cluster_peers":
+        from tests.test_cluster import call as ccall, make_cluster
+
+        servers, ports, _ = make_cluster(tmp_path, n=2, replica_n=2)
+        ccall(ports[0], "POST", "/index/i", {})
+        ccall(ports[0], "POST", "/index/i/field/f", {})
+        # both directions: each node's pooled client holds a
+        # keep-alive connection to the other
+        for p in ports:
+            ccall(p, "POST", "/index/i/query", b"Set(1, f=1)")
+            assert ccall(p, "POST", "/index/i/query", b"Count(Row(f=1))") == {
+                "results": [1]
+            }
+        assert all(s.http._conn_count >= 1 for s in servers)
+        for s in servers:
+            _assert_closes(s)
+        return
+    kw = {}
+    if case.startswith("tls_"):
+        kw = dict(tls_certificate=certpair[0], tls_key=certpair[1])
+    s = make_server(tmp_path, **kw)
+    logged = []
+    log = s.http.log
+    s.http.log = lambda msg: (logged.append(msg), log(msg))
+    held = []
+    try:
+        if case == "idle_keepalive":
+            held.append(_idle_keepalive(s.port))
+        elif case.startswith("tls_"):
+            if case == "tls_handshake_in_flight":
+                # TCP open, no ClientHello: a connection the listener
+                # counts and the loop never sees.  Accepted for sure
+                # once the one behind it has been answered
+                mute = socket.create_connection(("127.0.0.1", s.port), timeout=5)
+            ctx = ssl.create_default_context(cafile=certpair[0])
+            held.append(_idle_keepalive(
+                s.port, lambda k: ctx.wrap_socket(k, server_hostname="127.0.0.1")
+            ))
+            if case == "tls_handshake_in_flight":
+                held.append(mute)  # closed too, not left to its timeout
+        elif case == "half_head":
+            conn = socket.create_connection(("127.0.0.1", s.port), timeout=5)
+            conn.sendall(b"GET /status HTTP/1.1\r\nHost: x\r\n")
+            held.append(conn)
+            deadline = time.monotonic() + 5
+            while time.monotonic() < deadline and not any(
+                c.phase == eventloop._ConnState.HEAD for c in s.http._conns
+            ):
+                time.sleep(0.01)
+        elif case == "in_flight":
+            seed_index(s)
+            started = threading.Event()
+
+            def slow(index, pql, shards):
+                started.set()
+                time.sleep(0.3)
+                return {"results": [7]}
+
+            s.http.query_router = slow
+            conn = http.client.HTTPConnection("127.0.0.1", s.port, timeout=10)
+            conn.request("POST", "/index/i/query", b"Count(Row(f=1))")
+            assert started.wait(5)
+            _assert_closes(s)
+            # acknowledged or cut, never half: the whole reply, then EOF
+            resp = conn.getresponse()
+            assert resp.status == 200
+            assert json.loads(resp.read()) == {"results": [7]}
+            assert conn.sock is None or conn.sock.recv(1) == b""
+            conn.close()
+            return
+        elif case == "handler_never_returns":
+            seed_index(s)
+            router, started, release, _ = _blocking_router()
+            s.http.query_router = router
+            monkeypatch.setattr(eventloop, "SHUTDOWN_GRACE_S", 0.3)
+            holder_closes = []
+            close_holder = s.holder.close
+            monkeypatch.setattr(
+                s.holder, "close",
+                lambda: (holder_closes.append(1), close_holder()),
+            )
+            t = threading.Thread(
+                target=lambda: pytest.raises(
+                    Exception, call, s, "POST", "/index/i/query", b"Count(Row(f=1))"
+                )
+            )
+            t.start()
+            assert started.wait(5)
+            # read from live state: however often it is asked, close()
+            # refuses while the worker runs, and has closed nothing but
+            # the listener ...
+            for _ in range(2):
+                with pytest.raises(RuntimeError, match=r"1 admitted request.*still running"):
+                    s.close()
+                assert not holder_closes and not s._closed
+                assert s.api.query("i", "Count(Row(f=1))") == {"results": [2]}
+            assert any("cut after 0.3s, by phase: ['busy']" in m for m in logged)
+            release.set()
+            t.join(5)
+            assert not t.is_alive()
+            deadline = time.monotonic() + 5
+            while not all(w.done() for w in s.http._cut):
+                assert time.monotonic() < deadline
+                time.sleep(0.01)
+            _assert_closes(s)  # ... and the rest, once the worker has gone
+            assert holder_closes
+            return
+        elif case in ("slow_reader", "reader_never_reads"):
+            # a reply larger than the socket buffers, and a client that
+            # reads none of it until the close is under way: owed whole.
+            # One that never reads is cut at the bound, and that is no
+            # fault of the node's: nothing runs, close() goes through
+            seed_index(s)
+            big = "x" * (16 << 20)
+            s.http.query_router = lambda index, pql, shards: {"results": [big]}
+            conn = socket.socket()
+            conn.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 65536)
+            conn.settimeout(10)
+            conn.connect(("127.0.0.1", s.port))
+            q = b"Count(Row(f=1))"
+            conn.sendall(
+                b"POST /index/i/query HTTP/1.1\r\nHost: x\r\n"
+                b"Content-Length: %d\r\n\r\n%s" % (len(q), q)
+            )
+            deadline = time.monotonic() + 10
+            while not any(
+                c.writer.transport.get_write_buffer_size() for c in s.http._conns
+            ):
+                assert time.monotonic() < deadline  # the kernel took it all?
+                time.sleep(0.01)
+            if case == "reader_never_reads":
+                monkeypatch.setattr(eventloop, "SHUTDOWN_GRACE_S", 0.3)
+                _assert_closes(s, within=3.0)  # the grace and a second
+                conn.close()
+                assert any("by phase: ['busy']" in m for m in logged)
+                assert any("1 replies not read to their end" in m for m in logged)
+                return
+            got = bytearray()
+
+            def read_late():
+                time.sleep(0.3)
+                while chunk := conn.recv(1 << 20):
+                    got.extend(chunk)
+
+            t = threading.Thread(target=read_late)
+            t.start()
+            _assert_closes(s)
+            t.join(10)
+            assert not t.is_alive()  # the reply, then EOF
+            conn.close()
+            head, _, body = bytes(got).partition(b"\r\n\r\n")
+            assert head.startswith(b"HTTP/1.1 200")
+            assert json.loads(body) == {"results": [big]}
+            return
+        _assert_closes(s)
+        for conn in held:
+            assert conn.recv(1) == b""  # dropped: nothing was owed
+    finally:
+        for conn in held:
+            conn.close()
 
 
 # ------------------------------------------------------------- 10k smoke

@@ -1,15 +1,14 @@
-"""SPMD perf smoke: the five bench_all query shapes on the 8-device
-virtual CPU mesh with words_axis=2 (VERDICT r3 item 8).
+"""SPMD perf smoke: five benchmark-like query shapes on the 8-device
+virtual CPU mesh with words_axis=2.
 
-bench.py/bench_all.py only run on real hardware at the end of a round;
-between TPU windows nothing exercised the SERVING-path SPMD programs at
-bench-like query shapes, so a sharding/layout regression (e.g. a stack
-losing its NamedSharding, a reduction stopping being a collective)
-would surface only as a driver-bench failure. This suite compiles and
-runs each bench_all config's query shape over a (4 shards x 2 words)
-mesh at tiny scale and asserts exact results — correctness here means
-the psum/all_gather wiring is right, and compiling at all means the
-layouts are mesh-legal.
+The benchmark runs only on the chip; between chip runs nothing else
+exercises the SERVING-path SPMD programs at such query shapes, so a
+sharding/layout regression (e.g. a stack losing its NamedSharding, a
+reduction stopping being a collective) would surface only there. This
+suite compiles and runs each shape over a (4 shards x 2 words) mesh at
+tiny scale and asserts exact results — correctness here means the
+psum/all_gather wiring is right, and compiling at all means the layouts
+are mesh-legal.
 """
 
 import numpy as np

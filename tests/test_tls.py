@@ -3,12 +3,12 @@
 Reference: server/config.go (tls.certificate, tls.key, tls.skip-verify) —
 upstream serves HTTPS when a cert/key pair is configured and lets the
 node→node client trust self-signed certs. Certs here are generated
-per-session with the system openssl (self-signed, localhost SAN).
+per-session with the system openssl (self-signed, localhost SAN):
+conftest.py's ``certpair``.
 """
 
 import json
 import ssl
-import subprocess
 import urllib.request
 
 import pytest
@@ -16,23 +16,6 @@ import pytest
 from pilosa_tpu.parallel.client import InternalClient
 from pilosa_tpu.server import Server
 from pilosa_tpu.utils.config import Config, load_config
-
-
-@pytest.fixture(scope="module")
-def certpair(tmp_path_factory):
-    d = tmp_path_factory.mktemp("tls")
-    cert, key = d / "node.crt", d / "node.key"
-    subprocess.run(
-        [
-            "openssl", "req", "-x509", "-newkey", "rsa:2048", "-nodes",
-            "-keyout", str(key), "-out", str(cert), "-days", "2",
-            "-subj", "/CN=127.0.0.1",
-            "-addext", "subjectAltName=IP:127.0.0.1,DNS:localhost",
-        ],
-        check=True,
-        capture_output=True,
-    )
-    return str(cert), str(key)
 
 
 @pytest.fixture
