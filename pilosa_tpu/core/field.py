@@ -107,6 +107,9 @@ class Field:
         # background compaction queue, inherited by views/fragments
         # created under this field (injected by the holder chain)
         self.compactor = None
+        # the owning index's mutation stamp (core/view.py IndexStamp),
+        # inherited by every view created here; None outside an Index
+        self.index_stamp = None
         # row attributes (reference: field.go rowAttrStore) and row-key
         # translation (reference: translate.go)
         self.row_attrs = AttrStore(
@@ -142,7 +145,8 @@ class Field:
 
     @classmethod
     def load(
-        cls, index: str, name: str, path: str, compactor=None, pool=None
+        cls, index: str, name: str, path: str, compactor=None, pool=None,
+        index_stamp=None,
     ) -> "Field":
         """Load a field's views and fragments from disk. With ``pool``
         (a ThreadPoolExecutor lent by Holder.open), fragment opens —
@@ -157,6 +161,7 @@ class Field:
         f_obj = cls(index, name, path, FieldOptions(**meta["options"]))
         f_obj._bit_depth = meta.get("bit_depth", f_obj._bit_depth)
         f_obj.compactor = compactor
+        f_obj.index_stamp = index_stamp
         views_dir = os.path.join(path, "views")
         if os.path.isdir(views_dir):
             for view_name in sorted(os.listdir(views_dir)):
@@ -202,6 +207,7 @@ class Field:
                 self.options.cache_size,
             )
             v.compactor = self.compactor
+            v.index_stamp = self.index_stamp
             self.views[name] = v
         return v
 

@@ -63,6 +63,8 @@ class Holder:
         # over 12 fragments)
         self.load_min_fragments = load_min_fragments
         self.compactor = Compactor(workers=compaction_workers, stats=stats)
+        # inherited by every index: Index.shard_scope counts its rebuilds
+        self.stats = stats
 
     def _count_fragment_files(self) -> int:
         """Cheap pre-scan of on-disk fragment files (one listdir pass
@@ -91,7 +93,8 @@ class Holder:
                     os.path.join(index_path, ".meta.json")
                 ):
                     self.indexes[entry] = Index.load(
-                        entry, index_path, compactor=self.compactor, pool=pool
+                        entry, index_path, compactor=self.compactor, pool=pool,
+                        stats=self.stats,
                     )
             if pool is not None:
                 # join every concurrent fragment open; re-raise the first
@@ -136,6 +139,7 @@ class Holder:
         index_path = os.path.join(self.path, name) if self.path else None
         idx = Index(name, index_path, options)
         idx.compactor = self.compactor
+        idx.stats = self.stats
         idx.save_meta()
         self.indexes[name] = idx
         return idx

@@ -18,6 +18,7 @@ import numpy as np
 from pilosa_tpu.core.attrstore import AttrStore
 from pilosa_tpu.core.field import FIELD_SET, VIEW_STANDARD, Field, FieldOptions
 from pilosa_tpu.core.translate import TranslateStore
+from pilosa_tpu.core.view import IndexStamp
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu.utils import durable
 
@@ -39,6 +40,13 @@ class Index:
         self._create_lock = threading.Lock()
         # background compaction queue, inherited by fields created here
         self.compactor = None
+        # metrics sink (the holder's), for shard_scope_rebuilds_total
+        self.stats = None
+        # one mutation stamp for everything under this index (raised by
+        # every view bump and by delete_field), and what is derived from
+        # unchanged state memoized against it: (stamp, shard scope)
+        self.stamp = IndexStamp()
+        self._scope: tuple[int, tuple[int, ...]] = (0, ())
         # column attributes (reference: index.go columnAttrStore) and
         # column-key translation (reference: translate.go)
         self.column_attrs = AttrStore(
@@ -62,19 +70,21 @@ class Index:
 
     @classmethod
     def load(
-        cls, name: str, path: str, compactor=None, pool=None
+        cls, name: str, path: str, compactor=None, pool=None, stats=None
     ) -> "Index":
         with open(os.path.join(path, ".meta.json")) as f:
             meta = json.load(f)
         idx = cls(name, path, IndexOptions(**meta["options"]))
         idx.compactor = compactor
+        idx.stats = stats
         for entry in sorted(os.listdir(path)):
             field_path = os.path.join(path, entry)
             if os.path.isdir(field_path) and os.path.exists(
                 os.path.join(field_path, ".meta.json")
             ):
                 idx.fields[entry] = Field.load(
-                    name, entry, field_path, compactor=compactor, pool=pool
+                    name, entry, field_path, compactor=compactor, pool=pool,
+                    index_stamp=idx.stamp,
                 )
         return idx
 
@@ -105,6 +115,7 @@ class Index:
         field_path = os.path.join(self.path, name) if self.path else None
         f = Field(self.name, name, field_path, options or FieldOptions())
         f.compactor = self.compactor
+        f.index_stamp = self.stamp
         f.save_meta()
         self.fields[name] = f
         return f
@@ -113,6 +124,9 @@ class Index:
         f = self.fields.pop(name, None)
         if f is None:
             raise KeyError(f"field {name!r} not found")
+        # the one change of the index's state no view bump announces:
+        # the field's shards and views leave with it
+        self.stamp.bump()
         f.close()
         if f.path and os.path.isdir(f.path):
             shutil.rmtree(f.path)
@@ -174,6 +188,23 @@ class Index:
         for f in self.fields.values():
             shards |= f.available_shards()
         return shards
+
+    def shard_scope(self) -> tuple[int, ...]:
+        """The sorted tuple of ``available_shards()`` — the SAME object
+        until the index's stamp moves, so a read costs one comparison
+        whatever the shard count.  Read-only by type; after a write it
+        is rebuilt once, at the walk's cost, and never older than the
+        last acknowledged write (the stamp is read BEFORE the walk, and
+        writers mutate before they bump)."""
+        stamp = self.stamp.value
+        memo = self._scope
+        if memo[0] == stamp:
+            return memo[1]
+        scope = tuple(sorted(self.available_shards()))
+        self._scope = (stamp, scope)
+        if self.stats is not None:
+            self.stats.count("shard_scope_rebuilds_total")
+        return scope
 
     def close(self) -> None:
         for f in self.fields.values():

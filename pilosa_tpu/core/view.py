@@ -19,6 +19,27 @@ VIEW_BSI = "bsi"
 _VIEW_STAMPS = itertools.count(1)
 
 
+class IndexStamp:
+    """One mutation stamp for a whole index: raised by every
+    ``View._bump_version`` under the index (every fragment mutation,
+    creation and removal) and by ``Index.delete_field``, so whatever is
+    derived from unchanged state — the shard scope, the dedup token —
+    is validated by ONE read instead of a walk over fields x views x
+    fragments per query.  Values come from the views' global counter,
+    drawn under the lock: monotone per index, and an index deleted and
+    recreated can never replay a stamp an old cache entry carries."""
+
+    __slots__ = ("value", "_lock")
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.value = next(_VIEW_STAMPS)
+
+    def bump(self) -> None:
+        with self._lock:
+            self.value = next(_VIEW_STAMPS)
+
+
 class View:
     def __init__(
         self,
@@ -53,9 +74,20 @@ class View:
         # GLOBAL counter so a deleted-and-recreated view can never replay
         # a stamp an old cache entry carries.
         self.version = next(_VIEW_STAMPS)
+        # the owning index's stamp (injected by the field chain; None for
+        # a view built outside an Index)
+        self.index_stamp: IndexStamp | None = None
+        # (version, rows) of max_rows(), valid while version is current
+        self._max_rows: tuple[int, int] = (0, 1)
 
     def _bump_version(self) -> None:
+        # callers mutate FIRST and bump after, and the index's stamp is
+        # raised before this returns — so before the write is
+        # acknowledged: a reader that sees an unchanged stamp reads state
+        # at least as new as every acknowledged write
         self.version = next(_VIEW_STAMPS)
+        if self.index_stamp is not None:
+            self.index_stamp.bump()
 
     def fragment(self, shard: int) -> Fragment | None:
         return self.fragments.get(shard)
@@ -103,6 +135,21 @@ class View:
 
     def available_shards(self) -> set[int]:
         return set(self.fragments)
+
+    def max_rows(self) -> int:
+        """The largest ``n_rows()`` of any fragment (at least 1), walked
+        once per view version: the version is read BEFORE the walk, so a
+        write that lands during it leaves the memo under the old version
+        and the next read walks again."""
+        version = self.version
+        memo = self._max_rows
+        if memo[0] == version:
+            return memo[1]
+        n = 1
+        for frag in list(self.fragments.values()):
+            n = max(n, frag.n_rows())
+        self._max_rows = (version, n)
+        return n
 
     def remove_fragment(self, shard: int) -> bool:
         """Drop a fragment and its on-disk file — the relinquish half of a

@@ -42,6 +42,7 @@ from pilosa_tpu.core import (
     Field,
     Index,
 )
+from pilosa_tpu.core.fragment import _pad_rows
 from pilosa_tpu.core.timequantum import views_by_time_range
 from pilosa_tpu.pql import Call, Condition, coerce_timestamp
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD
@@ -351,8 +352,6 @@ class StackCache:
     def _projected_rows(view, shards: list[int]) -> int:
         """Padded stack height WITHOUT materializing any host matrix —
         the over-budget check must not itself allocate O(R·W)."""
-        from pilosa_tpu.core.fragment import _pad_rows
-
         n = 1
         for s in shards:
             frag = view.fragment(s) if view else None
@@ -769,10 +768,17 @@ class StackCache:
         self, idx: Index, field: Field, view_name: str, shards: list[int]
     ) -> bool:
         """Would this field's dense stack exceed the budget (i.e. do its
-        rows serve through the tiered/hot layer)?  O(S) metadata scan,
-        no allocation — the router's residency probe."""
+        rows serve through the tiered/hot layer)?  No allocation — the
+        router's residency probe, once per touched field per query.
+        Over the index's own scope (``Index.shard_scope``, told by
+        identity: it covers every fragment the view has) the height is
+        the view's memoized ``max_rows`` and nothing is walked; an
+        explicit shard list is scanned, O(its length)."""
         view = field.view(view_name)
-        r_pad = self._projected_rows(view, shards)
+        if view is not None and shards is idx.shard_scope():
+            r_pad = _pad_rows(view.max_rows())
+        else:
+            r_pad = self._projected_rows(view, shards)
         need = len(shards) * r_pad * WORDS_PER_SHARD * 4
         return need > self.STACK_BYTES_BUDGET
 

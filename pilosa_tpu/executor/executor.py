@@ -377,7 +377,7 @@ class Executor:
             raise ExecutionError(f"index {index_name!r} not found")
         calls = parse(query) if isinstance(query, str) else query
         prof = tracing.current_profile()
-        prof_shards: list[int] | None = None
+        prof_shards: tuple[int, ...] | None = None
         if routes is None:
             routes = self._routes_for(idx, index_name, query, calls, shards)
         results = []
@@ -494,11 +494,17 @@ class Executor:
             p.resolve_fetched()
         return elapsed
 
-    def _shards(self, idx: Index, shards: list[int] | None) -> list[int]:
+    def _shards(
+        self, idx: Index, shards: "list[int] | tuple[int, ...] | None"
+    ) -> tuple[int, ...]:
+        """The shard scope of one call: an explicit list (``shards=``,
+        ``Options(shards=...)``) sorted, else the index's own memoized
+        tuple (``Index.shard_scope``: the same object until a write
+        moves the index's stamp, so this costs one comparison whatever
+        the shard count).  Read-only by type."""
         if shards is not None:
-            return sorted(shards)
-        avail = idx.available_shards()
-        return sorted(avail) if avail else [0]
+            return tuple(sorted(shards))
+        return idx.shard_scope() or (0,)
 
     # ------------------------------------------------------------ routing
     def _route(self, idx: Index, call: Call, shards: list[int] | None):
@@ -512,7 +518,13 @@ class Executor:
         programs) the explicit-SPMD mesh path.  The trailing elements
         carry the decision INPUTS forward so the settle-time audit and
         EXPLAIN can rebuild every candidate's cost without re-walking
-        the tree."""
+        the tree.
+
+        Cost: the call tree alone.  The shard count is the length of the
+        index's memoized scope and a TopN's row count its view's
+        memoized ``max_rows``; neither walks a fragment until a write
+        moves the index's stamp, so a route costs the same at 8 shards
+        and at 954."""
         c, sh = call, shards
         while c.name == "Options" and len(c.children) == 1:
             sh = c.arg("shards", sh)
@@ -521,7 +533,7 @@ class Executor:
             return None, 0, False, 0
         if c.name == "Rows":
             return "host", 0, False, 0
-        n = len(sh) if sh is not None else max(1, len(idx.available_shards()))
+        n = len(sh) if sh is not None else max(1, len(idx.shard_scope()))
         work = estimate_words(idx, c, n)
         if self.router.mode in ("host", "device"):
             # pinned modes never consult mesh eligibility or the cold-row
@@ -770,7 +782,7 @@ class Executor:
                 "route": "host",
                 "note": "metadata-only call; always served host-side",
             }
-        n = len(sh) if sh is not None else max(1, len(idx.available_shards()))
+        n = len(sh) if sh is not None else max(1, len(idx.shard_scope()))
         work = estimate_words(idx, c, n)
         res_detail: list = []
         tiered, cold_words = self._residency_info(idx, c, sh, detail=res_detail)

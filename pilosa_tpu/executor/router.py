@@ -542,12 +542,9 @@ def _field_rows(idx, name: str | None) -> int:
     if f is None:
         return 1
     view = f.view(VIEW_STANDARD)
-    if view is None:
-        return 1
-    n = 1
-    for frag in view.fragments.values():
-        n = max(n, frag.n_rows())
-    return n
+    # memoized per view against its version (View.max_rows): no walk
+    # over the fragments until a write moves the view
+    return view.max_rows() if view is not None else 1
 
 
 def _call_field_name(call: Call) -> str | None:

@@ -107,24 +107,24 @@ def fetch_wave(pending: "list[_Pending]") -> None:
         ]
 
 
-def stack_token(idx) -> tuple:
-    """Mutation stamp for single-flight dedup: every write bumps its
-    view's version with a globally monotone counter (core/view.py), so
-    two identical queries may share one execution ONLY while their
-    tokens agree — a mutation between them forces the later query onto
-    its own execution (read-your-writes across the dedup).
+def stack_token(idx) -> int:
+    """Mutation stamp for single-flight dedup: two identical queries may
+    share one execution ONLY while their tokens agree — a mutation
+    between them forces the later query onto its own execution
+    (read-your-writes across the dedup).
 
-    Cost: O(fields × views) per batchable enqueue — microseconds for
-    realistic schemas (tens of fields, 1-2 views each). If schemas ever
-    grow to thousands of fields, maintain a per-INDEX max stamp in
-    View._bump_version instead and read it here in O(1)."""
-    tok, n = 0, 0
-    for f in list(idx.fields.values()):
-        for v in list(f.views.values()):
-            n += 1
-            if v.version > tok:
-                tok = v.version
-    return (tok, n)
+    The token is the index's own stamp (``Index.stamp``,
+    core/view.py): every ``View._bump_version`` — every fragment
+    mutation, creation and removal — raises it before the write
+    returns, with a value drawn from the views' global counter
+    (monotone, never replayed by a recreated index).  Nothing changes
+    an index's answers without a bump: a field CANNOT be deleted
+    without one (``Index.delete_field`` raises the stamp itself), and a
+    field or view just created holds no fragment, so no view count rides
+    in the token.
+
+    Cost: one attribute read, whatever the schema or the shard count."""
+    return idx.stamp.value
 
 
 def canonical_calls(calls) -> tuple:

@@ -371,18 +371,15 @@ class API:
         if cache is not None:
             cache.invalidate(index)
 
-    def mutation_stamp(self, index: str) -> tuple | None:
-        """The index's current view-version mutation stamp — the SAME
-        stack token single-flight dedup keys on (executor/scheduler.py),
-        read here for the workload plane's cachability estimate
+    def mutation_stamp(self, index: str) -> int | None:
+        """The index's current mutation stamp — the SAME stack token
+        single-flight dedup keys on (executor/scheduler.py), read here
+        for the workload plane's cachability estimate
         (docs/workload.md): a repeated fingerprint whose stamp is
         unchanged between repeats is exactly a query a mutation-stamped
         result cache would have served from cache.  None when the index
-        is gone (the settle races a delete).  Cost: the same
-        O(fields × views) walk stack_token documents — microseconds on
-        realistic schemas; if schemas grow to thousands of fields, take
-        the per-index max-stamp O(1) upgrade described there and both
-        callers get it."""
+        is gone (the settle races a delete).  Cost: one attribute read
+        (``Index.stamp``)."""
         idx = self.holder.index(index)
         if idx is None:
             return None

@@ -223,7 +223,7 @@ class ResultCache:
     ) -> _Entry | None:
         """Loop-thread fast path (server/eventloop.py): raw request →
         settled entry, or None when the worker path must run.  Pure
-        CPU — two dict lookups plus the stack-token walk, NO parsing
+        CPU — two dict lookups plus the stack-token read, NO parsing
         (the worker path's ``memoize_pql`` populated the keyer) — so it
         is legal inside the event loop's coroutine (the asyncpurity
         rule bans blocking calls, not dict lookups)."""

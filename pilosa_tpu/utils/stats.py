@@ -59,6 +59,7 @@ _METRIC_HELP = {
     "queries_served": "read legs this node executed",
     "queries_deduped": "queries answered by single-flight dedup",
     "scheduler_wakeups_total": "wake-ups of the wave scheduler's waiters, by reason",
+    "shard_scope_rebuilds_total": "rebuilds of an index's memoized shard tuple (its mutation stamp moved)",
     "queries_partial": "queries answered with partial results",
     "queries_rejected": "requests shed by admission control",
     "queries_per_wave": "occupancy of cross-query device waves",

@@ -25,6 +25,7 @@ import pytest
 
 from pilosa_tpu.core import Holder
 from pilosa_tpu.core.field import FIELD_INT, FieldOptions
+from pilosa_tpu.core.view import IndexStamp
 from pilosa_tpu.executor import Executor, RowResult
 from pilosa_tpu.executor.scheduler import WaveScheduler, stack_token
 from pilosa_tpu.shardwidth import SHARD_WIDTH
@@ -260,20 +261,73 @@ def test_single_flight_dedup_shares_one_execution():
     assert counters.get("queries_deduped") == 3
 
 
-def test_dedup_stack_token_moves_on_mutation():
+# Every kind of write the index takes: each lands it and returns how far
+# it moved Count(Row(f=1)).  Each bumps its view and, through it, the
+# index's stamp BEFORE it returns, which is what the dedup key reads.
+FREE_COL = int(2 * SHARD_WIDTH - 1)
+
+
+def _w_set_bit(h, e):
+    h.index("b").field("f").set_bit(1, FREE_COL)
+    return 1
+
+
+def _w_pql_set(h, e):
+    e.execute("b", f"Set({FREE_COL}, f=1)")
+    return 1
+
+
+def _w_pql_clear(h, e):
+    col = e.execute("b", "Row(f=1)")[0].columns()[0]
+    e.execute("b", f"Clear({col}, f=1)")
+    return -1
+
+
+def _w_bulk_import(h, e):
+    h.index("b").field("f").import_bulk(
+        np.array([1, 1], dtype=np.uint64),
+        np.array([FREE_COL, FREE_COL - 1], dtype=np.uint64),
+    )
+    return 2
+
+
+def _w_new_fragment(h, e):
+    h.index("b").field("f").set_bit(1, 2 * SHARD_WIDTH + 5)
+    return 1
+
+
+def _w_remove_fragment(h, e):
+    gone = e.execute("b", "Count(Row(f=1))", shards=[1])[0]
+    assert h.index("b").field("f").view("standard").remove_fragment(1)
+    return -gone
+
+
+WRITES = {
+    fn.__name__[3:]: fn
+    for fn in (_w_set_bit, _w_pql_set, _w_pql_clear, _w_bulk_import,
+               _w_new_fragment, _w_remove_fragment)
+}
+
+
+@pytest.mark.parametrize("write", sorted(WRITES))
+def test_dedup_stack_token_moves_on_mutation(write):
     h, e, sched, _stats = make_rig()
     idx = h.index("b")
     before = stack_token(idx)
-    e.execute("b", "Set(1, f=1)")
+    assert stack_token(idx) == before  # reads leave it alone
+    e.execute("b", "Count(Row(f=1))")
+    assert stack_token(idx) == before
+    WRITES[write](h, e)
     assert stack_token(idx) > before
 
 
-def test_dedup_not_joined_across_mutation():
-    """A query submitted AFTER a write must not join an identical
-    pre-write in-flight execution: the stack token in the dedup key
-    forces a fresh execution that sees the write."""
+@pytest.mark.parametrize("write", sorted(WRITES))
+def test_dedup_not_joined_across_mutation(write):
+    """A query submitted AFTER a write's acknowledgement must not join
+    an identical pre-write in-flight execution: the index's stamp in
+    the dedup key forces a fresh execution that sees the write,
+    whatever kind of write it was."""
     h, e, sched, _stats = make_rig()
-    idx = h.index("b")
     pql = "Count(Row(f=1))"
     base = e.execute("b", pql)[0]
     gate = threading.Event()
@@ -288,19 +342,18 @@ def test_dedup_not_joined_across_mutation():
             assert gate.wait(30)
         return orig(*a, **k)
 
-    e.dispatch = blocking_dispatch
     res: dict = {}
+    e.dispatch = blocking_dispatch
     t1 = threading.Thread(
         target=lambda: res.__setitem__("a", sched.execute("b", pql)[0]),
         daemon=True,
     )
     t1.start()
     assert entered.wait(30)  # prime is mid-dispatch, not sealed
-    # land a write that adds a NEW column to f=1 (bumps the view version)
-    free_col = int(2 * SHARD_WIDTH - 1)
-    f = idx.field("f")
-    f.set_bit(1, free_col)
-    idx.mark_columns_exist(np.array([free_col], dtype=np.uint64))
+    # land the write; the direct executor below must not be gated
+    e.dispatch = orig
+    moved = WRITES[write](h, e)
+    e.dispatch = blocking_dispatch
     t2 = threading.Thread(
         target=lambda: res.__setitem__("b", sched.execute("b", pql)[0]),
         daemon=True,
@@ -311,8 +364,8 @@ def test_dedup_not_joined_across_mutation():
     t1.join(30)
     t2.join(30)
     assert len(calls) == 2, "post-write query must not share the execution"
-    assert res["b"] == base + 1
-    assert res["a"] in (base, base + 1)  # racing write: either order legal
+    assert moved != 0 and res["b"] == base + moved
+    assert res["a"] in (base, base + moved)  # racing write: either order legal
 
 
 def test_host_routed_and_writes_bypass_waves():
@@ -563,7 +616,7 @@ class StubExecutor:
     released, as a device launch does."""
 
     class _Index:
-        fields: dict = {}
+        stamp = IndexStamp()  # what stack_token reads
 
     def __init__(self, dispatch_s=0.0002):
         self.holder = self
