@@ -92,6 +92,17 @@ def named_jit(name: str, fn: Callable) -> Callable:
     return jax.jit(program)
 
 
+def range_suffix(skey: str) -> str:
+    """``"_range"`` where the plan behind the structure key ``skey`` has a
+    BSI comparison leaf (``_Planner._plan_condition``), else ``""``: the
+    programs that scan an int field's bit slices carry it in their name
+    (``pilosa_count_range``), so the device trace and the compile
+    counter tell the bit-sliced scan from the two-row popcount. Field
+    names hold neither ``[`` nor ``(``, so only the leaf's own key
+    matches."""
+    return "_range" if "cmp[" in skey or "between(" in skey else ""
+
+
 # --------------------------------------------------------------- stacking
 def stack_view_matrices(view, shards: list[int]) -> tuple[np.ndarray, int]:
     """Stack a view's fragment host matrices → (np uint32[R, S, W], R).
@@ -1217,6 +1228,13 @@ class _Planner:
         self.scalars.append(int(value))
         return len(self.scalars) - 1
 
+    def _add_constant(self, value: int) -> int:
+        """A BSI comparison constant as ``ops.bsi.CONSTANT_WORDS``
+        consecutive scalars; → the index of the first."""
+        first = len(self.scalars)
+        self.scalars.extend(ops.bsi.constant_words(int(value)).tolist())
+        return first
+
     def _matrix_leaf(self, field: Field, view_name: str, row_id: int):
         """closure(arrays, scalars) → uint32[S, W] for one stored row.
 
@@ -1553,18 +1571,32 @@ class _Planner:
                 ), f"isnull({bkey})"
             raise PlanError(f"null only supports ==/!= comparisons, got {op!r}")
 
-        # vmap over the shard axis (axis 1 of the [D, S, W] block)
-        vmapped_between = jax.vmap(ops.bsi.between, in_axes=(1, None, None))
-        vmapped_cmp = jax.vmap(ops.bsi.compare, in_axes=(1, None, None))
+        # The constants are traced operands, like a row id: the structure
+        # key holds the operator and never a value, so every threshold
+        # runs the one compiled program (ops.bsi.constant_words).
+        at = [
+            self._add_constant(b)
+            for b in (value if op == "between" else [value])
+        ]
+        if self.stacks.stats is not None:
+            self.stacks.stats.count(
+                "bsi_condition_leaves_total", tags={"op": op}
+            )
+
+        def words(scalars, k):
+            return scalars[at[k] : at[k] + ops.bsi.CONSTANT_WORDS]
+
         if op == "between":
-            lo, hi = int(value[0]), int(value[1])
             return (
-                lambda arrays, scalars: vmapped_between(bsi(arrays, scalars), lo, hi)
-            ), f"between[{lo},{hi}]({bkey})"
-        v = int(value)
+                lambda arrays, scalars: ops.bsi.between(
+                    bsi(arrays, scalars), words(scalars, 0), words(scalars, 1)
+                )
+            ), f"between({bkey})"
         return (
-            lambda arrays, scalars: vmapped_cmp(bsi(arrays, scalars), op, v)
-        ), f"cmp[{op}{v}]({bkey})"
+            lambda arrays, scalars: ops.bsi.compare(
+                bsi(arrays, scalars), op, words(scalars, 0)
+            )
+        ), f"cmp[{op}]({bkey})"
 
     def _row_id(self, field: Field, row: Any) -> int:
         if isinstance(row, bool):
@@ -1601,7 +1633,7 @@ class QueryCompiler:
         # owner; the executor's router picks which one a call runs on.
         from pilosa_tpu.executor.hostpath import HostEngine
 
-        self.host = HostEngine()
+        self.host = HostEngine(stats=stats)
         # the MESH compilation layer: explicit shard_map programs with
         # psum reduction trees over the (shards × words) mesh — the
         # router's third path (docs/spmd.md). Only attached for a real
@@ -1619,10 +1651,19 @@ class QueryCompiler:
         Dispatching with a fresh numpy array uploads it host→device on
         every call — pure overhead next to the compute. Repeated
         queries, the common serving case, hit this cache and dispatch
-        with zero transfers."""
+        with zero transfers. Where the values do NOT repeat (row ids of
+        a wide field, or BSI comparison constants, which travel here
+        since they became operands: a fare slider sends another
+        threshold every time) nearly every call misses and pays one
+        small ``device_put`` on the calling thread, the wave leader's
+        under the scheduler, and the cache turns over every 4,096
+        queries. Each miss is counted (``device_scalar_uploads_total``);
+        over ``queries_routed`` that is uploads per query."""
         key = tuple(values)
         cached = self._scalar_arrays.get(key)
         if cached is None:
+            if self.stacks.stats is not None:
+                self.stacks.stats.count("device_scalar_uploads_total")
             if len(self._scalar_arrays) >= 4096:
                 # tiny (≤ a few hundred bytes each); drop-all beats LRU
                 # bookkeeping on the hot path, rebuild is one upload
@@ -1642,6 +1683,16 @@ class QueryCompiler:
                 cached = jnp.asarray(host)
             self._scalar_arrays[key] = cached
         return cached
+
+    def cache_snapshot(self) -> dict:
+        """/debug/vars ``stackCache``: the stack cache's counters and,
+        beside them, the number of programs this compiler holds. The
+        program cache has no bound: a key that varies with the data
+        (as a BSI constant did until it became an operand) shows here as
+        a number that climbs with the traffic."""
+        out = self.stacks.stats_snapshot()
+        out["programs"] = len(self._programs)
+        return out
 
     def program(self, key: tuple, build: Callable[[], Callable]) -> Callable:
         """Generic compiled-program cache (used by the executor for its
@@ -1714,7 +1765,7 @@ class QueryCompiler:
                 words = run(arrays, scalars)
                 return jnp.sum(ops.popcount_rows(words).astype(jnp.int64))
 
-            return named_jit("pilosa_count", count)
+            return named_jit("pilosa_count" + range_suffix(skey), count)
 
         prog = self.program(key, build)
         arrays = planner.materialize()

@@ -48,6 +48,7 @@ from pilosa_tpu.executor.compile import (
     StackOverBudget,
     _stack_budget,
     named_jit,
+    range_suffix,
 )
 from pilosa_tpu.executor.hostpath import HostPlanError
 from pilosa_tpu.executor.router import QueryRouter, estimate_words
@@ -1157,7 +1158,7 @@ class Executor:
                 pos, neg, n = self.compiler.run_program(
                     ("sum", len(shards), field.bit_depth, fskey),
                     lambda: named_jit(
-                        "pilosa_sum_filtered",
+                        "pilosa_sum_filtered" + range_suffix(fskey),
                         lambda s, fa, fs: self._sum_fn(s, frun(fa, fs)),
                     ),
                     slices,
@@ -1406,7 +1407,7 @@ class Executor:
                 counts = self.compiler.run_program(
                     ("topn", len(shards), fskey),
                     lambda: named_jit(
-                        "pilosa_topn_filtered",
+                        "pilosa_topn_filtered" + range_suffix(fskey),
                         lambda m, fa, fs: ops.popcount_rows(
                             m & frun(fa, fs)[None]
                         )

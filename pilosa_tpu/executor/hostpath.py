@@ -416,10 +416,13 @@ class HostPlanner:
     translation, time-range view resolution) — such plans are rebuilt
     per query, exactly like the device planner always is."""
 
-    def __init__(self, idx: Index, shards: list[int], stacks: HostStacks):
+    def __init__(
+        self, idx: Index, shards: list[int], stacks: HostStacks, stats=None
+    ):
         self.idx = idx
         self.shards = shards
         self.stacks = stacks
+        self.stats = stats  # counts the BSI condition leaves planned
         self.cacheable = True
         self.fields: list[tuple[str, Field]] = []  # identity validation
 
@@ -613,6 +616,8 @@ class HostPlanner:
             raise HostPlanError(
                 f"null only supports ==/!= comparisons, got {op!r}"
             )
+        if self.stats is not None:
+            self.stats.count("bsi_condition_leaves_total", tags={"op": op})
         if op == "between":
             lo, hi = int(value[0]), int(value[1])
             return self._bsi_apply(field, lambda b: bsi_between(b, lo, hi))
@@ -646,7 +651,8 @@ class HostEngine:
     # transient-tensor chunk bound for host GroupBy mask/count batches
     GB_CHUNK_BYTES = 256 << 20
 
-    def __init__(self):
+    def __init__(self, stats=None):
+        self.stats = stats
         self.stacks = HostStacks()
         self._plans: "OrderedDict[tuple, tuple]" = OrderedDict()
         self._lock = threading.Lock()
@@ -670,7 +676,7 @@ class HostEngine:
                     self._plans.move_to_end(key)
                     return run
                 del self._plans[key]
-        planner = HostPlanner(idx, shards, self.stacks)
+        planner = HostPlanner(idx, shards, self.stacks, self.stats)
         run = planner.plan(call)
         if planner.cacheable:
             with self._lock:

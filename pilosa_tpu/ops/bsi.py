@@ -35,61 +35,118 @@ OFFSET_ROW = 2
 _ONES = np.uint32(0xFFFFFFFF)
 
 
-def _magnitude_cmp(mag: jax.Array, c_abs: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+# A comparison constant travels to the program as DATA, like a row id
+# (executor/compile.py ``_add_scalar``): ``CONSTANT_WORDS`` int32 words,
+# the same layout whatever the field's depth, so one compiled program
+# serves every threshold. Word 0 holds the flags, words 1..3 hold |c| in
+# 21-bit chunks, LSB first: 63 magnitude bits, every word a non-negative
+# int32 (nothing to reinterpret, exact at every depth an int64 field can
+# have).
+CONSTANT_WORDS = 4
+_CHUNK_BITS = 21
+_CHUNK_MASK = (1 << _CHUNK_BITS) - 1
+_CHUNKS = CONSTANT_WORDS - 1
+_MAG_BITS = _CHUNKS * _CHUNK_BITS
+_FLAG_NEGATIVE = 1
+_FLAG_HUGE = 2  # |c| >= 2**63: beyond every depth
+
+
+def constant_words(value: int) -> np.ndarray:
+    """Host encoding of a comparison constant → int32[CONSTANT_WORDS]."""
+    value = int(value)
+    mag = abs(value)
+    flags = _FLAG_NEGATIVE if value < 0 else 0
+    if mag >> _MAG_BITS:
+        flags |= _FLAG_HUGE
+        mag = 0
+    return np.array(
+        [flags]
+        + [(mag >> (j * _CHUNK_BITS)) & _CHUNK_MASK for j in range(_CHUNKS)],
+        dtype=np.int32,
+    )
+
+
+def _as_words(value) -> jax.Array:
+    """A Python int is encoded here (a constant of the program that
+    traces it); anything else is ``constant_words``' vector already."""
+    if isinstance(value, (int, np.integer)):
+        return jnp.asarray(constant_words(value))
+    return value
+
+
+def _mask(flag) -> jax.Array:
+    """0/1 scalar → all-zeros / all-ones uint32 scalar."""
+    return jnp.uint32(0) - flag.astype(jnp.uint32)
+
+
+def _beyond(words: jax.Array, depth: int) -> jax.Array:
+    """bool scalar: |c| >= 2**depth, no stored magnitude reaches it."""
+    out = (words[0] & _FLAG_HUGE) != 0
+    for j in range(_CHUNKS):
+        lo = j * _CHUNK_BITS
+        if depth <= lo:
+            out = out | (words[1 + j] != 0)
+        elif depth < lo + _CHUNK_BITS:
+            out = out | ((words[1 + j] >> (depth - lo)) != 0)
+    return out
+
+
+def _magnitude_cmp(mag: jax.Array, words: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Per-column compare of magnitude slices vs constant |c|.
 
-    ``mag``: uint32[depth, W], LSB-first. Returns (eq, lt, gt) word masks.
-    Classic MSB→LSB bit-sliced comparison (O'Neil/Quass); the loop unrolls
-    at trace time.
+    ``mag``: uint32[depth, ...], LSB-first; ``words``: the encoded
+    constant. Returns (eq, lt, gt) word masks. Classic MSB→LSB bit-sliced
+    comparison (O'Neil/Quass); the loop unrolls at trace time, and the
+    constant's bit is a SELECT (a broadcast scalar mask), not a branch:
+    where stored bit and constant bit differ among the columns still
+    equal, the constant's bit says which side they fall on.
     """
-    depth, w = mag.shape
-    eq = jnp.full((w,), _ONES)
-    lt = jnp.zeros((w,), jnp.uint32)
-    gt = jnp.zeros((w,), jnp.uint32)
+    depth, shape = mag.shape[0], mag.shape[1:]
+    eq = jnp.full(shape, _ONES)
+    lt = jnp.zeros(shape, jnp.uint32)
     for k in range(depth - 1, -1, -1):
-        bit = mag[k]
-        if (c_abs >> k) & 1:
-            lt = lt | (eq & ~bit)
-            eq = eq & bit
+        if k < _MAG_BITS:
+            c = _mask((words[1 + k // _CHUNK_BITS] >> (k % _CHUNK_BITS)) & 1)
         else:
-            gt = gt | (eq & bit)
-            eq = eq & ~bit
-    return eq, lt, gt
+            c = jnp.uint32(0)
+        differ = eq & (mag[k] ^ c)
+        lt = lt | (differ & c)
+        eq = eq & ~differ
+    # |c| beyond the depth: nothing equal or greater, every stored
+    # magnitude is smaller
+    beyond = _mask(_beyond(words, depth))
+    eq = eq & ~beyond
+    lt = lt | beyond
+    return eq, lt, ~(eq | lt)
 
 
 @jax.named_scope("pilosa.bsi_compare")
-def compare(slices: jax.Array, op: str, value: int) -> jax.Array:
-    """Columns whose stored value ⟨op⟩ ``value`` → uint32[W] mask.
+def compare(slices: jax.Array, op: str, value) -> jax.Array:
+    """Columns whose stored value ⟨op⟩ ``value`` → uint32 mask, shaped
+    as one slice (``[W]`` for a ``[2 + depth, W]`` block, ``[S, W]`` for
+    a stacked one: every step is elementwise).
 
-    ``op`` ∈ {"==", "!=", "<", "<=", ">", ">="}. The caller intersects the
-    result with its row filter; existence is applied here.
+    ``op`` ∈ {"==", "!=", "<", "<=", ">", ">="} is static. ``value`` is
+    ``constant_words(c)``, traced: the constant, its sign and "beyond
+    the field's depth" are operands of the program, not part of it (a
+    Python int is accepted and encoded in place). The caller intersects
+    the result with its row filter; existence is applied here.
     """
+    words = _as_words(value)
     exists = slices[EXISTS_ROW]
     sign = slices[SIGN_ROW]
     mag = slices[OFFSET_ROW:]
     pos = exists & ~sign
     neg = exists & sign
-    c_abs = abs(value)
-    if c_abs >= 1 << mag.shape[0]:
-        # |c| exceeds every representable magnitude: nothing equal/greater,
-        # every stored magnitude is smaller
-        w = mag.shape[1]
-        eq_m = jnp.zeros((w,), jnp.uint32)
-        gt_m = jnp.zeros((w,), jnp.uint32)
-        lt_m = jnp.full((w,), _ONES)
-    else:
-        eq_m, lt_m, gt_m = _magnitude_cmp(mag, c_abs)
+    eq_m, lt_m, gt_m = _magnitude_cmp(mag, words)
+    negative = (words[0] & _FLAG_NEGATIVE) != 0
 
-    if value >= 0:
-        eq = pos & eq_m
-        # v < c: every negative, plus positives with smaller magnitude
-        lt = neg | (pos & lt_m)
-        gt = pos & gt_m
-    else:
-        eq = neg & eq_m
-        # v < c (c negative): negatives with larger magnitude
-        lt = neg & gt_m
-        gt = pos | (neg & lt_m)
+    # unused masks are dead code to XLA: each operator pays for its own
+    eq = jnp.where(negative, neg, pos) & eq_m
+    # v < c, c >= 0: every negative, plus positives with smaller magnitude;
+    # c < 0: negatives with larger magnitude
+    lt = jnp.where(negative, neg & gt_m, neg | (pos & lt_m))
+    gt = jnp.where(negative, pos | (neg & lt_m), pos & gt_m)
 
     if op == "==":
         return eq
@@ -106,8 +163,10 @@ def compare(slices: jax.Array, op: str, value: int) -> jax.Array:
     raise ValueError(f"bad BSI comparison op {op!r}")
 
 
-def between(slices: jax.Array, lo: int, hi: int) -> jax.Array:
-    """Columns with lo <= value <= hi (PQL Range/between) → uint32[W]."""
+def between(slices: jax.Array, lo, hi) -> jax.Array:
+    """Columns with lo <= value <= hi (PQL Range/between) → uint32 mask.
+    Both bounds as ``compare``'s ``value``; traced together they are one
+    fused pass over the stack."""
     return compare(slices, ">=", lo) & compare(slices, "<=", hi)
 
 

@@ -938,7 +938,7 @@ class Handler(BaseHTTPRequestHandler):
         # device-cache effectiveness counters (tests assert the write
         # path stays incremental; operators read them here)
         out["stackCache"] = snapshot_envelope(
-            self.api.executor.compiler.stacks.stats_snapshot()
+            self.api.executor.compiler.cache_snapshot()
         )
         # tiered compressed residency: container tiers, hot/cold row
         # promotion + demotion, per-container resident bytes

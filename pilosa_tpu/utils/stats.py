@@ -60,6 +60,8 @@ _METRIC_HELP = {
     "queries_deduped": "queries answered by single-flight dedup",
     "scheduler_wakeups_total": "wake-ups of the wave scheduler's waiters, by reason",
     "shard_scope_rebuilds_total": "rebuilds of an index's memoized shard tuple (its mutation stamp moved)",
+    "bsi_condition_leaves_total": "BSI comparison leaves planned, by operator",
+    "device_scalar_uploads_total": "misses of the device operand-vector cache (one small upload each)",
     "queries_partial": "queries answered with partial results",
     "queries_rejected": "requests shed by admission control",
     "queries_per_wave": "occupancy of cross-query device waves",
