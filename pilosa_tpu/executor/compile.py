@@ -1429,10 +1429,7 @@ class _Planner:
         )
 
         def run(arrays, scalars):
-            m = arrays[ai]
-            if m.shape[0] < need:
-                m = jnp.pad(m, ((0, need - m.shape[0]), (0, 0), (0, 0)))
-            return m[:need]
+            return ops.bsi.block(arrays[ai], need)
 
         return run, f"bsi({field.name}:{field.bit_depth})"
 

@@ -1075,13 +1075,17 @@ class Executor:
         return run, arrays, scalars, skey
 
     def _bsi_stacked(self, idx: Index, field: Field, shards: list[int]):
-        """uint32[D, S, W] bit-slice block for an int field (device,
-        row-major like every stack). Over-budget BSI stacks assemble
-        from tiered compressed slice rows in tiered residency mode
+        """uint32[R, S, W] bit-slice stack of an int field AS IT LIES IN
+        DEVICE MEMORY (row-major like every stack): R is the resident
+        stack's padded height, not the field's declared depth, and no
+        device operation runs here. The depth rule is ``ops.bsi.block``,
+        applied inside the program that reads the stack (``_sum_fn``,
+        ``_minmax_fn``). Over-budget BSI stacks assemble from tiered
+        compressed slice rows in tiered residency mode
         (docs/device-residency.md); the legacy slots mode surfaces the
         budget error clearly as before."""
         try:
-            m, _rows = self.compiler.stacks.matrix(idx, field, VIEW_BSI, shards)
+            return self.compiler.stacks.matrix(idx, field, VIEW_BSI, shards)[0]
         except StackOverBudget as e:
             if self.compiler.stacks.residency_mode() == "slots":
                 raise ExecutionError(str(e)) from e
@@ -1089,35 +1093,57 @@ class Executor:
                 return self.compiler.tiered_bsi_block(idx, field, shards)
             except StackOverBudget as e2:
                 raise ExecutionError(str(e2)) from e2
-        need = BSI_OFFSET + field.bit_depth
-        if m.shape[0] < need:
-            m = jnp.pad(m, ((0, need - m.shape[0]), (0, 0), (0, 0)))
-        return m[:need]
 
     # ------------------------------------------------------- aggregates
     @staticmethod
-    def _sum_fn(s, f):
-        """(slices [D,S,W], filt [S,W]) → (pos[D], neg[D], n) — the ONE
-        BSI-sum reduction body; Sum jits it directly and GroupBy's
-        aggregate wraps it in a group vmap so the two stay in sync.
-        vmap over the shard axis (axis 1 of the row-major block)."""
-        return tuple(
-            x.astype(jnp.int64).sum(axis=0)
-            for x in jax.vmap(ops.bsi.sum_counts, in_axes=(1, 0))(s, f)
-        )
+    def _sum_fn(field: Field):
+        """→ ``(stack [R,S,W], filt [S,W]) → (pos[D], neg[D], n)``, the
+        ONE BSI-sum reduction body of ``field``: Sum jits it directly,
+        GroupBy's aggregate wraps it in a group vmap and the mesh trees
+        run it inside their shard_map, so all stay in sync. It takes the
+        resident stack and applies the depth rule itself; D is the depth
+        of the planes it read, which ``weigh_sum`` takes from the
+        arrays. vmap over the shard axis (axis 1 of the row-major
+        block)."""
+        need = BSI_OFFSET + field.bit_depth
+
+        def sum_fn(s, f):
+            return tuple(
+                x.astype(jnp.int64).sum(axis=0)
+                for x in jax.vmap(ops.bsi.sum_counts, in_axes=(1, 0))(
+                    ops.bsi.block(s, need), f
+                )
+            )
+
+        return sum_fn
+
+    @staticmethod
+    def _minmax_fn(field: Field, want_max: bool):
+        """→ ``(stack [R,S,W], filt [S,W]) → (values[S], counts[S])``,
+        the per-shard Min/Max body, on the same terms as ``_sum_fn``."""
+        need = BSI_OFFSET + field.bit_depth
+
+        def minmax_fn(s, f):
+            return jax.vmap(
+                lambda ss, ff: ops.bsi.min_max(ss, ff, want_max=want_max),
+                in_axes=(1, 0),
+            )(ops.bsi.block(s, need), f)
+
+        return minmax_fn
 
     def _sum_program(self, field: Field, n_shards: int):
         return self.compiler.program(
             ("sum", n_shards, field.bit_depth),
-            lambda: named_jit("pilosa_sum", self._sum_fn),
+            lambda: named_jit("pilosa_sum", self._sum_fn(field)),
         )
 
     def _grouped_sum_program(self, field: Field, n_shards: int):
-        """(slices [D,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G])."""
+        """(stack [R,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G])."""
         return self.compiler.program(
             ("gb_sums", n_shards, field.bit_depth),
             lambda: named_jit(
-                "pilosa_sum_groups", jax.vmap(self._sum_fn, in_axes=(None, 0))
+                "pilosa_sum_groups",
+                jax.vmap(self._sum_fn(field), in_axes=(None, 0)),
             ),
         )
 
@@ -1138,7 +1164,8 @@ class Executor:
                 frun, farrays, fscalars, fskey = fplan
                 key = ("mesh_sum", len(shards), field.bit_depth, mode, fskey)
                 prog = self.compiler.program(
-                    key, lambda: eng.sum_tree(self._sum_fn, mode, frun=frun)
+                    key,
+                    lambda: eng.sum_tree(self._sum_fn(field), mode, frun=frun),
                 )
                 pos, neg, n = self.compiler._mesh_dispatch(
                     "sum", prog, slices, farrays, fscalars
@@ -1146,7 +1173,7 @@ class Executor:
             else:
                 key = ("mesh_sum", len(shards), field.bit_depth, mode)
                 prog = self.compiler.program(
-                    key, lambda: eng.sum_tree(self._sum_fn, mode)
+                    key, lambda: eng.sum_tree(self._sum_fn(field), mode)
                 )
                 pos, neg, n = self.compiler._mesh_dispatch(
                     "sum", prog, slices, self.compiler.ones(len(shards))
@@ -1155,12 +1182,17 @@ class Executor:
             fplan = self._filter_plan(idx, call, shards)
             if fplan is not None:
                 frun, farrays, fscalars, fskey = fplan
+
+                def build():
+                    sum_fn = self._sum_fn(field)
+                    return named_jit(
+                        "pilosa_sum_filtered" + range_suffix(fskey),
+                        lambda s, fa, fs: sum_fn(s, frun(fa, fs)),
+                    )
+
                 pos, neg, n = self.compiler.run_program(
                     ("sum", len(shards), field.bit_depth, fskey),
-                    lambda: named_jit(
-                        "pilosa_sum_filtered" + range_suffix(fskey),
-                        lambda s, fa, fs: self._sum_fn(s, frun(fa, fs)),
-                    ),
+                    build,
                     slices,
                     farrays,
                     fscalars,
@@ -1202,7 +1234,10 @@ class Executor:
                     mode, fskey,
                 )
                 prog = self.compiler.program(
-                    key, lambda: eng.minmax_tree(want_max, mode, frun=frun)
+                    key,
+                    lambda: eng.minmax_tree(
+                        self._minmax_fn(field, want_max), mode, frun=frun
+                    ),
                 )
                 values, counts = self.compiler._mesh_dispatch(
                     "minmax", prog, slices, farrays, fscalars
@@ -1213,17 +1248,17 @@ class Executor:
                     mode,
                 )
                 prog = self.compiler.program(
-                    key, lambda: eng.minmax_tree(want_max, mode)
+                    key,
+                    lambda: eng.minmax_tree(
+                        self._minmax_fn(field, want_max), mode
+                    ),
                 )
                 values, counts = self.compiler._mesh_dispatch(
                     "minmax", prog, slices,
                     self.compiler.ones(len(shards)),
                 )
         else:
-            vmapped = jax.vmap(
-                lambda ss, ff: ops.bsi.min_max(ss, ff, want_max=want_max),
-                in_axes=(1, 0),
-            )
+            vmapped = self._minmax_fn(field, want_max)
             fplan = self._filter_plan(idx, call, shards)
             if fplan is not None:
                 frun, farrays, fscalars, fskey = fplan
@@ -1690,7 +1725,9 @@ class Executor:
                 )
                 gsp = self.compiler.program(
                     gskey,
-                    lambda: eng.grouped_sum_tree(self._sum_fn, mesh_mode),
+                    lambda: eng.grouped_sum_tree(
+                        self._sum_fn(agg_field), mesh_mode
+                    ),
                 )
                 sum_prog = lambda s, m: self.compiler._mesh_dispatch(
                     "groupby", gsp, s, m

@@ -170,6 +170,24 @@ def between(slices: jax.Array, lo, hi) -> jax.Array:
     return compare(slices, ">=", lo) & compare(slices, "<=", hi)
 
 
+def block(m: jax.Array, need: int) -> jax.Array:
+    """THE depth rule of an int field's stack ``uint32[R, ...]`` against
+    the ``need = OFFSET_ROW + bit_depth`` planes its field declares.
+    Called at trace time INSIDE the consuming program, on the stack as
+    it lies in memory (stack heights pad to a power of two, so R is
+    seldom ``need``); never on a concrete array, where it would be a
+    device program of its own a query.
+
+    Deeper than declared: a static slice, which XLA fuses into the reads
+    (the planes left out are never fetched). Shallower: taken whole,
+    nothing padded. The planes it lacks hold no bit by construction (a
+    value that needs plane k makes the stack at least k + 1 deep), and
+    every kernel here takes its depth from the array: zeros add nothing
+    to ``sum_counts``, decide nothing in a ``min_max`` walk, and a
+    constant past the stack's depth is ``_beyond`` to ``compare``."""
+    return m[:need] if m.shape[0] > need else m
+
+
 @jax.named_scope("pilosa.bsi_sum")
 def sum_counts(slices: jax.Array, filt: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Per-magnitude-bit signed counts for Sum.

@@ -458,9 +458,11 @@ class MeshQueryEngine:
         return self._spmd("topn", local, (spec3,), P())
 
     def sum_tree(self, sum_fn, mode: str, frun=None):
-        """BSI Sum: (slices [D,S,W], filter) → (pos[D], neg[D], n),
-        replicated — ``sum_fn`` is Executor._sum_fn, THE one reduction
-        body (host/device/mesh stay in sync by construction)."""
+        """BSI Sum: (stack [R,S,W], filter) → (pos[D], neg[D], n),
+        replicated — ``sum_fn`` is Executor._sum_fn's, THE one reduction
+        body (device and mesh stay in sync by construction). It takes
+        the resident stack as placed (the plane axis is not sharded) and
+        applies the field's depth rule inside this shard_map body."""
         spec3 = self._arr_spec(1, mode)
         if frun is not None:
 
@@ -489,7 +491,7 @@ class MeshQueryEngine:
         )
 
     def grouped_sum_tree(self, sum_fn, mode: str):
-        """(slices [D,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G])
+        """(stack [R,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G])
         replicated — GroupBy's aggregate=Sum under the same psum tree."""
         spec3 = self._arr_spec(1, mode)
 
@@ -505,11 +507,13 @@ class MeshQueryEngine:
             "sum_groups", local, (spec3, spec3), (P(), P(), P())
         )
 
-    def minmax_tree(self, want_max: bool, mode: str, frun=None):
-        """BSI Min/Max: per-device per-shard extremes, all-gathered to a
-        replicated partial list the executor's finish() merges exactly
-        like per-shard device partials (min/max-with-count merges
-        associatively over disjoint column blocks).
+    def minmax_tree(self, minmax_fn, mode: str, frun=None):
+        """BSI Min/Max: per-device per-shard extremes (``minmax_fn`` is
+        Executor._minmax_fn's, the device route's body, on the resident
+        stack), all-gathered to a replicated partial list the executor's
+        finish() merges exactly like per-shard device partials
+        (min/max-with-count merges associatively over disjoint column
+        blocks).
 
         check_rep=False: all_gather's replication isn't statically
         inferred on the pinned jax — the gather of every block IS full
@@ -521,10 +525,7 @@ class MeshQueryEngine:
             return jax.lax.all_gather(v, AXIS_SHARDS).reshape(-1)
 
         def body(slices, filt):
-            vals, counts = jax.vmap(
-                lambda ss, ff: bsi_ops.min_max(ss, ff, want_max=want_max),
-                in_axes=(1, 0),
-            )(slices, filt)
+            vals, counts = minmax_fn(slices, filt)
             return gather_all(vals), gather_all(counts)
 
         if frun is not None:

@@ -94,6 +94,12 @@ def rig():
     cab.import_bulk(rng.integers(0, 5, n).astype(np.uint64), cols)
     pas.import_bulk(rng.integers(0, 8, n).astype(np.uint64), cols)
     fare.import_values(cols, rng.integers(0, 1 << 16, n))
+    # the benchmark's int field: 17 bits declared, 11 filled, so 13 of
+    # the 16 resident planes hold data and the field declares 19
+    amount = idx.create_field(
+        "amount", FieldOptions(field_type="int", min=0, max=(1 << 17) - 1)
+    )
+    amount.import_values(cols, rng.integers(0, 1 << 11, n))
     idx.mark_columns_exist(cols)
     e = Executor(h, route_mode="device")
     # GroupBy chunks its [G, S, W] group masks to an eighth of the stack
@@ -198,6 +204,31 @@ def test_bsi_sum_minmax_and_range(rig, one_chip, monkeypatch, pql):
     _h, _idx, e = rig
     for prog, args in record(monkeypatch, e, pql):
         compile_and_fit(prog, real_size(args, lambda _s: one_chip))
+
+
+def test_sum_reads_the_resident_stack_and_nothing_of_a_pad(rig, one_chip, monkeypatch):
+    """Q2 of the cell taxi-128.four_queries: ``pilosa_sum_filtered`` takes
+    the amount's stack as resident, [16, 128, W] under a field that
+    declares 19 planes, and the compiled program reads those 16 planes
+    once: no [19, S, W] block anywhere in it, no plane-sized temporary in
+    HBM. Bytes by XLA's own count: the 16 planes, and 7 more for the two
+    candidate masks (made from the existence, sign and filter rows,
+    written and read again by the reduction) and the count's two rows;
+    padded to the declared depth it read 26."""
+    _h, _idx, e = rig
+    calls = record(monkeypatch, e, "Sum(Row(passenger_count=2), field=amount)")
+    assert len(calls) == 1
+    prog, args = calls[0]
+    assert args[0].shape[0] == 16  # the tiny run's stack is as deep as the cell's
+    compiled = compile_and_fit(prog, real_size(args, lambda _s: one_chip, shards=128))
+    text = compiled.as_text()
+    assert "jit(pilosa_sum_filtered)" in text
+    assert "u32[16,128,32768]" in text and "u32[19," not in text and "pad(" not in text
+    plane = 128 * W * 4
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["bytes accessed"] <= 24 * plane, cost["bytes accessed"] / plane
+    assert compiled.memory_analysis().temp_size_in_bytes < plane
 
 
 def test_groupby_level_with_sum(rig, one_chip, monkeypatch):
@@ -310,14 +341,15 @@ def test_mesh_count_and_topn(rig, mesh):
 
 # the cell taxi-512x4.four_queries (benchmark/configs/taxi-512x4.json):
 # 512 shards over the four chips, dist_miles a 32-row stack, the amount's
-# BSI block 19 slices after _bsi_stacked's pad
+# BSI stack as resident: 16 planes under a field that declares 19, cut to
+# depth by ops.bsi.block inside the shard_map body
 S_CELL = 512
 
 
 @pytest.mark.parametrize("program", ["topn", "sum", "count"])
 def test_mesh_programs_at_the_four_chip_cells_shapes(rig, mesh, program):
     """Q4's TopN over [32, 512, W] under a two-row filter, Q2's Sum over
-    [19, 512, W] under a one-row filter and Q3's Count of an Intersect
+    [16, 512, W] under a one-row filter and Q3's Count of an Intersect
     compile for the 4 x 1 v5e mesh, 128 shards a chip, and their psum
     trees carry the scope the device trace is read by."""
     engine = MeshQueryEngine(mesh)
@@ -334,10 +366,12 @@ def test_mesh_programs_at_the_four_chip_cells_shapes(rig, mesh, program):
         args = (stack(32), farrays, fscalars)
     elif program == "sum":
         frun, (farrays, fscalars) = plan("Row(passenger_count=2)")
-        prog = engine.sum_tree(Executor._sum_fn, "grid", frun=frun)
-        args = (stack(19), farrays, fscalars)
+        amount = rig[1].field("amount")
+        prog = engine.sum_tree(Executor._sum_fn(amount), "grid", frun=frun)
+        args = (stack(16), farrays, fscalars)
     else:
         run, args = plan("Intersect(Row(cab_type=1), Row(passenger_count=2))")
         prog = engine.count_tree(run, "grid")
     text = compile_and_fit(prog, args, devices=4).as_text()
     assert "all-reduce" in text and "pilosa.mesh_psum" in text
+    assert "u32[19," not in text
