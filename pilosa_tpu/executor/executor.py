@@ -21,6 +21,8 @@ Redesigned for TPU:
 
 from __future__ import annotations
 
+import itertools
+import operator
 import os
 import threading
 import time
@@ -48,6 +50,7 @@ from pilosa_tpu.executor.compile import (
     StackOverBudget,
     _stack_budget,
     named_jit,
+    stack_budget_if_resolved,
     range_suffix,
 )
 from pilosa_tpu.executor.hostpath import HostPlanError
@@ -57,6 +60,7 @@ from pilosa_tpu.pql import Call, coerce_timestamp, parse
 from pilosa_tpu.roaring import unpack_words
 from pilosa_tpu.shardwidth import SHARD_WIDTH, WORDS_PER_SHARD
 from pilosa_tpu.utils import tracing
+from pilosa_tpu.utils.stats import NopStats
 from pilosa_tpu.utils.tracing import GLOBAL_TRACER
 
 def apply_options(idx: "Index", call: "Call", res: Any) -> Any:
@@ -177,33 +181,105 @@ class _Pending:
         return self.value
 
 
-def _gb_counts(masks, matrix, rows):
-    """GroupBy level counts: [G,S,W] masks × K candidate rows (gathered
-    from the [R,S,W] row-major stack) → int64[G,K] in one dispatch
-    (lax.map bounds transient memory to one row batch)."""
-    gathered = jnp.take(matrix, rows, axis=0, mode="fill", fill_value=0)
-    # popcount_rows accumulates the trailing axis in i32 (≤ 2^20 bits per
-    # row); i64 only for the [G,S] partials — an i64 [G,S,W] intermediate
-    # would relayout-copy the stack (see ops.bitwise.popcount)
-    per_row = lambda rm: jnp.sum(
-        ops.popcount_rows(masks & rm[None]).astype(jnp.int64), axis=1
-    )
-    return jax.lax.map(per_row, gathered).T
+# GroupBy's two device bodies live in ops/groupby.py; the mesh engine
+# runs the same two inside its shard_map trees.
+_gb_counts = named_jit("pilosa_groupby_counts", ops.groupby.level_counts)
+_gb_masks = named_jit("pilosa_groupby_masks", ops.groupby.pair_masks)
 
 
-_gb_counts = named_jit("pilosa_groupby_counts", _gb_counts)
+class _Held:
+    """One GroupBy's reservation in the transient ledger. ``done`` is the
+    device array whose readiness means the device has finished with the
+    query's masks (a fused GroupBy's counts); None while the query frees
+    its reservation itself (the level-synchronous path)."""
+
+    __slots__ = ("nbytes", "done")
+
+    def __init__(self, nbytes: int):
+        self.nbytes = nbytes
+        self.done = None
 
 
-def _gb_masks(masks, matrix, g_idx, row_sel):
-    """Materialize surviving groups' masks: gather parent masks and
-    candidate rows (axis 0 of the row-major stack), AND them — one
-    dispatch per level."""
-    sel = jnp.take(masks, g_idx, axis=0)
-    rows = jnp.take(matrix, row_sel, axis=0, mode="fill", fill_value=0)
-    return sel & rows
+class GroupByLedger:
+    """Device bytes that GroupBys in flight hold beside the resident
+    stacks: the filter's plane, the group masks of every level and the
+    programs' temporaries (``ops.groupby.TEMP_PLANES``). The stack budget
+    caps what is RESIDENT; this is the account of the rest (PR 21:
+    ``RESOURCE_EXHAUSTED`` at 512 shards with every stack inside its
+    budget), and ``Executor._gb_budget()`` is what it is held to.
 
+    A GroupBy reserves its whole need once, before its first program, and
+    so never waits while holding: no two can wait for each other. A
+    request that does not fit first retires the fused GroupBys that are
+    still in flight, oldest first, by waiting for the device to finish
+    each (their masks are freed with the program that read them), then
+    waits for the level-synchronous ones of other threads to release. A
+    query whose least need is over the whole budget (a pinned budget of a
+    few bytes) runs once nothing else is held: the mark then shows it.
+    ``/debug/resources`` row ``groupbyTransient``; gauge
+    ``groupby_transient_high_water_bytes``."""
 
-_gb_masks = named_jit("pilosa_groupby_masks", _gb_masks)
+    def __init__(self, stats):
+        self.stats = stats
+        self._cond = threading.Condition()
+        self.held = 0
+        self.high_water = 0
+        self._in_flight: list[_Held] = []
+
+    def admit(self, nbytes: int, budget: int) -> _Held:
+        token = _Held(int(nbytes))
+        with self._cond:
+            while self.held and self.held + token.nbytes > budget:
+                if self._in_flight:
+                    oldest = self._in_flight[0]
+                    done = oldest.done  # a release elsewhere clears the token's
+                    self._cond.release()
+                    try:
+                        done.block_until_ready()  # a wait for the device, no transfer
+                    finally:
+                        self._cond.acquire()
+                    self._drop(oldest)
+                else:
+                    self._cond.wait(0.05)
+            self.held += token.nbytes
+            if self.held > self.high_water:
+                self.high_water = self.held
+                self.stats.gauge(
+                    "groupby_transient_high_water_bytes", float(self.held)
+                )
+        return token
+
+    def in_flight(self, token: _Held, done) -> None:
+        """The query's programs are issued and nothing on the host holds
+        its masks any longer: ``token`` is spent once ``done`` is ready."""
+        with self._cond:
+            token.done = done
+            self._in_flight.append(token)
+
+    def release(self, token: _Held) -> None:
+        with self._cond:
+            self._drop(token)
+
+    def _drop(self, token: _Held) -> None:
+        """Idempotent; the caller holds the condition."""
+        if token.nbytes:
+            self.held -= token.nbytes
+            token.nbytes = 0
+            self._cond.notify_all()
+        if token.done is not None:
+            token.done = None
+            if token in self._in_flight:
+                self._in_flight.remove(token)
+
+    def snapshot(self) -> dict:
+        with self._cond:
+            for token in [t for t in self._in_flight if t.done.is_ready()]:
+                self._drop(token)
+            return {
+                "heldBytes": self.held,
+                "highWaterBytes": self.high_water,
+                "fusedInFlight": len(self._in_flight),
+            }
 
 
 class SumCount(dict):
@@ -232,7 +308,7 @@ class Executor:
     # _gb_budget(); tests pin an int (class or instance) to force paths.
     GROUPBY_MASK_BUDGET = None
 
-    def _gb_budget(self) -> int:
+    def _gb_budget(self, resolve: bool = True) -> int | None:
         """GroupBy transient-mask budget: a pinned GROUPBY_MASK_BUDGET
         wins; else PILOSA_TPU_GROUPBY_BUDGET env; else 1/8 of the stack
         budget (~70% of HBM), floored at 256 MiB. Sized so a realistic
@@ -245,7 +321,8 @@ class Executor:
         env = os.environ.get("PILOSA_TPU_GROUPBY_BUDGET")
         if env:
             return int(env)
-        return max(256 << 20, _stack_budget() // 8)
+        stack = _stack_budget() if resolve else stack_budget_if_resolved()
+        return None if stack is None else max(256 << 20, stack // 8)
 
     def __init__(
         self,
@@ -258,6 +335,9 @@ class Executor:
         self.holder = holder
         self.stats = stats  # optional StatsClient for per-call histograms
         self.compiler = QueryCompiler(mesh_ctx, stats=stats)
+        # GroupBy's counters and ledger, whether or not a registry is behind them
+        self._gb_stats = stats if stats is not None else NopStats()
+        self.gb_ledger = GroupByLedger(self._gb_stats)
         # per-call host/device routing (executor/router.py). Passing an
         # existing router preserves its calibration across executor
         # rebuilds (the server's mesh re-attach swaps the Executor but
@@ -1137,13 +1217,22 @@ class Executor:
             lambda: named_jit("pilosa_sum", self._sum_fn(field)),
         )
 
+    @staticmethod
+    def _grouped_sum_fn(sum_fn):
+        """``sum_fn`` over G group masks, one after another: (stack
+        [R,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G]). A loop, not
+        a vmap: batched over 16 masks the candidate masks of every group
+        were 47 planes of temporaries (0.73 GiB at 128 shards, compiled
+        for a v5e) for 5 % of the time (6.84 against 7.19 ms, my chip
+        runs, PR 34); one at a time each group is the plain filtered Sum
+        and the transient is none. Shared with the mesh tree."""
+        return lambda s, masks: jax.lax.map(lambda m: sum_fn(s, m), masks)
+
     def _grouped_sum_program(self, field: Field, n_shards: int):
-        """(stack [R,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G])."""
         return self.compiler.program(
             ("gb_sums", n_shards, field.bit_depth),
             lambda: named_jit(
-                "pilosa_sum_groups",
-                jax.vmap(self._sum_fn(field), in_axes=(None, 0)),
+                "pilosa_sum_groups", self._grouped_sum_fn(self._sum_fn(field))
             ),
         )
 
@@ -1608,6 +1697,23 @@ class Executor:
         )
         return gbc, gbm
 
+    def _gb_launch(self, what: str, prog, *args):
+        """Issue one device program of a GroupBy under its span
+        (``executor.groupby.filter|counts|masks|sums``), counted in
+        ``groupby_launches_total``."""
+        self._gb_stats.count("groupby_launches_total")
+        with GLOBAL_TRACER.span(f"executor.groupby.{what}"):
+            return prog(*args)
+
+    def _gb_read(self, arrays):
+        """A synchronous device→host read INSIDE the dispatch: the
+        level-synchronous path needs a level's counts on the host before
+        it can issue the next level. The calling thread, the wave's
+        leader under the scheduler, waits here for the device."""
+        self._gb_stats.count("groupby_level_readbacks_total")
+        with GLOBAL_TRACER.span("executor.groupby.readback"):
+            return jax.device_get(arrays)
+
     def _execute_group_by(
         self, idx: Index, call: Call, shards: list[int], lazy: bool = False,
         host: bool = False, mesh: bool = False,
@@ -1650,9 +1756,12 @@ class Executor:
         if host:
             # one engine, same spec: identical row universes and emission
             # order, so host/device results match entry for entry
+            self._gb_stats.count("groupby_queries_total", tags={"path": "host"})
             return self.compiler.host.group_by(
                 idx, fields, row_lists, filter_call, agg_field, limit, shards
             )
+        if not all(row_lists):
+            return []  # a level without rows: no group, nothing to launch
 
         agg_slices = (
             self._bsi_stacked(idx, agg_field, shards) if agg_field is not None else None
@@ -1669,52 +1778,101 @@ class Executor:
                 # (same discipline as _topn_chunked; VERDICT r2 item 4)
                 matrices.append(None)
 
-        mesh_mode = self.compiler.mesh_mode(len(shards)) if mesh else None
-        gb_counts_call, gb_masks_call = self._gb_programs(mesh_mode)
-        if filter_call is not None:
-            if mesh_mode is not None:
-                base_mask = self.compiler.mesh_bitmap_device(
-                    idx, filter_call, shards
+        # What the query will hold on the device beside the stacks, in
+        # [S, W] planes, reserved in the transient ledger BEFORE its first
+        # program: the filter's plane, the masks of every level that
+        # materialises them (all but the last; the last too under an
+        # aggregate) and the programs' temporaries. Level l never holds
+        # more masks than its padded pairs (``fold[l]``), so the small
+        # first levels of a deep GroupBy leave the budget to the last.
+        n_shards = len(shards)
+        plane_bytes = n_shards * WORDS_PER_SHARD * 4
+        budget = self._gb_budget()
+        kp = [_pow2(len(r)) for r in row_lists]
+        fold = list(itertools.accumulate(kp, operator.mul))  # pairs down to each level
+        mask_levels = fold if agg_field is not None else fold[:-1]
+        # a level without a resident stack streams its rows host→device,
+        # never more of them at a time than a level holds masks
+        streamed = max(
+            (k for k, m in zip(kp, matrices) if m is None), default=0
+        )
+
+        def need(cap: int) -> int:
+            """Bytes held when no level holds more than ``cap`` masks."""
+            planes = 1 + sum(min(cap, g) for g in mask_levels) + min(cap, streamed)
+            return (planes + ops.groupby.TEMP_PLANES) * plane_bytes
+
+        fused = (
+            agg_field is None
+            and not streamed
+            and need(fold[-1]) <= budget
+        )
+        if fused:
+            chunk_cap = fold[-1]
+        else:
+            # the largest power of two of masks a level that fits beside
+            # the rest: padded chunks never exceed it, and pow2 shapes
+            # keep XLA retraces to one compile per bucket
+            chunk_cap = _pow2(max(mask_levels + [1]))
+            while chunk_cap > 1 and need(chunk_cap) > budget:
+                chunk_cap //= 2
+        held = self.gb_ledger.admit(need(chunk_cap), budget)
+        try:
+            mesh_mode = self.compiler.mesh_mode(n_shards) if mesh else None
+            gb_counts_call, gb_masks_call = self._gb_programs(mesh_mode)
+            if filter_call is None:
+                base_mask = self.compiler.ones(n_shards)
+            elif mesh_mode is not None:
+                base_mask = self._gb_launch(
+                    "filter", self.compiler.mesh_bitmap_device,
+                    idx, filter_call, shards,
                 )
             else:
-                base_mask = self._filter_device(
-                    idx, Call("_", {}, [filter_call]), shards
+                base_mask = self._gb_launch(
+                    "filter", self._filter_device,
+                    idx, Call("_", {}, [filter_call]), shards,
                 )
-        else:
-            base_mask = self.compiler.ones(len(shards))
-
-        if (
-            aggregate is None
-            and all(m is not None for m in matrices)
-            and all(row_lists)
-        ):
-            fused = self._groupby_fused(
-                fields, row_lists, matrices, base_mask, limit, len(shards),
-                gb_counts_call, gb_masks_call, route_mesh=mesh_mode is not None,
+            if mesh_mode is not None:
+                base_mask = base_mask[None]  # the mesh trees' specs are [G, S, W]
+            self._gb_stats.count(
+                "groupby_queries_total",
+                tags={"path": "fused" if fused else "levels"},
             )
-            if fused is not None:
-                return fused if lazy else fused.resolve_now()
+            if fused:
+                pend = self._groupby_fused(
+                    fields, row_lists, kp, matrices, base_mask, limit, plane_bytes,
+                    gb_counts_call, gb_masks_call, held,
+                    route_mesh=mesh_mode is not None,
+                )
+                held = None  # the pending result frees it
+                return pend if lazy else pend.resolve_now()
+            return self._groupby_levels(
+                fields, row_lists, matrices, base_mask, limit, shards,
+                chunk_cap, agg_field, agg_slices, mesh_mode,
+                gb_counts_call, gb_masks_call,
+            )
+        finally:
+            if held is not None:
+                self.gb_ledger.release(held)
 
-        # Level-synchronous evaluation: a whole nesting level runs in TWO
-        # device dispatches — (1) counts of every (surviving group ×
-        # candidate row) pair, (2) materialization of the surviving
-        # groups' masks — instead of the reference's one-executor-pass-
-        # per-group (executor.go executeGroupBy; round-1 code dispatched
-        # one program per candidate row). Device memory for the [G, S, W]
-        # group-mask tensor is bounded by GROUPBY_MASK_BUDGET: when a
-        # level survives more groups than fit, the pair list is processed
-        # in mask-budget-sized chunks depth-first (order — and therefore
-        # limit semantics — is preserved because chunks run in pair
-        # order). Shapes pad to powers of two so recompiles stay rare.
+    def _groupby_levels(
+        self, fields, row_lists, matrices, base_mask, limit, shards,
+        chunk_cap, agg_field, agg_slices, mesh_mode,
+        gb_counts_call, gb_masks_call,
+    ) -> list[dict]:
+        """Level-synchronous evaluation: a whole nesting level runs in TWO
+        device dispatches — (1) counts of every (surviving group ×
+        candidate row) pair, (2) materialization of the surviving
+        groups' masks — instead of the reference's one-executor-pass-
+        per-group (executor.go executeGroupBy). Each level's counts are
+        READ on the host before the next level is issued
+        (``_gb_read``), so only surviving pairs are expanded. A level
+        that survives more pairs than ``chunk_cap`` is processed in
+        chunks depth-first (order — and therefore limit semantics — is
+        preserved because chunks run in pair order). Shapes pad to
+        powers of two so recompiles stay rare."""
         n_shards = len(shards)
-        # floor to a power of two so padded chunks never exceed the
-        # budget (p_pad ≤ chunk_cap), and pow2 shapes keep XLA retraces
-        # to one compile per bucket
-        chunk_cap = max(
-            1, self._gb_budget() // (n_shards * WORDS_PER_SHARD * 4)
-        )
-        chunk_cap = 1 << (chunk_cap.bit_length() - 1)
-
+        plane_bytes = n_shards * WORDS_PER_SHARD * 4
         results: list[dict] = []
         sum_prog = None
         if agg_slices is not None:
@@ -1726,7 +1884,7 @@ class Executor:
                 gsp = self.compiler.program(
                     gskey,
                     lambda: eng.grouped_sum_tree(
-                        self._sum_fn(agg_field), mesh_mode
+                        self._grouped_sum_fn(self._sum_fn(agg_field)), mesh_mode
                     ),
                 )
                 sum_prog = lambda s, m: self.compiler._mesh_dispatch(
@@ -1747,8 +1905,8 @@ class Executor:
                     }
                 )
             if sum_prog is not None:
-                pos, neg, _n = (
-                    np.asarray(x) for x in sum_prog(agg_slices, masks)
+                pos, neg, _n = self._gb_read(
+                    self._gb_launch("sums", sum_prog, agg_slices, masks)
                 )
                 for i in range(len(groups)):
                     results[start + i]["sum"] = ops.bsi.weigh_sum(pos[i], neg[i])
@@ -1794,28 +1952,29 @@ class Executor:
         def _level_counts(level: int, masks, n_groups: int) -> np.ndarray:
             """int64[n_groups, len(rows_l)] — resident stack when the level
             fits the budget, streamed row chunks otherwise (exactness and
-            (g, k) output order are identical either way)."""
+            (g, k) output order are identical either way). The row ids go
+            into the program as they are, numpy: an upload of their own
+            would be one more device call a level."""
             rows_l = row_lists[level]
             m = matrices[level]
             if m is not None:
                 k_pad = _pow2(len(rows_l))
                 rows_arr = _pad_row_ids(rows_l, k_pad)
-                return np.asarray(
-                    gb_counts_call(masks, m, jnp.asarray(rows_arr))
+                return self._gb_read(
+                    self._gb_launch("counts", gb_counts_call, masks, m, rows_arr)
                 )[:n_groups, : len(rows_l)]
             frags = _level_frags(level)
-            hot = self.compiler.stacks.hot_capacity(n_shards)
+            step = min(self.compiler.stacks.hot_capacity(n_shards), chunk_cap)
             parts = []
-            for lo in range(0, len(rows_l), hot):
-                sub = rows_l[lo : lo + hot]
+            for lo in range(0, len(rows_l), step):
+                sub = rows_l[lo : lo + step]
                 k_pad = _pow2(len(sub))
                 host = _pack_rows(level, frags, sub, k_pad)
                 parts.append(
-                    np.asarray(
-                        gb_counts_call(
-                            masks,
-                            jnp.asarray(host),
-                            jnp.arange(k_pad, dtype=jnp.int32),
+                    self._gb_read(
+                        self._gb_launch(
+                            "counts", gb_counts_call, masks, jnp.asarray(host),
+                            np.arange(k_pad, dtype=np.int32),
                         )
                     )[:n_groups, : len(sub)]
                 )
@@ -1844,9 +2003,8 @@ class Executor:
                 row_sel[: chunk.shape[0]] = np.searchsorted(uniq_k, chunk[:, 1])
             else:
                 row_sel[: chunk.shape[0]] = [rows_l[k] for k in chunk[:, 1]]
-            return gb_masks_call(
-                masks, m, jnp.asarray(g_idx), jnp.asarray(row_sel)
-            )
+            self._gb_stats.count("groupby_mask_bytes_total", p_pad * plane_bytes)
+            return self._gb_launch("masks", gb_masks_call, masks, m, g_idx, row_sel)
 
         def expand(level: int, masks, groups: list[tuple]) -> None:
             if limit is not None and len(results) >= limit:
@@ -1859,6 +2017,7 @@ class Executor:
                 pairs = pairs[: limit - len(results)]
             for lo in range(0, pairs.shape[0], chunk_cap):
                 chunk = pairs[lo : lo + chunk_cap]
+                self._gb_stats.count("groupby_chunks_total")
                 sub_groups = [
                     groups[g] + ((fields[level], rows_l[k]),)
                     for g, k in chunk.tolist()
@@ -1877,16 +2036,18 @@ class Executor:
                         )
                     else:
                         expand(level + 1, sub_masks, sub_groups)
+                    # freed before the next chunk's are made: a level holds
+                    # one chunk of masks, which is what was reserved
+                    del sub_masks
                 if limit is not None and len(results) >= limit:
                     return
 
-        if all(row_lists):
-            expand(0, base_mask[None], [()])
+        expand(0, base_mask, [()])
         return results
 
     def _groupby_fused(
-        self, fields, row_lists, matrices, base_mask, limit, n_shards,
-        gb_counts_call, gb_masks_call, route_mesh: bool = False,
+        self, fields, row_lists, kp, matrices, base_mask, limit, plane_bytes,
+        gb_counts_call, gb_masks_call, held, route_mesh: bool = False,
     ):
         """All-pairs GroupBy: fold every level but the last into one
         [G, S, W] pair-mask tensor with zero intermediate readbacks, then
@@ -1903,33 +2064,34 @@ class Executor:
         row-major order = nested ascending row order, so `limit` cuts
         identically to the level-synchronous path.
 
-        Returns None when the folded tensor would exceed
-        GROUPBY_MASK_BUDGET — the level-synchronous path prunes via
-        surviving groups and streams chunks, trading readbacks for
-        memory. Aggregate-Sum queries also take that path (sums need the
-        surviving groups' masks, which this path never materializes
-        host-side)."""
-        kp = [_pow2(len(r)) for r in row_lists]
+        The caller has reserved the folded masks in the transient ledger
+        (``held``) and takes the level-synchronous path where they do not
+        fit its budget, or where an aggregate needs the surviving groups'
+        masks; the reservation is spent when the device has produced the
+        counts (``GroupByLedger.in_flight``) or, at the latest, with the
+        readback. ``kp``: each level's row count padded to a power of two."""
         G = 1
-        masks = base_mask[None]
+        masks = base_mask
         for lvl in range(len(fields) - 1):
-            g_new = G * kp[lvl]
-            if g_new * n_shards * WORDS_PER_SHARD * 4 > self._gb_budget():
-                return None
             rows_arr = _pad_row_ids(row_lists[lvl], kp[lvl])
             g_idx = np.repeat(np.arange(G, dtype=np.int32), kp[lvl])
-            masks = gb_masks_call(
-                masks,
-                matrices[lvl],
-                jnp.asarray(g_idx),
-                jnp.asarray(np.tile(rows_arr, G)),
+            G *= kp[lvl]
+            self._gb_stats.count("groupby_mask_bytes_total", G * plane_bytes)
+            masks = self._gb_launch(
+                "masks", gb_masks_call, masks, matrices[lvl], g_idx,
+                np.tile(rows_arr, G // kp[lvl]),
             )
-            G = g_new
         last = len(fields) - 1
         rows_arr = _pad_row_ids(row_lists[last], kp[last])
-        counts = gb_counts_call(masks, matrices[last], jnp.asarray(rows_arr))
+        counts = self._gb_launch(
+            "counts", gb_counts_call, masks, matrices[last], rows_arr
+        )
+        del masks  # the counts program holds the last reference
+        ledger = self.gb_ledger
+        ledger.in_flight(held, counts)
 
         def finish(a):
+            ledger.release(held)
             cnt = a[0]  # [G, kp[last]]
             results: list[dict] = []
             for flat, k in np.argwhere(cnt > 0).tolist():

@@ -38,7 +38,7 @@ if (
 # so a restart compiles nothing
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
-from pilosa_tpu.ops import bsi, containers, similarity, topn
+from pilosa_tpu.ops import bsi, containers, groupby, similarity, topn
 from pilosa_tpu.ops.bitwise import (
     column_mask,
     count_and,
@@ -60,6 +60,7 @@ from pilosa_tpu.ops.bitwise import (
 __all__ = [
     "bsi",
     "containers",
+    "groupby",
     "similarity",
     "topn",
     "column_mask",

@@ -1,0 +1,94 @@
+"""GroupBy's two device bodies, shared by the single-program engine
+(executor/executor.py jits them) and the mesh engine (parallel/mesh.py
+runs them inside ``shard_map`` under a psum tree).
+
+Neither gathers a ``[K, S, W]`` copy of the candidate rows before it
+starts: they read a row of a stack where it lies (``plane``: a dynamic
+slice that fuses into its consumer), or gather the rows of one block of
+shards at a time. Compiled for a v5e at the cell's shapes (64 group
+masks by 32 rows of 128 shards) the whole-stack gathers were 70 planes
+of temporaries beside the count pass and 76 beside the mask pass,
+1.1-1.2 GiB that no budget knew of (tests/test_tpu_compile.py holds
+what is left to ``TEMP_PLANES``).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from pilosa_tpu.ops.bitwise import popcount_rows
+
+# Upper bound, in [S, W] planes, of the temporaries XLA allocates beside
+# the arguments and outputs of ANY program of a GroupBy (the two below and
+# the grouped sum, executor.Executor._grouped_sum_program), whatever the
+# number of groups: what the executor's transient ledger adds to the masks
+# a GroupBy holds. tests/test_tpu_compile.py compiles them for a described
+# v5e at taxi-128g's shapes and holds memory_analysis() to it.
+TEMP_PLANES = 1
+
+
+def plane(m: jax.Array, r) -> jax.Array:
+    """Row ``r`` of the row-major stack ``m [R, S, W]``; zeros where ``r``
+    is outside it (the -1 of a padded row list, a row past the stack)."""
+    rr = jnp.clip(r, 0, m.shape[0] - 1)
+    p = jax.lax.dynamic_index_in_dim(m, rr, axis=0, keepdims=False)
+    return jnp.where((r >= 0) & (r < m.shape[0]), p, jnp.uint32(0))
+
+
+def _groups(masks: jax.Array) -> jax.Array:
+    """A level's parent masks as ``[G, S, W]``; the root of a GroupBy is
+    its filter's one ``[S, W]`` plane, taken as it is (an eager ``[None]``
+    outside would be a device program of its own a query)."""
+    return masks[None] if masks.ndim == 2 else masks
+
+
+# Shards a block of the count pass, where the shard count divides by it
+# (8 is the sublane count of a TPU tile: blocks of 2 or 4 shards made XLA
+# copy the whole operands, 96 planes of temporaries). Inside a block the
+# candidate rows stay put while the group masks go by, so the compiler can
+# keep them in fast memory. One launch of 64 masks x 32 rows at 128 shards
+# (my chip runs, PR 34): 47 ms row by row over whole planes (742 GB/s:
+# every row re-reads every mask from HBM), 21 ms in blocks of 8 with the
+# masks kept and the rows going by, 13.7 ms this way; blocks of 16: 18 ms.
+SHARD_BLOCK = 8
+
+
+def level_counts(masks: jax.Array, matrix: jax.Array, rows: jax.Array) -> jax.Array:
+    """``[G, S, W]`` group masks x the K candidate ``rows`` (ids into the
+    ``[R, S, W]`` stack, -1 padding) -> int64 ``[G, K]`` columns in each
+    (group, row) pair. Popcounts accumulate in int32 along the word axis
+    and inside a block of shards (at most 2**23 bits); only the small
+    partials widen."""
+    masks = _groups(masks)
+    n_shards = masks.shape[1]
+    if n_shards % SHARD_BLOCK:
+        # whole planes, one row at a time: the transient is one plane
+        def per_row(r):
+            return jnp.sum(
+                popcount_rows(masks & plane(matrix, r)[None]).astype(jnp.int64), axis=1
+            )
+
+        return jax.lax.map(per_row, rows).T
+
+    def block(start):
+        m = jax.lax.dynamic_slice_in_dim(masks, start, SHARD_BLOCK, axis=1)
+        x = jax.lax.dynamic_slice_in_dim(matrix, start, SHARD_BLOCK, axis=1)
+        x = jnp.take(x, rows, axis=0, mode="fill", fill_value=0)  # [K, block, W]
+        return jax.lax.map(
+            lambda mg: jnp.sum(popcount_rows(x & mg[None]), axis=1, dtype=jnp.int32), m
+        )  # [G, K]
+
+    starts = jnp.arange(n_shards // SHARD_BLOCK, dtype=jnp.int32) * SHARD_BLOCK
+    return jnp.sum(jax.lax.map(block, starts).astype(jnp.int64), axis=0)
+
+
+def pair_masks(
+    masks: jax.Array, matrix: jax.Array, g_idx: jax.Array, row_sel: jax.Array
+) -> jax.Array:
+    """The masks of P (parent group, row) pairs -> ``[P, S, W]``: parent
+    ``g_idx[p]`` AND row ``row_sel[p]`` (-1: an all-zero padding mask)."""
+    masks = _groups(masks)
+    return jax.lax.map(
+        lambda gr: plane(masks, gr[0]) & plane(matrix, gr[1]), (g_idx, row_sel)
+    )

@@ -490,13 +490,14 @@ class MeshQueryEngine:
             "sum", local, (spec3, self.row_spec(mode)), (P(), P(), P())
         )
 
-    def grouped_sum_tree(self, sum_fn, mode: str):
+    def grouped_sum_tree(self, grouped_sum_fn, mode: str):
         """(stack [R,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G])
-        replicated — GroupBy's aggregate=Sum under the same psum tree."""
+        replicated — GroupBy's aggregate=Sum under the same psum tree
+        (``grouped_sum_fn`` is Executor._grouped_sum_fn's)."""
         spec3 = self._arr_spec(1, mode)
 
         def local(slices, masks):
-            pos, neg, n = jax.vmap(sum_fn, in_axes=(None, 0))(slices, masks)
+            pos, neg, n = grouped_sum_fn(slices, masks)
             return (
                 self._psum_both(pos),
                 self._psum_both(neg),
@@ -559,26 +560,17 @@ class MeshQueryEngine:
         spec3 = self._arr_spec(1, mode)
 
         def local(masks, matrix, rows):
-            gathered = jnp.take(matrix, rows, axis=0, mode="fill", fill_value=0)
-            per_row = lambda rm: jnp.sum(
-                ops.popcount_rows(masks & rm[None]).astype(jnp.int64), axis=1
-            )
-            return self._psum_both(jax.lax.map(per_row, gathered).T)
+            return self._psum_both(ops.groupby.level_counts(masks, matrix, rows))
 
         return self._spmd("groupby_counts", local, (spec3, spec3, P()), P())
 
     def groupby_masks_tree(self, mode: str):
         """(masks, matrix, g_idx, row_sel) → sharded [P,S,W] surviving
-        group masks — pure elementwise gather+AND, no collectives."""
+        group masks — pure elementwise select+AND, no collectives."""
         spec3 = self._arr_spec(1, mode)
 
-        def local(masks, matrix, g_idx, row_sel):
-            sel = jnp.take(masks, g_idx, axis=0)
-            rows = jnp.take(matrix, row_sel, axis=0, mode="fill", fill_value=0)
-            return sel & rows
-
         return self._spmd(
-            "groupby_masks", local, (spec3, spec3, P(), P()), spec3
+            "groupby_masks", ops.groupby.pair_masks, (spec3, spec3, P(), P()), spec3
         )
 
     # ------------------------------------------------------------ placement

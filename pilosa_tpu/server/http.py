@@ -1240,6 +1240,21 @@ class Handler(BaseHTTPRequestHandler):
             "bytes",
             **stacks.placement_snapshot(),
         )
+        # GroupBy's transient device bytes (group masks, the filter's
+        # plane, program temporaries): what queries in flight hold beside
+        # the resident stacks, and the mark they reached. The limit is
+        # Executor._gb_budget(), read like the stack budget without
+        # resolving it
+        executor = self.api.executor
+        gb = executor.gb_ledger.snapshot()
+        row(
+            "groupbyTransient",
+            gb["heldBytes"],
+            executor._gb_budget(resolve=False),
+            "bytes",
+            highWaterBytes=gb["highWaterBytes"],
+            fusedInFlight=gb["fusedInFlight"],
+        )
         # WAL / ops-log debt (crash-replay bytes) + compaction queue
         wal = self.api.holder.wal_ledger()
         row(

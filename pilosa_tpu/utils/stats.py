@@ -62,6 +62,12 @@ _METRIC_HELP = {
     "shard_scope_rebuilds_total": "rebuilds of an index's memoized shard tuple (its mutation stamp moved)",
     "bsi_condition_leaves_total": "BSI comparison leaves planned, by operator",
     "device_scalar_uploads_total": "misses of the device operand-vector cache (one small upload each)",
+    "groupby_queries_total": "GroupBy calls by path: fused (one deferred readback), levels (a read a level), host",
+    "groupby_launches_total": "device programs issued for GroupBys (filter, counts, masks, sums)",
+    "groupby_level_readbacks_total": "synchronous device-to-host reads inside a GroupBy's dispatch",
+    "groupby_mask_bytes_total": "bytes of group masks materialised on the device",
+    "groupby_chunks_total": "pair chunks a level-synchronous GroupBy expanded",
+    "groupby_transient_high_water_bytes": "most device bytes GroupBys in flight have held beside the stacks",
     "queries_partial": "queries answered with partial results",
     "queries_rejected": "requests shed by admission control",
     "queries_per_wave": "occupancy of cross-query device waves",

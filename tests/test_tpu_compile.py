@@ -254,6 +254,46 @@ def test_groupby_level_with_sum(rig, one_chip, monkeypatch):
     )
 
 
+@pytest.mark.parametrize("groups", [64, 16])
+def test_groupby_programs_at_the_groupby_cells_shapes(rig, one_chip, groups):
+    """The cell taxi-128g.groupby_fare: 128 shards, the fourth query's
+    level-synchronous chunks of 64 + 16 (passenger, year) masks against
+    the 32 distance rows, and the second query's grouped sum over the
+    16-plane amount block. What a GroupBy holds on the device is counted
+    by the transient ledger as masks + ``TEMP_PLANES``: XLA's own
+    ``temp_size_in_bytes`` of every program has to stay inside that, and
+    the fourth query's whole need inside the chip's transient budget."""
+    _h, idx, e = rig
+    shards, plane = 128, 128 * W * 4
+    sds = shapes_on(one_chip)
+    allowed = ops.groupby.TEMP_PLANES * plane
+    parents, years, miles = sds((16, shards, W), np.uint32), sds((8, shards, W), np.uint32), \
+        sds((32, shards, W), np.uint32)
+    masks = sds((groups, shards, W), np.uint32)
+    counts = compile_and_fit(executor_mod._gb_counts, (masks, miles, sds((32,), np.int32)))
+    assert counts.memory_analysis().temp_size_in_bytes <= allowed
+    made = compile_and_fit(
+        executor_mod._gb_masks,
+        (parents, years, sds((groups,), np.int32), sds((groups,), np.int32)),
+    )
+    mem = made.memory_analysis()
+    assert mem.output_size_in_bytes == groups * plane
+    assert mem.temp_size_in_bytes <= allowed
+    root = compile_and_fit(  # a level's first launch: the filter's one plane
+        executor_mod._gb_counts, (sds((shards, W), np.uint32), parents, sds((16,), np.int32))
+    )
+    assert root.memory_analysis().temp_size_in_bytes <= allowed
+    sums = compile_and_fit(
+        e._grouped_sum_program(idx.field("amount"), shards),
+        (sds((16, shards, W), np.uint32), sds((16, shards, W), np.uint32)),
+    )
+    assert sums.memory_analysis().temp_size_in_bytes <= allowed
+    # the fourth query at its fullest: the filter's plane, 16 passenger
+    # masks, a chunk of 64 pair masks, the temporaries
+    budget = int(16.9e9 * 0.7) // 8
+    assert (1 + 16 + 64 + ops.groupby.TEMP_PLANES) * plane <= budget
+
+
 def test_stack_delta_and_store_scatters(one_chip):
     """The write path: dirty rows scattered into the resident fare stack,
     and promoted rows scattered into the tiered container stores at the
