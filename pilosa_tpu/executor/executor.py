@@ -21,7 +21,9 @@ Redesigned for TPU:
 
 from __future__ import annotations
 
+import bisect
 import itertools
+import math
 import operator
 import os
 import threading
@@ -186,12 +188,23 @@ class _Pending:
 _gb_counts = named_jit("pilosa_groupby_counts", ops.groupby.level_counts)
 _gb_masks = named_jit("pilosa_groupby_masks", ops.groupby.pair_masks)
 
+# The most chunks of ``chunk_cap`` pairs that a GroupBy expands UNPRUNED,
+# every (parent, real row) pair with no level read back
+# (``Executor._groupby_deferred``). An unpruned chunk costs the device at
+# most what a full chunk of the level path costs (7.1 ms of masks and
+# 13.7 ms of counts for 64 masks by 32 rows at 128 shards; my chip runs,
+# PR 34), and a level's round trip costs 5-8 ms of an idle device and the
+# wave's leader (ledger, PR 34: ``groupby_readback_wait_ms`` 7.30): inside
+# two chunks the reads cost more than they could prune away; beyond, a
+# sparse expansion would have pruned more than its reads cost.
+DEFERRED_CHUNKS = 2
+
 
 class _Held:
     """One GroupBy's reservation in the transient ledger. ``done`` is the
     device array whose readiness means the device has finished with the
-    query's masks (a fused GroupBy's counts); None while the query frees
-    its reservation itself (the level-synchronous path)."""
+    query's masks (the last output of a deferred GroupBy); None while the
+    query frees its reservation itself (the level-synchronous path)."""
 
     __slots__ = ("nbytes", "done")
 
@@ -210,8 +223,8 @@ class GroupByLedger:
 
     A GroupBy reserves its whole need once, before its first program, and
     so never waits while holding: no two can wait for each other. A
-    request that does not fit first retires the fused GroupBys that are
-    still in flight, oldest first, by waiting for the device to finish
+    request that does not fit first retires the deferred GroupBys that
+    are still in flight, oldest first, by waiting for the device to finish
     each (their masks are freed with the program that read them), then
     waits for the level-synchronous ones of other threads to release. A
     query whose least need is over the whole budget (a pinned budget of a
@@ -312,8 +325,8 @@ class Executor:
         """GroupBy transient-mask budget: a pinned GROUPBY_MASK_BUDGET
         wins; else PILOSA_TPU_GROUPBY_BUDGET env; else 1/8 of the stack
         budget (~70% of HBM), floored at 256 MiB. Sized so a realistic
-        two-level GroupBy folds through the FUSED one-readback path on a
-        real chip instead of paying one sync round trip per level.
+        GroupBy expands in one or two chunks on a real chip, with one
+        deferred readback, instead of paying a sync round trip per level.
         Lazy: resolving device memory must never happen at construction
         (backend init)."""
         if self.GROUPBY_MASK_BUDGET is not None:
@@ -338,6 +351,10 @@ class Executor:
         # GroupBy's counters and ledger, whether or not a registry is behind them
         self._gb_stats = stats if stats is not None else NopStats()
         self.gb_ledger = GroupByLedger(self._gb_stats)
+        # the two counts of what a GroupBy PAID stand at 0 from the start:
+        # a scrape then says "never", not "a program without the family"
+        for family in ("groupby_level_readbacks_total", "groupby_chunk_waits_total"):
+            self._gb_stats.declare(family)
         # per-call host/device routing (executor/router.py). Passing an
         # existing router preserves its calibration across executor
         # rebuilds (the server's mesh re-attach swaps the Executor but
@@ -1714,6 +1731,42 @@ class Executor:
         with GLOBAL_TRACER.span("executor.groupby.readback"):
             return jax.device_get(arrays)
 
+    def _gb_wait(self, done) -> None:
+        """The deferred walk's one kind of wait: for the device to finish
+        the query's OWN last program, so that the chunk of masks it read
+        is freed before the next chunk's are made. No transfer, no host
+        work behind it, the interpreter lock released."""
+        self._gb_stats.count("groupby_chunk_waits_total")
+        with GLOBAL_TRACER.span("executor.groupby.wait"):
+            done.block_until_ready()
+
+    def _gb_make_masks(self, gb_masks_call, masks, matrix, parents, row_sel, plane_bytes):
+        """One masks launch: pair p is parent ``parents[p]`` of ``masks``
+        AND row ``row_sel[p]`` of ``matrix``. Padded to a power of two of
+        pairs: a padding entry is an all-zero mask (parent 0 & row -1),
+        and a stable shape avoids a compile per pair count."""
+        p_pad = _pow2(len(parents))
+        g_idx = np.zeros(p_pad, dtype=np.int32)
+        g_idx[: len(parents)] = parents
+        sel = np.full(p_pad, -1, dtype=np.int32)
+        sel[: len(parents)] = row_sel
+        self._gb_stats.count("groupby_mask_bytes_total", p_pad * plane_bytes)
+        return self._gb_launch("masks", gb_masks_call, masks, matrix, g_idx, sel)
+
+    def _gb_sum_program(self, agg_field: Field, n_shards: int, mesh_mode: str | None):
+        """``(slices, masks [G, S, W]) → (pos [G, D], neg [G, D], n [G])``:
+        the grouped sum of a GroupBy's aggregate on the query's route."""
+        if mesh_mode is None:
+            return self._grouped_sum_program(agg_field, n_shards)
+        eng = self.compiler.mesh_engine
+        gsp = self.compiler.program(
+            ("mesh_gb_sums", n_shards, agg_field.bit_depth, mesh_mode),
+            lambda: eng.grouped_sum_tree(
+                self._grouped_sum_fn(self._sum_fn(agg_field)), mesh_mode
+            ),
+        )
+        return lambda s, m: self.compiler._mesh_dispatch("groupby", gsp, s, m)
+
     def _execute_group_by(
         self, idx: Index, call: Call, shards: list[int], lazy: bool = False,
         host: bool = False, mesh: bool = False,
@@ -1802,24 +1855,33 @@ class Executor:
             planes = 1 + sum(min(cap, g) for g in mask_levels) + min(cap, streamed)
             return (planes + ops.groupby.TEMP_PLANES) * plane_bytes
 
-        fused = (
-            agg_field is None
-            and not streamed
-            and need(fold[-1]) <= budget
+        # the largest power of two of masks a level that fits beside the
+        # rest: padded chunks never exceed it, and pow2 shapes keep XLA
+        # retraces to one compile per bucket
+        chunk_cap = _pow2(max(mask_levels + [1]))
+        while chunk_cap > 1 and need(chunk_cap) > budget:
+            chunk_cap //= 2
+        # Which walk, from the shapes in hand: the (parent, real row)
+        # pairs of the deepest level that materialises masks, in chunks of
+        # chunk_cap. Few chunks over resident stacks: all pairs, nothing
+        # read back (DEFERRED_CHUNKS). Under a limit only where one chunk
+        # holds them all, since the level path stops early.
+        pairs = math.prod(len(r) for r in row_lists[: len(mask_levels)])
+        chunks = -(-pairs // chunk_cap)
+        deferred = (
+            not streamed
+            and chunks <= DEFERRED_CHUNKS
+            and (limit is None or chunks == 1)
         )
-        if fused:
-            chunk_cap = fold[-1]
-        else:
-            # the largest power of two of masks a level that fits beside
-            # the rest: padded chunks never exceed it, and pow2 shapes
-            # keep XLA retraces to one compile per bucket
-            chunk_cap = _pow2(max(mask_levels + [1]))
-            while chunk_cap > 1 and need(chunk_cap) > budget:
-                chunk_cap //= 2
         held = self.gb_ledger.admit(need(chunk_cap), budget)
         try:
             mesh_mode = self.compiler.mesh_mode(n_shards) if mesh else None
             gb_counts_call, gb_masks_call = self._gb_programs(mesh_mode)
+            sum_prog = (
+                self._gb_sum_program(agg_field, n_shards, mesh_mode)
+                if agg_field is not None
+                else None
+            )
             if filter_call is None:
                 base_mask = self.compiler.ones(n_shards)
             elif mesh_mode is not None:
@@ -1836,20 +1898,19 @@ class Executor:
                 base_mask = base_mask[None]  # the mesh trees' specs are [G, S, W]
             self._gb_stats.count(
                 "groupby_queries_total",
-                tags={"path": "fused" if fused else "levels"},
+                tags={"path": "fused" if deferred else "levels"},
             )
-            if fused:
-                pend = self._groupby_fused(
-                    fields, row_lists, kp, matrices, base_mask, limit, plane_bytes,
-                    gb_counts_call, gb_masks_call, held,
-                    route_mesh=mesh_mode is not None,
+            if deferred:
+                pend = self._groupby_deferred(
+                    fields, row_lists, matrices, base_mask, limit, plane_bytes,
+                    chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
+                    held, route="mesh" if mesh_mode is not None else "device",
                 )
                 held = None  # the pending result frees it
                 return pend if lazy else pend.resolve_now()
             return self._groupby_levels(
                 fields, row_lists, matrices, base_mask, limit, shards,
-                chunk_cap, agg_field, agg_slices, mesh_mode,
-                gb_counts_call, gb_masks_call,
+                chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
             )
         finally:
             if held is not None:
@@ -1857,8 +1918,7 @@ class Executor:
 
     def _groupby_levels(
         self, fields, row_lists, matrices, base_mask, limit, shards,
-        chunk_cap, agg_field, agg_slices, mesh_mode,
-        gb_counts_call, gb_masks_call,
+        chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
     ) -> list[dict]:
         """Level-synchronous evaluation: a whole nesting level runs in TWO
         device dispatches — (1) counts of every (surviving group ×
@@ -1870,28 +1930,13 @@ class Executor:
         that survives more pairs than ``chunk_cap`` is processed in
         chunks depth-first (order — and therefore limit semantics — is
         preserved because chunks run in pair order). Shapes pad to
-        powers of two so recompiles stay rare."""
+        powers of two so recompiles stay rare. The walk of streamed
+        levels, of expansions over ``DEFERRED_CHUNKS`` chunks and of
+        chunked ones under a ``limit``; the rest never read a level back
+        (``_groupby_deferred``)."""
         n_shards = len(shards)
         plane_bytes = n_shards * WORDS_PER_SHARD * 4
         results: list[dict] = []
-        sum_prog = None
-        if agg_slices is not None:
-            if mesh_mode is not None:
-                eng = self.compiler.mesh_engine
-                gskey = (
-                    "mesh_gb_sums", n_shards, agg_field.bit_depth, mesh_mode,
-                )
-                gsp = self.compiler.program(
-                    gskey,
-                    lambda: eng.grouped_sum_tree(
-                        self._grouped_sum_fn(self._sum_fn(agg_field)), mesh_mode
-                    ),
-                )
-                sum_prog = lambda s, m: self.compiler._mesh_dispatch(
-                    "groupby", gsp, s, m
-                )
-            else:
-                sum_prog = self._grouped_sum_program(agg_field, n_shards)
 
         def emit(groups: list[tuple], counts: np.ndarray, masks) -> None:
             start = len(results)
@@ -1986,10 +2031,6 @@ class Executor:
             budget) and select them by local index."""
             rows_l = row_lists[level]
             m = matrices[level]
-            p_pad = _pow2(chunk.shape[0])
-            g_idx = np.zeros(p_pad, dtype=np.int32)
-            row_sel = np.full(p_pad, -1, dtype=np.int32)
-            g_idx[: chunk.shape[0]] = chunk[:, 0]
             if m is None:
                 uniq_k = np.unique(chunk[:, 1])
                 m = jnp.asarray(
@@ -2000,11 +2041,12 @@ class Executor:
                         _pow2(uniq_k.size),
                     )
                 )
-                row_sel[: chunk.shape[0]] = np.searchsorted(uniq_k, chunk[:, 1])
+                row_sel = np.searchsorted(uniq_k, chunk[:, 1])
             else:
-                row_sel[: chunk.shape[0]] = [rows_l[k] for k in chunk[:, 1]]
-            self._gb_stats.count("groupby_mask_bytes_total", p_pad * plane_bytes)
-            return self._gb_launch("masks", gb_masks_call, masks, m, g_idx, row_sel)
+                row_sel = [rows_l[k] for k in chunk[:, 1]]
+            return self._gb_make_masks(
+                gb_masks_call, masks, m, chunk[:, 0], row_sel, plane_bytes
+            )
 
         def expand(level: int, masks, groups: list[tuple]) -> None:
             if limit is not None and len(results) >= limit:
@@ -2026,9 +2068,6 @@ class Executor:
                     # counts suffice — skip materializing final masks
                     emit(sub_groups, cnp[chunk[:, 0], chunk[:, 1]], None)
                 else:
-                    # p_pad-padded: padding entries are all-zero masks
-                    # (g_idx 0 & row -1 → 0) and count 0, and a stable
-                    # pow2 shape avoids per-G recompiles
                     sub_masks = _pair_masks(level, masks, chunk)
                     if last:
                         emit(
@@ -2045,79 +2084,117 @@ class Executor:
         expand(0, base_mask, [()])
         return results
 
-    def _groupby_fused(
-        self, fields, row_lists, kp, matrices, base_mask, limit, plane_bytes,
-        gb_counts_call, gb_masks_call, held, route_mesh: bool = False,
+    def _groupby_deferred(
+        self, fields, row_lists, matrices, base_mask, limit, plane_bytes,
+        chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
+        held, route: str,
     ):
-        """All-pairs GroupBy: fold every level but the last into one
-        [G, S, W] pair-mask tensor with zero intermediate readbacks, then
-        count the last level's rows against it — the whole query is one
-        dispatch chain ending in a single DEFERRED [G, K] readback
-        (_Pending), so a GroupBy costs the same one transport RTT as a
-        Count (VERDICT r3 weak #3: sync GroupBy measured BELOW the CPU
-        baseline because each level paid a full sync RTT).
+        """All-pairs GroupBy over resident stacks: the level path's walk
+        with nothing read back. On every level but the last, every
+        (parent, REAL row) pair is expanded, in (g-major, k-minor) order
+        and in chunks of ``chunk_cap`` masks, with no counts launch; on
+        the last level the counts stay on the device, under an aggregate
+        with the grouped sums of the last level's masks. The whole query
+        is one chain of launches ending in ONE deferred readback
+        (_Pending) that rides the wave's, so a GroupBy costs the one
+        transport RTT a Count costs.
 
-        Pruning falls out of the algebra instead of host control flow: a
-        padding row (-1) or an empty parent gathers an all-zero mask, so
-        every invalid/empty combination surfaces as count 0 and the
-        resolve-time argwhere(>0) drops it. Emission order is argwhere's
-        row-major order = nested ascending row order, so `limit` cuts
-        identically to the level-synchronous path.
+        Pruning falls out of the algebra instead of host control flow: an
+        empty parent gives all-zero masks, so every empty combination
+        surfaces as count 0 and ``finish`` drops it. The chunks of a level
+        are consecutive ranges of the lexicographic enumeration of the
+        levels' real rows above it, so the emission order is nested
+        ascending row order and ``limit`` cuts as on the level path.
 
-        The caller has reserved the folded masks in the transient ledger
-        (``held``) and takes the level-synchronous path where they do not
-        fit its budget, or where an aggregate needs the surviving groups'
-        masks; the reservation is spent when the device has produced the
-        counts (``GroupByLedger.in_flight``) or, at the latest, with the
-        readback. ``kp``: each level's row count padded to a power of two."""
-        G = 1
-        masks = base_mask
-        for lvl in range(len(fields) - 1):
-            rows_arr = _pad_row_ids(row_lists[lvl], kp[lvl])
-            g_idx = np.repeat(np.arange(G, dtype=np.int32), kp[lvl])
-            G *= kp[lvl]
-            self._gb_stats.count("groupby_mask_bytes_total", G * plane_bytes)
-            masks = self._gb_launch(
-                "masks", gb_masks_call, masks, matrices[lvl], g_idx,
-                np.tile(rows_arr, G // kp[lvl]),
-            )
+        The caller has reserved ``need(chunk_cap)`` in the transient
+        ledger (``held``): one chunk of masks a level. Before a level's
+        next chunk is made, the reference to the last one goes and the
+        walk waits for the device to finish the query's own last program
+        (``_gb_wait``). The reservation is spent when the device has
+        produced the last output (``GroupByLedger.in_flight``) or, at the
+        latest, with the readback."""
         last = len(fields) - 1
-        rows_arr = _pad_row_ids(row_lists[last], kp[last])
-        counts = self._gb_launch(
-            "counts", gb_counts_call, masks, matrices[last], rows_arr
-        )
-        del masks  # the counts program holds the last reference
+        lens = [len(r) for r in row_lists]
+        rows_np = [np.asarray(r, dtype=np.int32) for r in row_lists]  # a masks launch's row ids
+        arrays: list = []  # the pending result's: counts, then (pos, neg) pairs
+        parts: list[tuple[int, int, int]] = []  # (first parent, parents, counts slot)
+        sums: list[tuple[int, int]] = []  # (first pair, pos slot), in pair order
+
+        def expand(level: int, masks, start: int, n: int) -> None:
+            """``masks``: the ``n`` parents from ``start`` on of the
+            enumeration of the levels above ``level``."""
+            k_l = lens[level]
+            if level == last:
+                parts.append((start, n, len(arrays)))
+                arrays.append(
+                    self._gb_launch(
+                        "counts", gb_counts_call, masks, matrices[level],
+                        _pad_row_ids(row_lists[level], _pow2(k_l)),
+                    )
+                )
+                if sum_prog is None:
+                    return  # counts suffice: no masks of the last level
+            end = (start + n) * k_l
+            for lo in range(start * k_l, end, chunk_cap):
+                if lo > start * k_l:
+                    self._gb_wait(arrays[-1])
+                g, k = np.divmod(np.arange(lo, min(lo + chunk_cap, end)), k_l)
+                sub_masks = self._gb_make_masks(
+                    gb_masks_call, masks, matrices[level], g - start,
+                    rows_np[level][k], plane_bytes,
+                )
+                if level == last:
+                    pos, neg, _n = self._gb_launch("sums", sum_prog, agg_slices, sub_masks)
+                    sums.append((lo, len(arrays)))
+                    arrays.extend((pos, neg))
+                else:
+                    expand(level + 1, sub_masks, lo, g.size)
+                # the reference goes before the next chunk's are made: a
+                # level holds one chunk of masks, which is what was reserved
+                del sub_masks
+
+        expand(0, base_mask, 0, 1)
         ledger = self.gb_ledger
-        ledger.in_flight(held, counts)
+        ledger.in_flight(held, arrays[-1])
+        sum_starts = [lo for lo, _slot in sums]
 
         def finish(a):
+            """The surviving groups of every part, in numpy up to the reply's
+            own dicts: this runs on the wave's leader before the wave's
+            waiters are woken, with the device idle. A (field, row) cell
+            is ONE dict, shared by the groups of this reply that hold it:
+            two allocations a group instead of one a level more."""
             ledger.release(held)
-            cnt = a[0]  # [G, kp[last]]
+            cells = [
+                [{"field": f.name, "rowID": r} for r in rows]
+                for f, rows in zip(fields, row_lists)
+            ]
             results: list[dict] = []
-            for flat, k in np.argwhere(cnt > 0).tolist():
-                if limit is not None and len(results) >= limit:
-                    break
-                idxs = [k]
-                rem = flat
+            for start, n, slot in parts:
+                cnt = a[slot][:n, : lens[last]]  # the padding of both axes cut off
+                g, k = np.nonzero(cnt)  # row-major: (g-major, k-minor)
+                if limit is not None:
+                    g, k = g[: limit - len(results)], k[: limit - len(results)]
+                # a group's cell a level, from its parent's place in the enumeration
+                cols = [[cells[last][j] for j in k.tolist()]]
+                rem = g + start
                 for lvl in range(last - 1, -1, -1):
-                    idxs.append(rem % kp[lvl])
-                    rem //= kp[lvl]
-                idxs.reverse()
-                results.append(
-                    {
-                        "group": [
-                            {"field": fields[lvl].name,
-                             "rowID": row_lists[lvl][j]}
-                            for lvl, j in enumerate(idxs)
-                        ],
-                        "count": int(cnt[flat, k]),
-                    }
+                    rem, j = np.divmod(rem, lens[lvl])
+                    cols.append([cells[lvl][i] for i in j.tolist()])
+                cols.reverse()
+                first = len(results)
+                results.extend(
+                    {"group": group, "count": c}
+                    for *group, c in zip(*cols, cnt[g, k].tolist())
                 )
+                if sums:
+                    pairs = ((g + start) * lens[last] + k).tolist()
+                    for entry, pair in zip(results[first:], pairs):
+                        lo, at = sums[bisect.bisect_right(sum_starts, pair) - 1]
+                        entry["sum"] = ops.bsi.weigh_sum(a[at][pair - lo], a[at + 1][pair - lo])
             return results
 
-        return _Pending(
-            [counts], finish, route="mesh" if route_mesh else "device"
-        )
+        return _Pending(arrays, finish, route=route)
 
     # ------------------------------------------------------------ writes
     def _execute_includes_column(

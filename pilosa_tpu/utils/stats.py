@@ -62,9 +62,10 @@ _METRIC_HELP = {
     "shard_scope_rebuilds_total": "rebuilds of an index's memoized shard tuple (its mutation stamp moved)",
     "bsi_condition_leaves_total": "BSI comparison leaves planned, by operator",
     "device_scalar_uploads_total": "misses of the device operand-vector cache (one small upload each)",
-    "groupby_queries_total": "GroupBy calls by path: fused (one deferred readback), levels (a read a level), host",
+    "groupby_queries_total": "GroupBy calls by path: fused (all pairs, one deferred readback), levels (a read a level), host",
     "groupby_launches_total": "device programs issued for GroupBys (filter, counts, masks, sums)",
-    "groupby_level_readbacks_total": "synchronous device-to-host reads inside a GroupBy's dispatch",
+    "groupby_level_readbacks_total": "synchronous device-to-host reads inside a level-synchronous GroupBy's dispatch",
+    "groupby_chunk_waits_total": "waits of a deferred GroupBy for its own last program before its next chunk of masks",
     "groupby_mask_bytes_total": "bytes of group masks materialised on the device",
     "groupby_chunks_total": "pair chunks a level-synchronous GroupBy expanded",
     "groupby_transient_high_water_bytes": "most device bytes GroupBys in flight have held beside the stacks",
@@ -237,6 +238,13 @@ class StatsClient:
     def count(self, name: str, value: float = 1, tags: dict | None = None) -> None:
         with self._lock:
             self._counters[self._key(name, tags)] += value
+
+    def declare(self, name: str, tags: dict | None = None) -> None:
+        """Export counter ``name`` from now on, at 0 until it is counted:
+        a scrape then tells "never happened" from "a program without the
+        family". The registry only; nothing is emitted to a statsd sink."""
+        with self._lock:
+            self._counters[self._key(name, tags)] += 0
 
     def gauge(self, name: str, value: float, tags: dict | None = None) -> None:
         with self._lock:
@@ -493,6 +501,9 @@ def make_stats(service: str, statsd_host: str = "") -> StatsClient:
 
 class NopStats(StatsClient):
     def count(self, *a, **k):
+        pass
+
+    def declare(self, *a, **k):
         pass
 
     def gauge(self, *a, **k):

@@ -54,6 +54,7 @@ CAP = 4  # masks a level under the pinned budget
 NEW_FAMILIES = (
     "groupby_queries_total", "groupby_launches_total", "groupby_level_readbacks_total",
     "groupby_mask_bytes_total", "groupby_chunks_total", "groupby_transient_high_water_bytes",
+    "groupby_chunk_waits_total",
 )
 
 
@@ -272,30 +273,152 @@ def test_one_g4_moves_every_family_by_the_documented_amount(pinned, rides):
     }
 
 
+def _span_names() -> list[str]:
+    return [s["name"] for s in GLOBAL_TRACER.recent(4096)]
+
+
+def _clear_spans() -> None:
+    with GLOBAL_TRACER._lock:
+        GLOBAL_TRACER._spans.clear()
+
+
+def _fused(client: StatsClient) -> float:
+    with client._lock:
+        return client._counters[("groupby_queries_total", (("path", "fused"),))]
+
+
 def test_fused_and_aggregate_paths_are_counted(pinned):
-    """g1 and g3 fold on the device (one deferred readback, no read inside
-    the dispatch); g2's aggregate takes the level path and opens
-    ``executor.groupby.sums``."""
+    """g1 and g3 expand all pairs on the device (one deferred readback, no
+    read inside the dispatch); so does g2: its aggregate opens one
+    ``executor.groupby.sums`` on the masks of its one level, and its
+    counts and sums ride the wave's readback."""
     api, client = pinned
     api.executor.GROUPBY_MASK_BUDGET = None  # the default: everything fits
     try:
         before = {f: _family(client, f) for f in NEW_FAMILIES}
-        fused0 = client._counters[("groupby_queries_total", (("path", "fused"),))]
+        fused0 = _fused(client)
         ask(api, "g1_by_cab", 40)
         ask(api, "g3_by_passengers_year", 40)
-        assert client._counters[("groupby_queries_total", (("path", "fused"),))] == fused0 + 2
+        assert _fused(client) == fused0 + 2
         moved = {f: _family(client, f) - before[f] for f in NEW_FAMILIES}
         assert moved["groupby_launches_total"] == (1 + 1) + (1 + 1 + 1)
         assert moved["groupby_level_readbacks_total"] == 0
         assert moved["groupby_mask_bytes_total"] == 16 * PLANE  # g3's padded passenger rows
-        with GLOBAL_TRACER._lock:
-            GLOBAL_TRACER._spans.clear()
+        before = {f: _family(client, f) for f in NEW_FAMILIES}
+        _clear_spans()
         ask(api, "g2_amount_by_passengers", 40)
-        names = [s["name"] for s in GLOBAL_TRACER.recent(4096)]
+        assert _fused(client) == fused0 + 3
+        moved = {f: _family(client, f) - before[f] for f in NEW_FAMILIES}
+        assert moved["groupby_launches_total"] == 4  # filter, counts, masks [16], sums
+        assert moved["groupby_level_readbacks_total"] == 0
+        assert moved["groupby_chunk_waits_total"] == 0
+        assert moved["groupby_mask_bytes_total"] == 16 * PLANE
+        names = _span_names()
         assert names.count("executor.groupby.sums") == 1
-        assert names.count("executor.groupby.readback") == 2  # the counts, the sums
+        assert names.count("executor.groupby.readback") == 0
     finally:
         api.executor.GROUPBY_MASK_BUDGET = pinned_budget()
+
+
+@pytest.mark.parametrize("template", list(TEMPLATES))
+def test_no_level_is_read_back_under_the_default_budget(pinned, rides, template):
+    """Every template of the cell takes the deferred walk: no synchronous
+    read inside its dispatch, no wait for a chunk, the exact table."""
+    api, client = pinned
+    api.executor.GROUPBY_MASK_BUDGET = None
+    try:
+        before = {f: _family(client, f) for f in NEW_FAMILIES}
+        fused0 = _fused(client)
+        _clear_spans()
+        assert ask(api, template, 40) == reference(rides, template, 40)
+        moved = {f: _family(client, f) - before[f] for f in NEW_FAMILIES}
+        assert _fused(client) == fused0 + 1
+        assert moved["groupby_level_readbacks_total"] == 0
+        assert moved["groupby_chunk_waits_total"] == 0
+        names = _span_names()
+        assert "executor.groupby.readback" not in names
+        assert "executor.groupby.wait" not in names
+    finally:
+        api.executor.GROUPBY_MASK_BUDGET = pinned_budget()
+
+
+def two_chunk_budget() -> int:
+    """What the cell's chip gives the fourth query: the filter's plane,
+    the 16 padded passenger masks, ONE chunk of 64 (passenger, year)
+    masks and the temporaries, 82 planes; its 80 real pairs are 64 + 16."""
+    return (1 + 16 + 64 + ops.groupby.TEMP_PLANES) * PLANE
+
+
+def test_a_g4_in_two_chunks_waits_once_and_reads_nothing_back(pinned, rides):
+    """docs/observability.md: the deferred walk of three levels whose
+    (passenger, year) pairs need two chunks of the one reservation."""
+    api, client = pinned
+    api.executor.GROUPBY_MASK_BUDGET = two_chunk_budget()
+    try:
+        before = {f: _family(client, f) for f in NEW_FAMILIES}
+        fused0 = _fused(client)
+        mark0 = api.executor.gb_ledger.snapshot()["highWaterBytes"]
+        _clear_spans()
+        got = ask(api, "g4_by_passengers_year_distance", 40)
+        assert got == reference(rides, "g4_by_passengers_year_distance", 40)
+        moved = {f: _family(client, f) - before[f] for f in NEW_FAMILIES}
+        assert _fused(client) == fused0 + 1
+        # filter, masks [16], then masks [64] + counts [64]x32, masks [16] + counts [16]x32
+        assert moved["groupby_launches_total"] == 6
+        assert moved["groupby_chunk_waits_total"] == 1
+        assert moved["groupby_level_readbacks_total"] == 0
+        assert moved["groupby_mask_bytes_total"] == (16 + 64 + 16) * PLANE
+        spans = GLOBAL_TRACER.recent(4096)
+        (call,) = [s for s in spans if s["name"] == "executor.GroupBy"]
+        mine = [s for s in spans if s["name"].startswith("executor.groupby.")]
+        assert all(s["parentSpanID"] == call["spanID"] for s in mine)
+        assert sorted(s["name"] for s in mine) == sorted(
+            ["executor.groupby.filter"] + 3 * ["executor.groupby.masks"]
+            + 2 * ["executor.groupby.counts"] + ["executor.groupby.wait"]
+        )
+        ledger = api.executor.gb_ledger.snapshot()
+        assert ledger["heldBytes"] == 0
+        assert max(mark0, two_chunk_budget()) == ledger["highWaterBytes"]
+    finally:
+        api.executor.GROUPBY_MASK_BUDGET = pinned_budget()
+
+
+def test_sixteen_direct_threads_share_the_two_chunk_budget(holder, rides):
+    """``batch-mode`` off: every request thread dispatches its own query,
+    so sixteen walks reserve, wait for their own chunks and retire each
+    other's reservations at once. A g4 takes the whole budget and a g2 or
+    g3 eighteen planes of it: every answer exact, the mark inside the
+    budget, nothing held afterwards, no thread left waiting."""
+    client = StatsClient()
+    api = API(holder, stats=client, batch_mode="off",
+              router=QueryRouter(mode="device", stats=client))
+    api.executor.GROUPBY_MASK_BUDGET = two_chunk_budget()
+    want = {(name, t): reference(rides, name, t) for name in TEMPLATES for t in FLOORS}
+    wrong: list = []
+
+    def decks(seed: int):
+        rng = np.random.default_rng(seed)
+        try:
+            for _ in range(3):
+                for name in rng.permutation(list(TEMPLATES)):
+                    t = FLOORS[int(rng.integers(len(FLOORS)))]
+                    if ask(api, name, t) != want[(name, t)]:
+                        wrong.append((name, t))
+        except Exception as e:  # noqa: BLE001 — reported below
+            wrong.append(repr(e))
+
+    threads = [threading.Thread(target=decks, args=(300 + k,), daemon=True) for k in range(16)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=300)
+    assert not any(th.is_alive() for th in threads), "a thread is still waiting"
+    assert not wrong, wrong[:3]
+    assert _family(client, "groupby_level_readbacks_total") == 0
+    assert _family(client, "groupby_chunk_waits_total") == 16 * 3  # one a g4
+    ledger = api.executor.gb_ledger.snapshot()
+    assert 0 < ledger["highWaterBytes"] <= two_chunk_budget()
+    assert ledger["heldBytes"] == 0 and ledger["fusedInFlight"] == 0
 
 
 def test_resources_row_reads_the_ledger(tmp_path):

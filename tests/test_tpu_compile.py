@@ -100,6 +100,10 @@ def rig():
         "amount", FieldOptions(field_type="int", min=0, max=(1 << 17) - 1)
     )
     amount.import_values(cols, rng.integers(0, 1 << 11, n))
+    # the GroupBy cell's three grouped fields at their row counts: 10
+    # passenger rows (16 padded), 8 years, 32 rounded distances
+    for name, k in (("riders", 10), ("year", 8), ("miles", 32)):
+        idx.create_field(name).import_bulk(rng.integers(0, k, n).astype(np.uint64), cols)
     idx.mark_columns_exist(cols)
     e = Executor(h, route_mode="device")
     # GroupBy chunks its [G, S, W] group masks to an eighth of the stack
@@ -292,6 +296,60 @@ def test_groupby_programs_at_the_groupby_cells_shapes(rig, one_chip, groups):
     # masks, a chunk of 64 pair masks, the temporaries
     budget = int(16.9e9 * 0.7) // 8
     assert (1 + 16 + 64 + ops.groupby.TEMP_PLANES) * plane <= budget
+
+
+G2 = "GroupBy(Rows(riders), filter=Row(amount > 40), aggregate=Sum(field=amount))"
+G4 = "GroupBy(Rows(riders), Rows(year), Rows(miles), filter=Row(amount > 40))"
+# what the deferred walk launches for them at the cell's size, beside the
+# filter: (program, its arguments' leading dimensions). No shape the
+# level-synchronous walk did not launch; it launched four more a deck
+# (g2's two reads aside): g4's counts [1] x 16 and [16] x 8
+DEFERRED_LAUNCHES = {
+    G2: [("counts", (), (16,), (16,)), ("masks", (), (16,), (16,), (16,)),
+         ("sums", (16,), (16,))],
+    G4: [("masks", (), (16,), (16,), (16,)), ("masks", (16,), (8,), (64,), (64,)),
+         ("counts", (64,), (32,), (32,)), ("masks", (16,), (8,), (16,), (16,)),
+         ("counts", (16,), (32,), (32,))],
+}
+
+
+@pytest.mark.parametrize("pql", [G2, G4], ids=["g2", "g4"])
+def test_deferred_groupby_launches_at_the_groupby_cells_shapes(rig, one_chip, monkeypatch, pql):
+    """The second and fourth query of taxi-128g.groupby_fare on the
+    deferred walk, under the budget the cell's chip gives them (88 planes
+    of 128 shards): every program they launch, at the shapes they launch
+    it with, compiles for the described v5e with its temporaries inside
+    ``TEMP_PLANES``, and the list of them is the one above."""
+    h, _idx, _e = rig
+    shards, plane = 128, 128 * W * 4
+    e = Executor(h, route_mode="device")
+    e.GROUPBY_MASK_BUDGET = (int(16.9e9 * 0.7) // 8 // plane) * S_TINY * WORDS_PER_SHARD * 4
+    launches: list[tuple] = []
+    original = Executor._gb_launch
+
+    def launch(self, what, prog, *args):
+        if what != "filter":  # the planner's own program, compiled above
+            launches.append((what, prog, args))
+        return original(self, what, prog, *args)
+
+    monkeypatch.setattr(Executor, "_gb_launch", launch)
+    assert e.execute("taxi", pql)[0]
+    monkeypatch.undo()
+
+    def leading(x):
+        return tuple(x.shape[:-2]) if x.ndim >= 2 else tuple(x.shape)
+
+    assert [(what,) + tuple(leading(a) for a in args) for what, _p, args in launches] \
+        == DEFERRED_LAUNCHES[pql]
+    seen = set()
+    for what, prog, args in launches:
+        sized = real_size(args, lambda _s: one_chip, shards=shards)
+        key = (what,) + tuple(a.shape for a in sized)
+        if key in seen:
+            continue
+        seen.add(key)
+        mem = compile_and_fit(prog, sized).memory_analysis()
+        assert mem.temp_size_in_bytes <= ops.groupby.TEMP_PLANES * plane, key
 
 
 def test_stack_delta_and_store_scatters(one_chip):
