@@ -243,17 +243,22 @@ class GroupByLedger:
         token = _Held(int(nbytes))
         with self._cond:
             while self.held and self.held + token.nbytes > budget:
-                if self._in_flight:
-                    oldest = self._in_flight[0]
-                    done = oldest.done  # a release elsewhere clears the token's
-                    self._cond.release()
-                    try:
-                        done.block_until_ready()  # a wait for the device, no transfer
-                    finally:
-                        self._cond.acquire()
-                    self._drop(oldest)
-                else:
-                    self._cond.wait(0.05)
+                # a span only where the call waits (the leader's thread,
+                # under the scheduler): what fits at once pays nothing
+                with GLOBAL_TRACER.span(
+                    "executor.groupby.admit", bytes=token.nbytes
+                ):
+                    if self._in_flight:
+                        oldest = self._in_flight[0]
+                        done = oldest.done  # a release elsewhere clears the token's
+                        self._cond.release()
+                        try:
+                            done.block_until_ready()  # a wait for the device, no transfer
+                        finally:
+                            self._cond.acquire()
+                        self._drop(oldest)
+                    else:
+                        self._cond.wait(0.05)
             self.held += token.nbytes
             if self.held > self.high_water:
                 self.high_water = self.held

@@ -168,13 +168,15 @@ class _Waiter:
     ``execute_many``: all its items, one thread).  ``event`` is set for
     exactly two reasons, both under the scheduler's lock: the call's
     last item completed (``pending`` reached 0), or the call was handed
-    the leadership of the next wave (``WaveScheduler._heir``)."""
+    the leadership of the next wave (``WaveScheduler._heir``);
+    ``handed`` is then the scheduler's clock at that ``set()``."""
 
-    __slots__ = ("event", "pending")
+    __slots__ = ("event", "pending", "handed")
 
     def __init__(self) -> None:
         self.event = threading.Event()
         self.pending = 0  # items of this call not completed yet
+        self.handed = 0.0
 
 
 class _WorkItem:
@@ -531,6 +533,9 @@ class WaveScheduler:
                     if lead:
                         self._heir = None
                         why = "lead"
+                        # from the old leader's set() to this thread
+                        # running again: the hand-over of the leadership
+                        handover = self._clock() - waiter.handed
                     else:
                         why = "again" if waiter.pending else "done"
                     self.wakeups[why] += 1
@@ -538,6 +543,8 @@ class WaveScheduler:
                     self.stats.count(
                         "scheduler_wakeups_total", tags={"why": why}
                     )
+                    if lead:
+                        self._phase("handover", handover)
 
     def _release(self, waiter: _Waiter) -> bool:
         """End of the leader's one wave.  Nothing queued: nobody leads,
@@ -554,8 +561,19 @@ class WaveScheduler:
             if heir is waiter:
                 return True
             self._heir = heir
+            heir.handed = self._clock()
             heir.event.set()
             return False
+
+    def _phase(self, phase: str, seconds: float) -> None:
+        """What one wave's time went to: ``handover`` (a timer of its
+        own, across two threads), then ``window``, ``dispatch`` (the sum
+        of the wave's ``scheduler.query``), ``readback`` and ``settle``,
+        each observed once a wave from the duration its span already
+        holds.  A phase a wave did not have is not observed."""
+        self.stats.timing(
+            "scheduler_wave_phase_seconds", seconds, tags={"phase": phase}
+        )
 
     def _run_one_wave(self) -> None:
         # resolve the executor AT WAVE TIME, not from whatever instance
@@ -575,6 +593,8 @@ class WaveScheduler:
             with GLOBAL_TRACER.span("scheduler.window") as sp:
                 reason = self._wait_window(executor, batch)
                 sp.set_tag("reason", reason)
+            if self.stats is not None:
+                self._phase("window", sp.duration)
         try:
             self._execute_wave(executor, batch, reason)
         except Exception as e:  # noqa: BLE001 — harness backstop: a
@@ -661,6 +681,10 @@ class WaveScheduler:
             "scheduler.wave", queries=n, reason=reason
         ) as wave_span:
             settled: list[_WorkItem] = []
+            # the wave's phases are read off these spans once it is over
+            phase_spans: dict[str, list] = {
+                "dispatch": [], "readback": [], "settle": []
+            }
             for it in batch:
                 ctx = it.trace_ctx or (None, None)
                 try:
@@ -670,7 +694,8 @@ class WaveScheduler:
                                 "scheduler.query",
                                 wave=wave_span.span_id,
                                 queries=n,
-                            ):
+                            ) as sp:
+                                phase_spans["dispatch"].append(sp)
                                 it.raw = executor.dispatch(
                                     it.index,
                                     it.calls,
@@ -691,52 +716,57 @@ class WaveScheduler:
             if all_pending:
                 try:
                     fetch_seconds = self._readback(
-                        executor, all_pending, wave_span.span_id
+                        executor, all_pending, wave_span, phase_spans
                     )
                 except Exception:  # noqa: BLE001 — a poisoned joint
                     # readback falls back to per-query fetches below so
                     # only the poisoned query errors
                     joint_ok = False
-            # settle every query outside the lock (its own work, its
-            # own errors), then complete them all in ONE pass under one
+            # The leader's SETTLE, one span a wave: every query's results
+            # are finished outside the lock (its own work, its own
+            # errors), then all are completed in ONE pass under one
             # acquisition: the wave's waiters are woken together, by one
             # holder of the lock instead of one a query in turn
-            outcomes: list[tuple] = []
-            for it in settled:
-                try:
-                    if not joint_ok and it.pendings:
-                        fetch_seconds = self._readback(
-                            executor, it.pendings, wave_span.span_id
+            with GLOBAL_TRACER.span(
+                "scheduler.settle", wave=wave_span.span_id, queries=n
+            ) as sp:
+                phase_spans["settle"].append(sp)
+                outcomes: list[tuple] = []
+                for it in settled:
+                    try:
+                        if not joint_ok and it.pendings:
+                            fetch_seconds = self._readback(
+                                executor, it.pendings, wave_span, phase_spans
+                            )
+                        for p in it.pendings:
+                            p.resolve_fetched()
+                        outcomes.append(
+                            (
+                                it,
+                                finalize(it.raw),
+                                None,
+                                fetch_seconds if it.pendings else None,
+                            )
                         )
-                    for p in it.pendings:
-                        p.resolve_fetched()
-                    outcomes.append(
-                        (
+                    except Exception as e:  # noqa: BLE001 — per-query
+                        # isolation at settle: a finish() failure (bad
+                        # attr, overflow) errors its own query only
+                        outcomes.append((it, None, e, None))
+                with self._lock:
+                    for it, results, error, readback in outcomes:
+                        self._finish(
                             it,
-                            finalize(it.raw),
-                            None,
-                            fetch_seconds if it.pendings else None,
+                            results=results,
+                            error=error,
+                            readback=readback,
+                            # under the lock that seals it: "shared"
+                            # counts every follower this execution answers
+                            wave={
+                                "queries": n,
+                                "shared": 1 + len(it.followers),
+                                "flushReason": reason,
+                            },
                         )
-                    )
-                except Exception as e:  # noqa: BLE001 — per-query
-                    # isolation at settle: a finish() failure (bad
-                    # attr, overflow) errors its own query only
-                    outcomes.append((it, None, e, None))
-            with self._lock:
-                for it, results, error, readback in outcomes:
-                    self._finish(
-                        it,
-                        results=results,
-                        error=error,
-                        readback=readback,
-                        # under the lock that seals it: "shared" counts
-                        # every follower this execution answers
-                        wave={
-                            "queries": n,
-                            "shared": 1 + len(it.followers),
-                            "flushReason": reason,
-                        },
-                    )
         # final occupancy: every prime plus every follower it fanned
         # out to (followers can no longer join — all items sealed)
         n = len(batch) + sum(len(it.followers) for it in batch)
@@ -746,16 +776,23 @@ class WaveScheduler:
         if self.stats is not None:
             self.stats.observe("queries_per_wave", float(n))
             self.stats.count("wave_flush_reason", tags={"reason": reason})
+            self.stats.timing("scheduler_wave_seconds", wave_span.duration)
+            for phase, spans in phase_spans.items():
+                if spans:
+                    self._phase(phase, sum(sp.duration for sp in spans))
 
     @staticmethod
-    def _readback(executor, pendings: "list[_Pending]", wave_id: str) -> float:
+    def _readback(
+        executor, pendings: "list[_Pending]", wave_span, phase_spans: dict
+    ) -> float:
         """executor.fetch under the ``scheduler.readback`` span (the
         joint call and the per-query fall-back alike)."""
         with GLOBAL_TRACER.span(
             "scheduler.readback",
-            wave=wave_id,
+            wave=wave_span.span_id,
             arrays=sum(len(p.arrays) for p in pendings),
-        ):
+        ) as sp:
+            phase_spans["readback"].append(sp)
             return executor.fetch(pendings)
 
     def _finish(

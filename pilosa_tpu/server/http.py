@@ -927,9 +927,13 @@ class Handler(BaseHTTPRequestHandler):
         self._json({"version": __version__})
 
     def h_metrics(self) -> None:
+        # the tracer's per-span table is kept by the tracer and rendered
+        # here, when somebody reads it (docs/observability.md)
+        GLOBAL_TRACER.publish(self.stats)
         self._text(self.stats.prometheus(), content_type="text/plain; version=0.0.4")
 
     def h_debug_vars(self) -> None:
+        GLOBAL_TRACER.publish(self.stats)
         out = self.stats.expvar()
         # every section below carries the uniform snapshotMonotonicS +
         # generatedAt envelope (snapshot_envelope): sections used to mix

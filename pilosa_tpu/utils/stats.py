@@ -59,6 +59,12 @@ _METRIC_HELP = {
     "queries_served": "read legs this node executed",
     "queries_deduped": "queries answered by single-flight dedup",
     "scheduler_wakeups_total": "wake-ups of the wave scheduler's waiters, by reason",
+    "scheduler_wave_seconds": "one wave on its leader's thread (the scheduler.wave span)",
+    "scheduler_wave_phase_seconds": "what a wave's time went to: handover, window, dispatch, readback, settle",
+    "spans_total": "closed spans by name (the tracer's table, rendered at scrape time)",
+    "span_wall_seconds_total": "wall seconds inside spans of a name, children included",
+    "span_self_wall_seconds_total": "wall seconds of a span's self time, by the thread's open spans; kind=wait for spans that block on purpose",
+    "span_self_offcpu_seconds_total": "of a span's self time, seconds its thread was not running (in a work span: the wait for the interpreter lock)",
     "shard_scope_rebuilds_total": "rebuilds of an index's memoized shard tuple (its mutation stamp moved)",
     "bsi_condition_leaves_total": "BSI comparison leaves planned, by operator",
     "device_scalar_uploads_total": "misses of the device operand-vector cache (one small upload each)",

@@ -868,3 +868,157 @@ def test_solo_client_never_sleeps(entry, items, monkeypatch):
     snap = sched.snapshot()
     assert snap["wakeups"] == {"done": 0, "lead": 0, "again": 0}
     assert snap["waves"] == 5
+
+
+# ------------------------------------------- a wave's anatomy (ISSUE 36)
+# the leader's settle as a span, the hand-over of the leadership as a
+# timer across two threads, and one observation a phase a wave.
+def _phase(stats, phase):
+    return stats.histogram("scheduler_wave_phase_seconds", {"phase": phase})
+
+
+def _queued_behind_a_gated_leader(sched, e, names):
+    """Thread A leads and stops in its dispatch; the calls of ``names``
+    queue behind it in order; then A goes on.  The threads, joined."""
+    gate, entered = threading.Event(), threading.Event()
+    plain = e.dispatch
+
+    def gated(index, calls, shards, routes=None):
+        if threading.current_thread().name == "A" and not entered.is_set():
+            entered.set()
+            assert gate.wait(30)
+        return plain(index, calls, shards, routes=routes)
+
+    e.dispatch = gated
+    ts = [
+        threading.Thread(
+            target=sched.execute, args=("b", f"Count(Row(f={ord(n)}))"),
+            daemon=True, name=n,
+        )
+        for n in "A" + names
+    ]
+    ts[0].start()
+    assert entered.wait(30)
+    for depth, t in enumerate(ts[1:], start=1):
+        t.start()
+        deadline = time.monotonic() + 30
+        while len(sched._queue) < depth:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+    gate.set()
+    for t in ts:
+        t.join(30)
+        assert not t.is_alive()
+
+
+@pytest.mark.parametrize("max_queries,handovers", [(1, 3), (2, 2), (64, 1)])
+def test_handover_timer_observes_once_a_passed_leadership(max_queries, handovers):
+    """A releases with B, C, D queued: every wave after the first began
+    with a hand-over to another call, timed from the releasing leader's
+    set() to the heir running again, on the scheduler's clock."""
+    clock = FakeClock()
+    e, sched, stats = stub_rig(max_queries=max_queries, clock=clock)
+    e.dispatch_s = 0
+    _queued_behind_a_gated_leader(sched, e, "BCD")
+    snap = sched.snapshot()
+    assert snap["waves"] == handovers + 1
+    hist = _phase(stats, "handover")
+    count, total = hist.totals()
+    assert count == handovers == snap["wakeups"]["lead"]
+    # the fake clock steps once a reading: stamp and observation are two
+    # readings apart at least
+    assert total >= handovers * clock.step
+
+
+@pytest.mark.parametrize(
+    "case", ["solo", "leader_keeps_the_lead"], ids=lambda c: c
+)
+def test_no_handover_is_observed_without_one(case):
+    """A release onto an empty queue, and a leader whose own call heads
+    the queue again (an ``execute_many`` larger than a wave), hand
+    nothing over."""
+    e, sched, stats = stub_rig(max_queries=2, clock=FakeClock())
+    if case == "solo":
+        for j in range(3):
+            sched.execute("b", f"Count(Row(f={j}))")
+        waves = 3
+    else:
+        out = sched.execute_many(
+            [("b", f"Count(Row(f={j}))", None, None) for j in range(5)]
+        )
+        assert out == [[f"Count(Row(f={j}))"] for j in range(5)]
+        assert set(e.led_by) == {threading.current_thread().name}
+        waves = 3
+    assert sched.snapshot()["waves"] == waves
+    assert _phase(stats, "handover") is None
+    assert stats.histogram("scheduler_wave_seconds").totals()[0] == waves
+
+
+def test_settle_span_opens_once_a_wave_and_covers_finalize_and_wakeups(monkeypatch):
+    from pilosa_tpu.executor import scheduler as sched_mod
+    from pilosa_tpu.utils.tracing import GLOBAL_TRACER
+
+    with GLOBAL_TRACER._lock:
+        GLOBAL_TRACER._spans.clear()
+    e, sched, stats = stub_rig(max_queries=2)
+    inside: list = []
+    plain_finalize, plain_finish = sched_mod.finalize, sched._finish
+
+    def finalize(raw):
+        inside.append(("finalize", GLOBAL_TRACER.current_name()))
+        return plain_finalize(raw)
+
+    def finish(item, **kw):
+        inside.append(("finish", GLOBAL_TRACER.current_name()))
+        return plain_finish(item, **kw)
+
+    monkeypatch.setattr(sched_mod, "finalize", finalize)
+    sched._finish = finish
+    out = sched.execute_many(
+        [("b", f"Count(Row(f={j}))", None, None) for j in range(5)]
+    )
+    assert out == [[f"Count(Row(f={j}))"] for j in range(5)]
+    # five queries in waves of 2, 2 and 1: each finished and completed
+    # inside the wave's one settle span
+    assert inside.count(("finalize", "scheduler.settle")) == 5
+    assert inside.count(("finish", "scheduler.settle")) == 5
+    assert len(inside) == 10
+    spans = GLOBAL_TRACER.recent(4096)
+    waves = [s for s in spans if s["name"] == "scheduler.wave"]
+    settles = [s for s in spans if s["name"] == "scheduler.settle"]
+    assert len(waves) == len(settles) == 3
+    assert [s["tags"]["queries"] for s in settles] == [2, 2, 1]
+    # the settle is the wave's child, tagged with its id, and ends inside it
+    for wave, settle in zip(waves, settles):
+        assert settle["parentSpanID"] == settle["tags"]["wave"] == wave["spanID"]
+        assert settle["ts"] >= wave["ts"]
+        assert (
+            settle["ts"] + settle["durationSeconds"]
+            <= wave["ts"] + wave["durationSeconds"] + 1e-6
+        )
+
+
+def test_a_waves_phases_are_observed_once_each_and_fit_inside_it():
+    _h, _e, sched, stats = make_rig(mode="always", window_us=200.0)
+    for q in ("Count(Row(f=1))", "TopN(f, n=3)", "Sum(field=v)"):
+        sched.execute("b", q)
+    waves = sched.snapshot()["waves"]
+    assert waves == 3
+    wave_n, wave_s = stats.histogram("scheduler_wave_seconds").totals()
+    assert wave_n == waves
+    inside = 0.0
+    for phase in ("dispatch", "readback", "settle"):
+        n, total = _phase(stats, phase).totals()
+        assert n == waves, phase
+        inside += total
+    # the phases inside the wave are its spans' children: no more than it
+    assert 0 < inside <= wave_s
+    # the window is held before the wave's span opens; one a wave here
+    assert _phase(stats, "window").totals()[0] == waves
+    assert _phase(stats, "handover") is None  # one client: nothing passed
+    # and a full wave has no window to observe
+    _e2, sched2, stats2 = stub_rig(max_queries=1)
+    sched2.execute("b", "Count(Row(f=1))")
+    assert _phase(stats2, "window") is None
+    assert _phase(stats2, "readback") is None  # the stub's results are not pending
+    assert _phase(stats2, "dispatch").totals()[0] == 1
