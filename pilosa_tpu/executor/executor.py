@@ -183,10 +183,11 @@ class _Pending:
         return self.value
 
 
-# GroupBy's two device bodies live in ops/groupby.py; the mesh engine
-# runs the same two inside its shard_map trees.
+# GroupBy's three device bodies live in ops/groupby.py; the mesh engine
+# runs the same three inside its shard_map trees.
 _gb_counts = named_jit("pilosa_groupby_counts", ops.groupby.level_counts)
 _gb_masks = named_jit("pilosa_groupby_masks", ops.groupby.pair_masks)
+_gb_chains = named_jit("pilosa_groupby_chains", ops.groupby.chain_counts)
 
 # The most chunks of ``chunk_cap`` pairs that a GroupBy expands UNPRUNED,
 # every (parent, real row) pair with no level read back
@@ -311,6 +312,16 @@ def _pow2(n: int) -> int:
     return 1 << max(0, (n - 1)).bit_length()
 
 
+def _chain_table(lens: list[int]) -> np.ndarray:
+    """Every chain of one place a level in row lists of ``lens`` rows, in
+    (g-major, k-minor) order -> int32 ``[P_pad, levels]``, padded with -1
+    rows to a power of two (``ops.groupby.chain_counts``' table)."""
+    places = np.indices(lens, dtype=np.int32).reshape(len(lens), -1).T
+    table = np.full((_pow2(len(places)), len(lens)), -1, dtype=np.int32)
+    table[: len(places)] = places
+    return table
+
+
 def _pad_row_ids(rows: list[int], k_pad: int) -> np.ndarray:
     """Row ids padded to k_pad with -1: jnp.take(mode="fill") turns the
     padding into all-zero rows, so padded slots count 0 and prune."""
@@ -356,9 +367,13 @@ class Executor:
         # GroupBy's counters and ledger, whether or not a registry is behind them
         self._gb_stats = stats if stats is not None else NopStats()
         self.gb_ledger = GroupByLedger(self._gb_stats)
-        # the two counts of what a GroupBy PAID stand at 0 from the start:
-        # a scrape then says "never", not "a program without the family"
-        for family in ("groupby_level_readbacks_total", "groupby_chunk_waits_total"):
+        # the counts of what a GroupBy PAID, and of the GroupBys a chain
+        # count answered, stand at 0 from the start: a scrape then says
+        # "never", not "a program without the family"
+        for family in (
+            "groupby_level_readbacks_total", "groupby_chunk_waits_total",
+            "groupby_chain_queries_total",
+        ):
             self._gb_stats.declare(family)
         # per-call host/device routing (executor/router.py). Passing an
         # existing router preserves its calibration across executor
@@ -1696,12 +1711,12 @@ class Executor:
         return {"rows": rows}
 
     def _gb_programs(self, mesh_mode: str | None):
-        """(gb_counts, gb_masks) program callables for one GroupBy
-        execution: the single-program jitted pair, or the mesh engine's
-        shard_map pair (same bodies, psum merge tree) when the query
-        routed mesh — every call site below stays engine-agnostic."""
+        """(gb_counts, gb_masks, gb_chains) program callables for one
+        GroupBy execution: the single-program jitted ones, or the mesh
+        engine's shard_map ones (same bodies, psum merge tree) when the
+        query routed mesh — every call site below stays engine-agnostic."""
         if mesh_mode is None:
-            return _gb_counts, _gb_masks
+            return _gb_counts, _gb_masks, _gb_chains
         eng = self.compiler.mesh_engine
         ckey = ("mesh_gb_counts", mesh_mode)
         cprog = self.compiler.program(
@@ -1717,11 +1732,15 @@ class Executor:
         gbm = lambda masks, m, g_idx, row_sel: self.compiler._mesh_dispatch(
             "groupby", mprog, masks, m, g_idx, row_sel
         )
-        return gbc, gbm
+        chprog = self.compiler.program(
+            ("mesh_gb_chains", mesh_mode), lambda: eng.groupby_chains_tree(mesh_mode)
+        )
+        gbch = lambda *args: self.compiler._mesh_dispatch("groupby", chprog, *args)
+        return gbc, gbm, gbch
 
     def _gb_launch(self, what: str, prog, *args):
         """Issue one device program of a GroupBy under its span
-        (``executor.groupby.filter|counts|masks|sums``), counted in
+        (``executor.groupby.filter|counts|masks|chains|sums``), counted in
         ``groupby_launches_total``."""
         self._gb_stats.count("groupby_launches_total")
         with GLOBAL_TRACER.span(f"executor.groupby.{what}"):
@@ -1878,10 +1897,18 @@ class Executor:
             and chunks <= DEFERRED_CHUNKS
             and (limit is None or chunks == 1)
         )
-        held = self.gb_ledger.admit(need(chunk_cap), budget)
+        # Of those, a GroupBy of several levels without an aggregate needs
+        # no mask at all: one chain count ANDs the levels above the last
+        # inside the program, so it holds the filter's plane and the
+        # temporaries only
+        chain = deferred and len(fields) > 1 and agg_field is None
+        held = self.gb_ledger.admit(
+            (1 + ops.groupby.TEMP_PLANES) * plane_bytes if chain else need(chunk_cap),
+            budget,
+        )
         try:
             mesh_mode = self.compiler.mesh_mode(n_shards) if mesh else None
-            gb_counts_call, gb_masks_call = self._gb_programs(mesh_mode)
+            gb_counts_call, gb_masks_call, gb_chains_call = self._gb_programs(mesh_mode)
             sum_prog = (
                 self._gb_sum_program(agg_field, n_shards, mesh_mode)
                 if agg_field is not None
@@ -1905,10 +1932,13 @@ class Executor:
                 "groupby_queries_total",
                 tags={"path": "fused" if deferred else "levels"},
             )
+            if chain:
+                self._gb_stats.count("groupby_chain_queries_total")
             if deferred:
                 pend = self._groupby_deferred(
                     fields, row_lists, matrices, base_mask, limit, plane_bytes,
                     chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
+                    gb_chains_call if chain else None,
                     held, route="mesh" if mesh_mode is not None else "device",
                 )
                 held = None  # the pending result frees it
@@ -2092,7 +2122,7 @@ class Executor:
     def _groupby_deferred(
         self, fields, row_lists, matrices, base_mask, limit, plane_bytes,
         chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
-        held, route: str,
+        gb_chains_call, held, route: str,
     ):
         """All-pairs GroupBy over resident stacks: the level path's walk
         with nothing read back. On every level but the last, every
@@ -2117,7 +2147,12 @@ class Executor:
         walk waits for the device to finish the query's own last program
         (``_gb_wait``). The reservation is spent when the device has
         produced the last output (``GroupByLedger.in_flight``) or, at the
-        latest, with the readback."""
+        latest, with the readback.
+
+        With ``gb_chains_call`` (several levels, no aggregate) there is no
+        walk: ONE launch counts every chain of real rows of the levels
+        above the last against the last level's rows, in the same
+        enumeration, and no mask is made."""
         last = len(fields) - 1
         lens = [len(r) for r in row_lists]
         rows_np = [np.asarray(r, dtype=np.int32) for r in row_lists]  # a masks launch's row ids
@@ -2158,7 +2193,19 @@ class Executor:
                 # level holds one chunk of masks, which is what was reserved
                 del sub_masks
 
-        expand(0, base_mask, 0, 1)
+        if gb_chains_call is None:
+            expand(0, base_mask, 0, 1)
+        else:
+            ids = [_pad_row_ids(r, _pow2(len(r))) for r in row_lists]
+            n_chains = math.prod(lens[:last])
+            parts.append((0, n_chains, 0))
+            arrays.append(
+                self._gb_launch(
+                    "chains", gb_chains_call, base_mask, tuple(matrices[:last]),
+                    tuple(ids[:last]), _chain_table(lens[:last]), np.int32(n_chains),
+                    matrices[last], ids[last],
+                )
+            )
         ledger = self.gb_ledger
         ledger.in_flight(held, arrays[-1])
         sum_starts = [lo for lo, _slot in sums]

@@ -1,8 +1,8 @@
-"""GroupBy's two device bodies, shared by the single-program engine
+"""GroupBy's three device bodies, shared by the single-program engine
 (executor/executor.py jits them) and the mesh engine (parallel/mesh.py
 runs them inside ``shard_map`` under a psum tree).
 
-Neither gathers a ``[K, S, W]`` copy of the candidate rows before it
+None gathers a ``[K, S, W]`` copy of the candidate rows before it
 starts: they read a row of a stack where it lies (``plane``: a dynamic
 slice that fuses into its consumer), or gather the rows of one block of
 shards at a time. Compiled for a v5e at the cell's shapes (64 group
@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from pilosa_tpu.ops.bitwise import popcount_rows
 
 # Upper bound, in [S, W] planes, of the temporaries XLA allocates beside
-# the arguments and outputs of ANY program of a GroupBy (the two below and
+# the arguments and outputs of ANY program of a GroupBy (the three below and
 # the grouped sum, executor.Executor._grouped_sum_program), whatever the
 # number of groups: what the executor's transient ledger adds to the masks
 # a GroupBy holds. tests/test_tpu_compile.py compiles them for a described
@@ -54,6 +54,15 @@ def _groups(masks: jax.Array) -> jax.Array:
 SHARD_BLOCK = 8
 
 
+def _rows_block(matrix: jax.Array, rows: jax.Array, start) -> jax.Array:
+    """The ``rows`` of the stack ``matrix [R, S, W]`` in the block of
+    shards from ``start`` -> ``[K, block, W]``; zeros for a -1 (``take``
+    would wrap it to the last row) or a row past the stack."""
+    x = jax.lax.dynamic_slice_in_dim(matrix, start, SHARD_BLOCK, axis=1)
+    rows = jnp.where(rows < 0, matrix.shape[0], rows)
+    return jnp.take(x, rows, axis=0, mode="fill", fill_value=0)
+
+
 def level_counts(masks: jax.Array, matrix: jax.Array, rows: jax.Array) -> jax.Array:
     """``[G, S, W]`` group masks x the K candidate ``rows`` (ids into the
     ``[R, S, W]`` stack, -1 padding) -> int64 ``[G, K]`` columns in each
@@ -73,11 +82,76 @@ def level_counts(masks: jax.Array, matrix: jax.Array, rows: jax.Array) -> jax.Ar
 
     def block(start):
         m = jax.lax.dynamic_slice_in_dim(masks, start, SHARD_BLOCK, axis=1)
-        x = jax.lax.dynamic_slice_in_dim(matrix, start, SHARD_BLOCK, axis=1)
-        x = jnp.take(x, rows, axis=0, mode="fill", fill_value=0)  # [K, block, W]
+        x = _rows_block(matrix, rows, start)
         return jax.lax.map(
             lambda mg: jnp.sum(popcount_rows(x & mg[None]), axis=1, dtype=jnp.int32), m
         )  # [G, K]
+
+    starts = jnp.arange(n_shards // SHARD_BLOCK, dtype=jnp.int32) * SHARD_BLOCK
+    return jnp.sum(jax.lax.map(block, starts).astype(jnp.int64), axis=0)
+
+
+def chain_counts(
+    filt: jax.Array,
+    uppers: tuple,
+    upper_rows: tuple,
+    chains: jax.Array,
+    n_chains: jax.Array,
+    matrix: jax.Array,
+    rows: jax.Array,
+) -> jax.Array:
+    """The counts of a whole ``GroupBy`` without an aggregate, with no
+    group mask written to memory. ``filt`` is the filter's ``[S, W]``
+    plane (``[1, S, W]`` on the mesh route), ``uppers`` the stacks of
+    every level above the last and ``upper_rows`` each one's candidate
+    row ids (-1 padding), ``chains [P, L-1]`` the parent chains as
+    places in those lists, the ``n_chains`` real ones first, ``matrix``
+    the last level's stack and ``rows`` its K candidate row ids (-1
+    padding). -> int64 ``[P, K]``: entry ``[c, k]`` counts the columns of
+    ``filt & uppers[0][upper_rows[0][chains[c, 0]]] & ... &
+    matrix[rows[k]]``; the rows from ``n_chains`` on are 0 and cost
+    nothing.
+
+    The blocked form of ``level_counts``: inside a block of shards every
+    level's candidate rows stay put (the only rows gathered, so the
+    transient follows the query and not the stacks) while the chains go
+    by, and a chain's mask is ANDed once a block from them."""
+    filt = filt if filt.ndim == 2 else filt[0]
+    chains = jnp.asarray(chains)  # indexed by the loop's traced counter
+    n_shards = filt.shape[0]
+
+    def zeros(dtype):
+        # like the filter: inside shard_map the loop's carry then varies
+        # over the mesh axes as its body's output does
+        return jnp.zeros_like(filt, shape=(chains.shape[0], rows.shape[0]), dtype=dtype)
+
+    if n_shards % SHARD_BLOCK:
+        # whole planes, a chain's mask at a time: the transient is one plane
+        def per_chain(c, acc):
+            m = filt
+            for level, (stack, ids) in enumerate(zip(uppers, upper_rows)):
+                m = m & plane(stack, jnp.asarray(ids)[chains[c, level]])
+            counts = jax.lax.map(
+                lambda r: jnp.sum(popcount_rows(m & plane(matrix, r)).astype(jnp.int64)),
+                rows,
+            )
+            return jax.lax.dynamic_update_index_in_dim(acc, counts, c, axis=0)
+
+        return jax.lax.fori_loop(0, n_chains, per_chain, zeros(jnp.int64))
+
+    def block(start):
+        x = _rows_block(matrix, rows, start)
+        f = jax.lax.dynamic_slice_in_dim(filt, start, SHARD_BLOCK, axis=0)
+        ups = [_rows_block(u, ids, start) for u, ids in zip(uppers, upper_rows)]
+
+        def per_chain(c, acc):
+            m = f
+            for level, u in enumerate(ups):
+                m = m & jax.lax.dynamic_index_in_dim(u, chains[c, level], keepdims=False)
+            counts = jnp.sum(popcount_rows(x & m[None]), axis=1, dtype=jnp.int32)
+            return jax.lax.dynamic_update_index_in_dim(acc, counts, c, axis=0)
+
+        return jax.lax.fori_loop(0, n_chains, per_chain, zeros(jnp.int32))
 
     starts = jnp.arange(n_shards // SHARD_BLOCK, dtype=jnp.int32) * SHARD_BLOCK
     return jnp.sum(jax.lax.map(block, starts).astype(jnp.int64), axis=0)

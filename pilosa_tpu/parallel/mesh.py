@@ -573,6 +573,22 @@ class MeshQueryEngine:
             "groupby_masks", ops.groupby.pair_masks, (spec3, spec3, P(), P()), spec3
         )
 
+    def groupby_chains_tree(self, mode: str):
+        """(filter [1,S,W], upper stacks, their rows, chains [P, L-1],
+        real chains, matrix [R,S,W], rows [K]) → int64[P,K] replicated: a
+        whole GroupBy without an aggregate, no mask made, one psum tree
+        (executor._gb_chains, intra-mesh)."""
+        spec3 = self._arr_spec(1, mode)
+
+        def local(filt, uppers, upper_rows, chains, n_chains, matrix, rows):
+            return self._psum_both(ops.groupby.chain_counts(
+                filt, uppers, upper_rows, chains, n_chains, matrix, rows
+            ))
+
+        return self._spmd(
+            "groupby_chains", local, (spec3, spec3, P(), P(), P(), spec3, P()), P()
+        )
+
     # ------------------------------------------------------------ placement
     def spec_matrix(self) -> NamedSharding:
         return NamedSharding(self.mesh, P(None, AXIS_SHARDS, AXIS_WORDS))

@@ -72,6 +72,7 @@ _METRIC_HELP = {
     "groupby_launches_total": "device programs issued for GroupBys (filter, counts, masks, sums)",
     "groupby_level_readbacks_total": "synchronous device-to-host reads inside a level-synchronous GroupBy's dispatch",
     "groupby_chunk_waits_total": "waits of a deferred GroupBy for its own last program before its next chunk of masks",
+    "groupby_chain_queries_total": "GroupBys answered by one chain count launch (several levels, no aggregate, no mask made)",
     "groupby_mask_bytes_total": "bytes of group masks materialised on the device",
     "groupby_chunks_total": "pair chunks a level-synchronous GroupBy expanded",
     "groupby_transient_high_water_bytes": "most device bytes GroupBys in flight have held beside the stacks",

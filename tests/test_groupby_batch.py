@@ -31,8 +31,8 @@ def _setup():
 def test_groupby_level_dispatch_count(monkeypatch):
     h, cols, arows, brows, vals = _setup()
     e = Executor(h)
-    calls = {"counts": 0, "masks": 0}
-    orig_counts, orig_masks = ex_mod._gb_counts, ex_mod._gb_masks
+    calls = {"counts": 0, "masks": 0, "chains": 0}
+    orig_counts, orig_masks, orig_chains = ex_mod._gb_counts, ex_mod._gb_masks, ex_mod._gb_chains
     monkeypatch.setattr(
         ex_mod,
         "_gb_counts",
@@ -43,13 +43,19 @@ def test_groupby_level_dispatch_count(monkeypatch):
         "_gb_masks",
         lambda *a: (calls.__setitem__("masks", calls["masks"] + 1), orig_masks(*a))[1],
     )
+    monkeypatch.setattr(
+        ex_mod,
+        "_gb_chains",
+        lambda *a: (calls.__setitem__("chains", calls["chains"] + 1), orig_chains(*a))[1],
+    )
     res = e.execute("g", "GroupBy(Rows(a), Rows(b))")[0]
-    # fused all-pairs path: ONE masks dispatch folds level 0, ONE counts
-    # dispatch covers every (a-row, b-row) pair, and the readback defers
-    # to the execute() wave; 30×40 candidate pairs would have been ≥1200
-    # dispatches on the r1 path and 2 counts + 1 masks + per-level sync
-    # readbacks on the r3 level-synchronous path
-    assert calls["counts"] == 1 and calls["masks"] == 1
+    # without an aggregate the whole GroupBy is ONE chain count: every
+    # (a-row, b-row) pair counted with no mask made, and the readback
+    # defers to the execute() wave; 30×40 candidate pairs would have been
+    # ≥1200 dispatches on the r1 path, 2 counts + 1 masks + per-level sync
+    # readbacks on the r3 level-synchronous path, 1 masks + 1 counts on
+    # the all-pairs walk with masks
+    assert calls == {"counts": 0, "masks": 0, "chains": 1}
     assert len(res) > 0
 
 

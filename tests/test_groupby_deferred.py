@@ -7,7 +7,10 @@ expands the survivors. Entry for entry, on the device and mesh routes,
 against each other and against a brute-force table written here.
 
 Also the rule that picks the walk (``DEFERRED_CHUNKS``): from the row
-lists, the resident stacks and the chunk cap alone.
+lists, the resident stacks and the chunk cap alone; and, of the deferred
+``GroupBy``s, the ones of several levels without an aggregate as ONE
+chain count (``ops.groupby.chain_counts``), held to the masks and counts
+it replaces.
 """
 
 import numpy as np
@@ -161,29 +164,34 @@ def test_deferred_and_level_walks_agree_on_sparse_data(apis, table, query, chunk
         assert counter(walk_stats, "groupby_level_readbacks_total") == 0
 
 
-# (pql, budget, stack budget in rows or None, path, chunk waits)
+# (pql, budget, stack budget in rows or None, path, chunk waits, chain
+# counts): a deferred GroupBy of several levels without an aggregate is
+# one chain count, so it never waits for a chunk of masks
 RULE = {
-    "one_chunk": ("GroupBy(Rows(a), Rows(b), Rows(c))", None, None, "fused", 0),
-    "two_chunks": ("GroupBy(Rows(a), Rows(b), Rows(c))", budget(16), None, "fused", 1),
+    "one_chunk": ("GroupBy(Rows(a), Rows(b), Rows(c))", None, None, "fused", 0, 1),
+    "two_chunks": ("GroupBy(Rows(a), Rows(b), Rows(c))", budget(16), None, "fused", 0, 1),
     "two_chunks_of_sums": ("GroupBy(Rows(a), Rows(b), aggregate=Sum(field=v))",
-                           budget(16), None, "fused", 1),
-    "four_chunks": ("GroupBy(Rows(a), Rows(b), Rows(c))", budget(8), None, "levels", 0),
-    "limit_in_one_chunk": ("GroupBy(Rows(a), Rows(b), Rows(c), limit=4)", None, None, "fused", 0),
+                           budget(16), None, "fused", 1, 0),
+    "four_chunks": ("GroupBy(Rows(a), Rows(b), Rows(c))", budget(8), None, "levels", 0, 0),
+    "limit_in_one_chunk": ("GroupBy(Rows(a), Rows(b), Rows(c), limit=4)", None, None,
+                           "fused", 0, 1),
     "limit_in_two_chunks": ("GroupBy(Rows(a), Rows(b), Rows(c), limit=4)",
-                            budget(16), None, "levels", 0),
-    "a_streamed_level": ("GroupBy(Rows(a), Rows(wide))", None, 16, "levels", 0),
+                            budget(16), None, "levels", 0, 0),
+    "a_streamed_level": ("GroupBy(Rows(a), Rows(wide))", None, 16, "levels", 0, 0),
+    "one_level": ("GroupBy(Rows(a), filter=Row(c=3))", None, None, "fused", 0, 0),
 }
 
 
 @pytest.mark.parametrize("case", list(RULE))
 def test_the_walk_is_chosen_from_pairs_stacks_and_cap(holder, apis, monkeypatch, case):
-    pql, pinned, stack_rows, path, waits = RULE[case]
+    pql, pinned, stack_rows, path, waits, chained = RULE[case]
     if stack_rows is not None:  # wide's 64 padded rows no longer fit a stack
         monkeypatch.setattr(StackCache, "STACK_BYTES_BUDGET", stack_rows * PLANE)
     api, client = _api(holder, "device", pinned)
     got = api.query("s", pql)["results"][0]
     assert paths(client) == {path: 1}
     assert counter(client, "groupby_chunk_waits_total") == waits
+    assert counter(client, "groupby_chain_queries_total") == chained
     assert (counter(client, "groupby_level_readbacks_total") > 0) == (path == "levels")
     if stack_rows is None:
         assert got == apis[("device", "levels")][0].query("s", pql)["results"][0]
@@ -194,3 +202,64 @@ def test_the_walk_is_chosen_from_pairs_stacks_and_cap(holder, apis, monkeypatch,
     assert ledger["heldBytes"] == 0
     if pinned is not None:
         assert ledger["highWaterBytes"] <= pinned
+
+
+def _pop(words: np.ndarray) -> np.ndarray:
+    """Set bits of each ``[..., S, W]`` plane, counted bit by bit."""
+    return np.unpackbits(words.view(np.uint8), axis=-1).sum(axis=(-1, -2)).astype(np.int64)
+
+
+@pytest.mark.parametrize("shards", [8, 3], ids=["blocked", "whole_planes"])
+@pytest.mark.parametrize("levels", [2, 3])
+def test_chain_counts_equal_masks_then_counts(levels, shards):
+    """One chain count against the masks of every level made and counted
+    (``pair_masks`` level by level, then ``level_counts``) and against
+    numpy: padded last-level rows (-1), padding chains past the real
+    ones, an all-zero upper row and an all-zero filter word count 0."""
+    rng = np.random.default_rng(38 * levels + shards)
+    w = 64
+
+    def bits(*shape):
+        return rng.integers(0, 1 << 32, shape, dtype=np.uint32) & rng.integers(
+            0, 1 << 32, shape, dtype=np.uint32)
+
+    filt = bits(shards, w)
+    filt[:, 5] = 0
+    uppers = [bits(r, shards, w) for r in (5, 4)[: levels - 1]]
+    uppers[0][3] = 0  # every chain through this row is all-zero
+    last = bits(6, shards, w)
+    # each upper level's candidate rows, -1 padded; the table holds places in them
+    upper_rows = tuple(np.array(r, np.int32) for r in ([4, 0, 1, 3], [1, 3, 2, -1])[: levels - 1])
+    real = [4, 3][: levels - 1]
+    rows = np.array([4, 0, 5, 2, -1, -1, -1, -1], dtype=np.int32)
+    n = int(np.prod(real))
+    table = np.full((16, levels - 1), -1, dtype=np.int32)
+    table[:n] = np.indices(real).reshape(levels - 1, -1).T
+    ids = np.stack([np.where(table[:, lv] >= 0, upper_rows[lv][table[:, lv]], -1)
+                    for lv in range(levels - 1)], axis=1)  # the chains as row ids
+
+    got = np.asarray(ops.groupby.chain_counts(
+        filt, tuple(uppers), upper_rows, table, np.int32(n), last, rows))
+    assert got.shape == (16, 8) and got.dtype == np.int64
+
+    masks = ops.groupby.pair_masks(filt, uppers[0], np.zeros(16, np.int32), ids[:, 0])
+    for level in range(1, levels - 1):
+        masks = ops.groupby.pair_masks(masks, uppers[level], np.arange(16, dtype=np.int32),
+                                       ids[:, level])
+    assert (got == np.asarray(ops.groupby.level_counts(masks, last, rows))).all()
+
+    want = np.zeros((16, 8), np.int64)
+    for c in range(n):
+        m = filt.copy()
+        for level, stack in enumerate(uppers):
+            m &= stack[ids[c, level]]
+        for k, r in enumerate(rows):
+            if r >= 0:
+                want[c, k] = _pop(m & last[r])
+    assert (got == want).all()
+    assert (got[ids[:, 0] == 3] == 0).all() and got[:n].sum() > 0
+
+    # a real-chain count below the table's real rows: the rest are 0
+    fewer = np.asarray(ops.groupby.chain_counts(
+        filt, tuple(uppers), upper_rows, table, np.int32(n - 3), last, rows))
+    assert (fewer[: n - 3] == want[: n - 3]).all() and not fewer[n - 3:].any()

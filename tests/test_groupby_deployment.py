@@ -54,7 +54,7 @@ CAP = 4  # masks a level under the pinned budget
 NEW_FAMILIES = (
     "groupby_queries_total", "groupby_launches_total", "groupby_level_readbacks_total",
     "groupby_mask_bytes_total", "groupby_chunks_total", "groupby_transient_high_water_bytes",
-    "groupby_chunk_waits_total",
+    "groupby_chunk_waits_total", "groupby_chain_queries_total",
 )
 
 
@@ -87,7 +87,10 @@ def holder(rides):
 def reference(rides, template: str, t: int) -> list[dict]:
     """What ``results[0]`` must be: the joint table of the grouped fields
     over the rides above the floor, its cells in nested ascending order."""
-    fields = GROUPED[template]
+    return joint_table(rides, GROUPED[template], t, "aggregate=" in TEMPLATES[template])
+
+
+def joint_table(rides, fields: list[str], t: int, agg: bool) -> list[dict]:
     keep = rides[AMOUNT] > t
     at = tuple(rides[f][keep] for f in fields)
     shape = tuple(ROWS[f] for f in fields)
@@ -99,7 +102,7 @@ def reference(rides, template: str, t: int) -> list[dict]:
     for cell in np.argwhere(count > 0).tolist():
         g = {"group": [{"field": f, "rowID": r} for f, r in zip(fields, cell)],
              "count": int(count[tuple(cell)])}
-        if "aggregate=" in TEMPLATES[template]:
+        if agg:
             g["sum"] = int(total[tuple(cell)])
         out.append(g)
     return out
@@ -154,6 +157,34 @@ def test_served_reply_equals_the_brute_force_table(apis, rides, template, t, rou
 def test_same_reply_in_chunks_of_four(apis, pinned, template, t):
     api, _client = pinned
     assert ask(api, template, t) == ask(apis["device"], template, t)
+
+
+@pytest.fixture(scope="module")
+def counted(holder):
+    """A server a route with a registry of its own behind it."""
+    out = {}
+    for route in ("device", "host", "mesh"):
+        client = StatsClient()
+        out[route] = (_api(holder, route, stats=client), client)
+    return out
+
+
+@pytest.mark.parametrize("limit", [None, 7, 100])
+@pytest.mark.parametrize("route", ["device", "host", "mesh"])
+@pytest.mark.parametrize("template", ["g3_by_passengers_year", "g4_by_passengers_year_distance"])
+def test_chain_count_answers_with_and_without_limit(counted, rides, template, route, limit):
+    """The g3 and g4 shapes, one chain count on the device and mesh
+    routes and the numpy engine on the host route: the same table, cut
+    by a ``limit`` where the query has one, and counted in
+    ``groupby_chain_queries_total`` where a chain count answered."""
+    api, client = counted[route]
+    pql = TEMPLATES[template].format(t=40)
+    if limit is not None:
+        pql = pql[:-1] + f", limit={limit})"
+    chained = _family(client, "groupby_chain_queries_total")
+    got = api.query("taxi", pql)["results"][0]
+    assert got == reference(rides, template, 40)[:limit]
+    assert _family(client, "groupby_chain_queries_total") == chained + (route != "host")
 
 
 def _family(client: StatsClient, name: str) -> float:
@@ -288,10 +319,11 @@ def _fused(client: StatsClient) -> float:
 
 
 def test_fused_and_aggregate_paths_are_counted(pinned):
-    """g1 and g3 expand all pairs on the device (one deferred readback, no
-    read inside the dispatch); so does g2: its aggregate opens one
-    ``executor.groupby.sums`` on the masks of its one level, and its
-    counts and sums ride the wave's readback."""
+    """g1 and g3 count all pairs on the device (one deferred readback, no
+    read inside the dispatch; g3 as one chain count, no mask made); so
+    does g2: its aggregate opens one ``executor.groupby.sums`` on the
+    masks of its one level, and its counts and sums ride the wave's
+    readback."""
     api, client = pinned
     api.executor.GROUPBY_MASK_BUDGET = None  # the default: everything fits
     try:
@@ -301,9 +333,10 @@ def test_fused_and_aggregate_paths_are_counted(pinned):
         ask(api, "g3_by_passengers_year", 40)
         assert _fused(client) == fused0 + 2
         moved = {f: _family(client, f) - before[f] for f in NEW_FAMILIES}
-        assert moved["groupby_launches_total"] == (1 + 1) + (1 + 1 + 1)
+        assert moved["groupby_launches_total"] == (1 + 1) + (1 + 1)  # filter, counts | chains
         assert moved["groupby_level_readbacks_total"] == 0
-        assert moved["groupby_mask_bytes_total"] == 16 * PLANE  # g3's padded passenger rows
+        assert moved["groupby_chain_queries_total"] == 1
+        assert moved["groupby_mask_bytes_total"] == 0
         before = {f: _family(client, f) for f in NEW_FAMILIES}
         _clear_spans()
         ask(api, "g2_amount_by_passengers", 40)
@@ -312,6 +345,7 @@ def test_fused_and_aggregate_paths_are_counted(pinned):
         assert moved["groupby_launches_total"] == 4  # filter, counts, masks [16], sums
         assert moved["groupby_level_readbacks_total"] == 0
         assert moved["groupby_chunk_waits_total"] == 0
+        assert moved["groupby_chain_queries_total"] == 0
         assert moved["groupby_mask_bytes_total"] == 16 * PLANE
         names = _span_names()
         assert names.count("executor.groupby.sums") == 1
@@ -343,15 +377,22 @@ def test_no_level_is_read_back_under_the_default_budget(pinned, rides, template)
 
 
 def two_chunk_budget() -> int:
-    """What the cell's chip gives the fourth query: the filter's plane,
-    the 16 padded passenger masks, ONE chunk of 64 (passenger, year)
-    masks and the temporaries, 82 planes; its 80 real pairs are 64 + 16."""
+    """What the cell's chip gives the fourth query's walk: the filter's
+    plane, the 16 padded passenger masks, ONE chunk of 64 (passenger,
+    year) masks and the temporaries, 82 planes; its 80 real pairs are
+    64 + 16. A walk of two chunks is deferred; without an aggregate it is
+    one chain count and holds the filter's plane and the temporaries."""
     return (1 + 16 + 64 + ops.groupby.TEMP_PLANES) * PLANE
 
 
+def chain_need() -> int:
+    return (1 + ops.groupby.TEMP_PLANES) * PLANE
+
+
 def test_a_g4_in_two_chunks_waits_once_and_reads_nothing_back(pinned, rides):
-    """docs/observability.md: the deferred walk of three levels whose
-    (passenger, year) pairs need two chunks of the one reservation."""
+    """docs/observability.md: the three levels whose (passenger, year)
+    pairs would need two chunks of masks are ONE chain count: no mask, no
+    wait for a chunk, nothing read back, two planes reserved."""
     api, client = pinned
     api.executor.GROUPBY_MASK_BUDGET = two_chunk_budget()
     try:
@@ -363,19 +404,52 @@ def test_a_g4_in_two_chunks_waits_once_and_reads_nothing_back(pinned, rides):
         assert got == reference(rides, "g4_by_passengers_year_distance", 40)
         moved = {f: _family(client, f) - before[f] for f in NEW_FAMILIES}
         assert _fused(client) == fused0 + 1
-        # filter, masks [16], then masks [64] + counts [64]x32, masks [16] + counts [16]x32
-        assert moved["groupby_launches_total"] == 6
-        assert moved["groupby_chunk_waits_total"] == 1
+        assert moved["groupby_launches_total"] == 2  # filter, chains [128, 2] x 32
+        assert moved["groupby_chain_queries_total"] == 1
+        assert moved["groupby_chunk_waits_total"] == 0
         assert moved["groupby_level_readbacks_total"] == 0
-        assert moved["groupby_mask_bytes_total"] == (16 + 64 + 16) * PLANE
+        assert moved["groupby_mask_bytes_total"] == 0
         spans = GLOBAL_TRACER.recent(4096)
         (call,) = [s for s in spans if s["name"] == "executor.GroupBy"]
         mine = [s for s in spans if s["name"].startswith("executor.groupby.")]
         assert all(s["parentSpanID"] == call["spanID"] for s in mine)
-        assert sorted(s["name"] for s in mine) == sorted(
-            ["executor.groupby.filter"] + 3 * ["executor.groupby.masks"]
-            + 2 * ["executor.groupby.counts"] + ["executor.groupby.wait"]
-        )
+        assert sorted(s["name"] for s in mine) == [
+            "executor.groupby.chains", "executor.groupby.filter"]
+        ledger = api.executor.gb_ledger.snapshot()
+        assert ledger["heldBytes"] == 0
+        assert max(mark0, chain_need()) == ledger["highWaterBytes"]
+    finally:
+        api.executor.GROUPBY_MASK_BUDGET = pinned_budget()
+
+
+G3_SUM = (f"GroupBy(Rows(passenger_count), Rows(pickup_year), filter=Row({AMOUNT} > 40), "
+          f"aggregate=Sum(field={AMOUNT}))")
+
+
+def test_a_g3_with_a_sum_in_two_chunks_waits_once(pinned, rides):
+    """An aggregate still needs the last level's masks: the (passenger,
+    year) pairs in two chunks of the one reservation of 82 planes, one
+    wait for the first chunk's sums before the second chunk is made."""
+    api, client = pinned
+    api.executor.GROUPBY_MASK_BUDGET = two_chunk_budget()
+    try:
+        before = {f: _family(client, f) for f in NEW_FAMILIES}
+        fused0 = _fused(client)
+        mark0 = api.executor.gb_ledger.snapshot()["highWaterBytes"]
+        _clear_spans()
+        got = api.query("taxi", G3_SUM)["results"][0]
+        assert got == joint_table(rides, ["passenger_count", "pickup_year"], 40, True)
+        moved = {f: _family(client, f) - before[f] for f in NEW_FAMILIES}
+        assert _fused(client) == fused0 + 1
+        # filter, masks [16], counts [16]x8, masks [64] + sums, masks [16] + sums
+        assert moved["groupby_launches_total"] == 7
+        assert moved["groupby_chain_queries_total"] == 0
+        assert moved["groupby_chunk_waits_total"] == 1
+        assert moved["groupby_level_readbacks_total"] == 0
+        assert moved["groupby_mask_bytes_total"] == (16 + 64 + 16) * PLANE
+        assert sorted(n for n in _span_names() if n.startswith("executor.groupby.")) == sorted(
+            ["executor.groupby.filter", "executor.groupby.counts", "executor.groupby.wait"]
+            + 3 * ["executor.groupby.masks"] + 2 * ["executor.groupby.sums"])
         ledger = api.executor.gb_ledger.snapshot()
         assert ledger["heldBytes"] == 0
         assert max(mark0, two_chunk_budget()) == ledger["highWaterBytes"]
@@ -385,10 +459,11 @@ def test_a_g4_in_two_chunks_waits_once_and_reads_nothing_back(pinned, rides):
 
 def test_sixteen_direct_threads_share_the_two_chunk_budget(holder, rides):
     """``batch-mode`` off: every request thread dispatches its own query,
-    so sixteen walks reserve, wait for their own chunks and retire each
-    other's reservations at once. A g4 takes the whole budget and a g2 or
-    g3 eighteen planes of it: every answer exact, the mark inside the
-    budget, nothing held afterwards, no thread left waiting."""
+    so sixteen walks reserve and retire each other's reservations at
+    once. A g2 takes eighteen planes of the budget and a g1, g3 or g4 two
+    (the g3 and g4 as one chain count each, no chunk to wait for): every
+    answer exact, the mark inside the budget, nothing held afterwards, no
+    thread left waiting."""
     client = StatsClient()
     api = API(holder, stats=client, batch_mode="off",
               router=QueryRouter(mode="device", stats=client))
@@ -415,7 +490,8 @@ def test_sixteen_direct_threads_share_the_two_chunk_budget(holder, rides):
     assert not any(th.is_alive() for th in threads), "a thread is still waiting"
     assert not wrong, wrong[:3]
     assert _family(client, "groupby_level_readbacks_total") == 0
-    assert _family(client, "groupby_chunk_waits_total") == 16 * 3  # one a g4
+    assert _family(client, "groupby_chunk_waits_total") == 0
+    assert _family(client, "groupby_chain_queries_total") == 16 * 3 * 2  # a g3 and a g4 a deck
     ledger = api.executor.gb_ledger.snapshot()
     assert 0 < ledger["highWaterBytes"] <= two_chunk_budget()
     assert ledger["heldBytes"] == 0 and ledger["fusedInFlight"] == 0
@@ -451,8 +527,8 @@ def test_resources_row_reads_the_ledger(tmp_path):
         assert len(groups) == 30
         row = call("GET", "/debug/resources")["subsystems"]["groupbyTransient"]
         assert row["unit"] == "bytes" and row["used"] == 0 and row["fusedInFlight"] == 0
-        # the filter's plane, 4 + 8 padded masks, the temporaries: one shard
-        assert row["highWaterBytes"] == (1 + 4 + 8 + ops.groupby.TEMP_PLANES) * WORDS_PER_SHARD * 4
+        # one chain count, no mask: the filter's plane and the temporaries of one shard
+        assert row["highWaterBytes"] == (1 + ops.groupby.TEMP_PLANES) * WORDS_PER_SHARD * 4
         assert row["limit"] == srv.api.executor._gb_budget() and row["pressure"] == 0.0
     finally:
         srv.close()

@@ -299,52 +299,68 @@ def test_groupby_programs_at_the_groupby_cells_shapes(rig, one_chip, groups):
 
 
 G2 = "GroupBy(Rows(riders), filter=Row(amount > 40), aggregate=Sum(field=amount))"
+G3 = "GroupBy(Rows(riders), Rows(year), filter=Row(amount > 40))"
 G4 = "GroupBy(Rows(riders), Rows(year), Rows(miles), filter=Row(amount > 40))"
 # what the deferred walk launches for them at the cell's size, beside the
-# filter: (program, its arguments' leading dimensions). No shape the
-# level-synchronous walk did not launch; it launched four more a deck
-# (g2's two reads aside): g4's counts [1] x 16 and [16] x 8
+# filter: (program, its arguments' leading dimensions; a tuple of stacks
+# as a tuple). A GroupBy of several levels without an aggregate is ONE
+# chain count: the upper stacks and their padded rows, the (riders[,
+# year]) chains of real rows [P, L-1], how many are real, the last
+# level's stack and its padded rows. The walk
+# with masks launched g4's masks [16], [64] and [16] and counts [64] x 32
+# and [16] x 32 (the 64/16 programs of the level path, compiled above)
 DEFERRED_LAUNCHES = {
     G2: [("counts", (), (16,), (16,)), ("masks", (), (16,), (16,), (16,)),
          ("sums", (16,), (16,))],
-    G4: [("masks", (), (16,), (16,), (16,)), ("masks", (16,), (8,), (64,), (64,)),
-         ("counts", (64,), (32,), (32,)), ("masks", (16,), (8,), (16,), (16,)),
-         ("counts", (16,), (32,), (32,))],
+    G3: [("chains", (), ((16,),), ((16,),), (16, 1), (), (8,), (8,))],
+    G4: [("chains", (), ((16,), (8,)), ((16,), (8,)), (128, 2), (), (32,), (32,))],
 }
 
 
-@pytest.mark.parametrize("pql", [G2, G4], ids=["g2", "g4"])
+@pytest.mark.parametrize("pql", [G2, G3, G4], ids=["g2", "g3", "g4"])
 def test_deferred_groupby_launches_at_the_groupby_cells_shapes(rig, one_chip, monkeypatch, pql):
-    """The second and fourth query of taxi-128g.groupby_fare on the
-    deferred walk, under the budget the cell's chip gives them (88 planes
-    of 128 shards): every program they launch, at the shapes they launch
-    it with, compiles for the described v5e with its temporaries inside
-    ``TEMP_PLANES``, and the list of them is the one above."""
+    """The second, third and fourth query of taxi-128g.groupby_fare on
+    the deferred walk, under the budget the cell's chip gives them (88
+    planes of 128 shards): every program they launch, at the shapes they
+    launch it with, compiles for the described v5e with its temporaries
+    inside ``TEMP_PLANES``, the list of them is the one above, and a
+    chain count reserves the filter's plane and the temporaries alone."""
     h, _idx, _e = rig
     shards, plane = 128, 128 * W * 4
     e = Executor(h, route_mode="device")
     e.GROUPBY_MASK_BUDGET = (int(16.9e9 * 0.7) // 8 // plane) * S_TINY * WORDS_PER_SHARD * 4
     launches: list[tuple] = []
-    original = Executor._gb_launch
+    reserved: list[int] = []
+    original, admit = Executor._gb_launch, executor_mod.GroupByLedger.admit
 
     def launch(self, what, prog, *args):
         if what != "filter":  # the planner's own program, compiled above
             launches.append((what, prog, args))
         return original(self, what, prog, *args)
 
+    def admitted(self, nbytes, budget):
+        reserved.append(nbytes // (S_TINY * WORDS_PER_SHARD * 4))
+        return admit(self, nbytes, budget)
+
     monkeypatch.setattr(Executor, "_gb_launch", launch)
+    monkeypatch.setattr(executor_mod.GroupByLedger, "admit", admitted)
     assert e.execute("taxi", pql)[0]
     monkeypatch.undo()
+    # in planes: g4's walk with masks reserved 1 + 16 + 64 + TEMP_PLANES
+    chain = launches[0][0] == "chains"
+    assert reserved == [1 + (0 if chain else 16) + ops.groupby.TEMP_PLANES]
 
     def leading(x):
-        return tuple(x.shape[:-2]) if x.ndim >= 2 else tuple(x.shape)
+        if isinstance(x, tuple):
+            return tuple(leading(a) for a in x)
+        return tuple(x.shape[:-2]) if x.shape[-2:] == (S_TINY, WORDS_PER_SHARD) else tuple(x.shape)
 
     assert [(what,) + tuple(leading(a) for a in args) for what, _p, args in launches] \
         == DEFERRED_LAUNCHES[pql]
     seen = set()
     for what, prog, args in launches:
         sized = real_size(args, lambda _s: one_chip, shards=shards)
-        key = (what,) + tuple(a.shape for a in sized)
+        key = (what,) + tuple(str(jax.tree_util.tree_map(np.shape, a)) for a in sized)
         if key in seen:
             continue
         seen.add(key)
@@ -444,12 +460,14 @@ def test_mesh_count_and_topn(rig, mesh):
 S_CELL = 512
 
 
-@pytest.mark.parametrize("program", ["topn", "sum", "count"])
+@pytest.mark.parametrize("program", ["topn", "sum", "count", "chains"])
 def test_mesh_programs_at_the_four_chip_cells_shapes(rig, mesh, program):
     """Q4's TopN over [32, 512, W] under a two-row filter, Q2's Sum over
-    [16, 512, W] under a one-row filter and Q3's Count of an Intersect
-    compile for the 4 x 1 v5e mesh, 128 shards a chip, and their psum
-    trees carry the scope the device trace is read by."""
+    [16, 512, W] under a one-row filter, Q3's Count of an Intersect and a
+    g4-shaped GroupBy's chain count ([128, 2] chains of the 16 and 8 row
+    stacks against 32 rows, temporaries inside ``TEMP_PLANES`` of a
+    chip's plane) compile for the 4 x 1 v5e mesh, 128 shards a chip, and
+    their psum trees carry the scope the device trace is read by."""
     engine = MeshQueryEngine(mesh)
     placed = placed_on(mesh)
     plan = lambda pql: mesh_plan(rig, placed, pql, S_CELL)
@@ -467,9 +485,18 @@ def test_mesh_programs_at_the_four_chip_cells_shapes(rig, mesh, program):
         amount = rig[1].field("amount")
         prog = engine.sum_tree(Executor._sum_fn(amount), "grid", frun=frun)
         args = (stack(16), farrays, fscalars)
-    else:
+    elif program == "count":
         run, args = plan("Intersect(Row(cab_type=1), Row(passenger_count=2))")
         prog = engine.count_tree(run, "grid")
-    text = compile_and_fit(prog, args, devices=4).as_text()
+    else:
+        prog = engine.groupby_chains_tree("grid")
+        scalars = lambda shape: jax.ShapeDtypeStruct(shape, np.int32, sharding=placed(()))
+        args = (stack(1), (stack(16), stack(8)), (scalars((16,)), scalars((8,))),
+                scalars((128, 2)), scalars(()), stack(32), scalars((32,)))
+    compiled = compile_and_fit(prog, args, devices=4)
+    if program == "chains":
+        chip_plane = S_CELL // 4 * W * 4
+        assert compiled.memory_analysis().temp_size_in_bytes <= ops.groupby.TEMP_PLANES * chip_plane
+    text = compiled.as_text()
     assert "all-reduce" in text and "pilosa.mesh_psum" in text
     assert "u32[19," not in text
