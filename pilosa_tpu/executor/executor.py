@@ -372,9 +372,11 @@ class Executor:
         # "never", not "a program without the family"
         for family in (
             "groupby_level_readbacks_total", "groupby_chunk_waits_total",
-            "groupby_chain_queries_total",
+            "groupby_chain_queries_total", "groupby_groups_summed_total",
         ):
             self._gb_stats.declare(family)
+        for stage in ("counted", "kept"):
+            self._gb_stats.declare("groupby_level_pairs_total", tags={"stage": stage})
         # per-call host/device routing (executor/router.py). Passing an
         # existing router preserves its calibration across executor
         # rebuilds (the server's mesh re-attach swaps the Executor but
@@ -1255,22 +1257,18 @@ class Executor:
         )
 
     @staticmethod
-    def _grouped_sum_fn(sum_fn):
-        """``sum_fn`` over G group masks, one after another: (stack
-        [R,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G]). A loop, not
-        a vmap: batched over 16 masks the candidate masks of every group
-        were 47 planes of temporaries (0.73 GiB at 128 shards, compiled
-        for a v5e) for 5 % of the time (6.84 against 7.19 ms, my chip
-        runs, PR 34); one at a time each group is the plain filtered Sum
-        and the transient is none. Shared with the mesh tree."""
-        return lambda s, masks: jax.lax.map(lambda m: sum_fn(s, m), masks)
+    def _grouped_sum_fn(field: Field):
+        """(stack [R,S,W], masks [G,S,W]) → (pos[G,D], neg[G,D], n[G]):
+        the filtered Sum of every group mask, ``ops.groupby.grouped_sums``
+        on the planes the field's depth rule keeps (as ``_sum_fn``'s).
+        Shared with the mesh tree."""
+        need = BSI_OFFSET + field.bit_depth
+        return lambda s, masks: ops.groupby.grouped_sums(ops.bsi.block(s, need), masks)
 
     def _grouped_sum_program(self, field: Field, n_shards: int):
         return self.compiler.program(
             ("gb_sums", n_shards, field.bit_depth),
-            lambda: named_jit(
-                "pilosa_sum_groups", self._grouped_sum_fn(self._sum_fn(field))
-            ),
+            lambda: named_jit("pilosa_sum_groups", self._grouped_sum_fn(field)),
         )
 
     def _execute_sum(
@@ -1756,9 +1754,9 @@ class Executor:
             return jax.device_get(arrays)
 
     def _gb_wait(self, done) -> None:
-        """The deferred walk's one kind of wait: for the device to finish
-        the query's OWN last program, so that the chunk of masks it read
-        is freed before the next chunk's are made. No transfer, no host
+        """A walk's one kind of wait: for the device to finish the
+        query's OWN last program, so that the chunk of masks it read is
+        freed before the next chunk's are made. No transfer, no host
         work behind it, the interpreter lock released."""
         self._gb_stats.count("groupby_chunk_waits_total")
         with GLOBAL_TRACER.span("executor.groupby.wait"):
@@ -1785,9 +1783,7 @@ class Executor:
         eng = self.compiler.mesh_engine
         gsp = self.compiler.program(
             ("mesh_gb_sums", n_shards, agg_field.bit_depth, mesh_mode),
-            lambda: eng.grouped_sum_tree(
-                self._grouped_sum_fn(self._sum_fn(agg_field)), mesh_mode
-            ),
+            lambda: eng.grouped_sum_tree(self._grouped_sum_fn(agg_field), mesh_mode),
         )
         return lambda s, m: self.compiler._mesh_dispatch("groupby", gsp, s, m)
 
@@ -1943,10 +1939,13 @@ class Executor:
                 )
                 held = None  # the pending result frees it
                 return pend if lazy else pend.resolve_now()
-            return self._groupby_levels(
+            out = self._groupby_levels(
                 fields, row_lists, matrices, base_mask, limit, shards,
                 chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
+                held, route="mesh" if mesh_mode is not None else "device",
             )
+            held = None  # the walk released it, or its pending result does
+            return out.resolve_now() if isinstance(out, _Pending) and not lazy else out
         finally:
             if held is not None:
                 self.gb_ledger.release(held)
@@ -1954,7 +1953,8 @@ class Executor:
     def _groupby_levels(
         self, fields, row_lists, matrices, base_mask, limit, shards,
         chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
-    ) -> list[dict]:
+        held, route: str,
+    ) -> "list[dict] | _Pending":
         """Level-synchronous evaluation: a whole nesting level runs in TWO
         device dispatches — (1) counts of every (surviving group ×
         candidate row) pair, (2) materialization of the surviving
@@ -1968,10 +1968,23 @@ class Executor:
         powers of two so recompiles stay rare. The walk of streamed
         levels, of expansions over ``DEFERRED_CHUNKS`` chunks and of
         chunked ones under a ``limit``; the rest never read a level back
-        (``_groupby_deferred``)."""
+        (``_groupby_deferred``).
+
+        Over resident stacks the first read holds the filter's counts
+        against the rows of EVERY level: a parent lies inside the filter,
+        so a row the filter does not hold pairs with none, and the levels
+        below the first count only the rows it holds. The grouped sums
+        are never read inside the walk: they ride the wave's readback as
+        a ``_Pending``, and before a chunk's masks are made the walk waits
+        for the device to finish the last sums (``_gb_wait``), so a level
+        still holds one chunk of masks, which is what ``held`` reserved.
+        Without sums the walk releases ``held`` and returns the groups."""
         n_shards = len(shards)
         plane_bytes = n_shards * WORDS_PER_SHARD * 4
         results: list[dict] = []
+        row_lists = list(row_lists)
+        arrays: list = []  # the pending result's: (pos, neg) pairs
+        sums: list[tuple[int, int, int]] = []  # (first result, groups, pos slot)
 
         def emit(groups: list[tuple], counts: np.ndarray, masks) -> None:
             start = len(results)
@@ -1985,11 +1998,19 @@ class Executor:
                     }
                 )
             if sum_prog is not None:
-                pos, neg, _n = self._gb_read(
-                    self._gb_launch("sums", sum_prog, agg_slices, masks)
-                )
-                for i in range(len(groups)):
-                    results[start + i]["sum"] = ops.bsi.weigh_sum(pos[i], neg[i])
+                self._gb_stats.count("groupby_groups_summed_total", len(groups))
+                pos, neg, _n = self._gb_launch("sums", sum_prog, agg_slices, masks)
+                sums.append((start, len(groups), len(arrays)))
+                arrays.extend((pos, neg))
+
+        waited = [0]  # len(arrays) at the last wait
+
+        def settled() -> None:
+            """Wait for the last sums before masks are made: the chunk
+            they read is then freed."""
+            if len(arrays) > waited[0]:
+                self._gb_wait(arrays[-1])
+                waited[0] = len(arrays)
 
         def _level_frags(level: int) -> list:
             view = fields[level].view(VIEW_STANDARD)
@@ -2064,6 +2085,7 @@ class Executor:
             """Materialize one pair-chunk's group masks. Streamed levels
             pack only the chunk's distinct rows (≤ chunk_cap ≤ the mask
             budget) and select them by local index."""
+            settled()
             rows_l = row_lists[level]
             m = matrices[level]
             if m is None:
@@ -2087,11 +2109,15 @@ class Executor:
             if limit is not None and len(results) >= limit:
                 return
             rows_l = row_lists[level]
-            cnp = _level_counts(level, masks, len(groups))
+            cnp = root.pop() if root else _level_counts(level, masks, len(groups))
             pairs = np.argwhere(cnp > 0)  # (g-major, k-minor) = lexicographic
             last = level == len(fields) - 1
             if last and limit is not None:
                 pairs = pairs[: limit - len(results)]
+            # how much the read prunes: the real pairs the launch counted,
+            # and those the walk goes on with
+            self._gb_stats.count("groupby_level_pairs_total", cnp.size, tags={"stage": "counted"})
+            self._gb_stats.count("groupby_level_pairs_total", len(pairs), tags={"stage": "kept"})
             for lo in range(0, pairs.shape[0], chunk_cap):
                 chunk = pairs[lo : lo + chunk_cap]
                 self._gb_stats.count("groupby_chunks_total")
@@ -2116,8 +2142,35 @@ class Executor:
                 if limit is not None and len(results) >= limit:
                     return
 
+        root: list[np.ndarray] = []  # level 0's counts, where the first read has them
+        if len(fields) > 1 and all(m is not None for m in matrices):
+            got = self._gb_read([
+                self._gb_launch(
+                    "counts", gb_counts_call, base_mask, m, _pad_row_ids(rows, _pow2(len(rows)))
+                )
+                for m, rows in zip(matrices, row_lists)
+            ])
+            root.append(got[0][:1, : len(row_lists[0])])
+            for level in range(1, len(fields)):
+                holds = (got[level][0, : len(row_lists[level])] > 0).tolist()
+                self._gb_stats.count("groupby_level_pairs_total", len(holds), tags={"stage": "counted"})
+                self._gb_stats.count("groupby_level_pairs_total", sum(holds), tags={"stage": "kept"})
+                row_lists[level] = [r for r, h in zip(row_lists[level], holds) if h]
         expand(0, base_mask, [()])
-        return results
+        if not arrays:
+            self.gb_ledger.release(held)
+            return results
+        self.gb_ledger.in_flight(held, arrays[-1])
+
+        def finish(a):
+            self.gb_ledger.release(held)
+            for start, n, slot in sums:
+                values = ops.bsi.weigh_sums(a[slot][:n], a[slot + 1][:n])
+                for i, value in enumerate(values):
+                    results[start + i]["sum"] = value
+            return results
+
+        return _Pending(arrays, finish, route=route)
 
     def _groupby_deferred(
         self, fields, row_lists, matrices, base_mask, limit, plane_bytes,
@@ -2184,6 +2237,7 @@ class Executor:
                     rows_np[level][k], plane_bytes,
                 )
                 if level == last:
+                    self._gb_stats.count("groupby_groups_summed_total", g.size)
                     pos, neg, _n = self._gb_launch("sums", sum_prog, agg_slices, sub_masks)
                     sums.append((lo, len(arrays)))
                     arrays.extend((pos, neg))

@@ -217,6 +217,19 @@ def weigh_sum(pos_counts, neg_counts) -> int:
     return total
 
 
+def weigh_sums(pos_counts, neg_counts) -> list[int]:
+    """``weigh_sum`` of every row of ``[G, D]`` counts, exact: one int64
+    product where no sum can pass 2**63 (every count under ``2**b`` and
+    ``b + D`` under 63 bits), Python ints a row otherwise."""
+    pos = np.asarray(pos_counts, dtype=np.int64)
+    neg = np.asarray(neg_counts, dtype=np.int64)
+    depth = pos.shape[-1]
+    top = int(max(pos.max(initial=0), neg.max(initial=0)))
+    if top.bit_length() + depth >= 63:
+        return [weigh_sum(p, q) for p, q in zip(pos, neg)]
+    return ((pos - neg) @ (np.int64(1) << np.arange(depth, dtype=np.int64))).tolist()
+
+
 def sum_device(slices: jax.Array, filt: jax.Array) -> tuple[jax.Array, jax.Array]:
     """All-device Sum → (sum int64, count int64). Used inside sharded
     programs where the result participates in a psum; needs x64 enabled

@@ -1,4 +1,4 @@
-"""GroupBy's three device bodies, shared by the single-program engine
+"""GroupBy's device bodies, shared by the single-program engine
 (executor/executor.py jits them) and the mesh engine (parallel/mesh.py
 runs them inside ``shard_map`` under a psum tree).
 
@@ -16,15 +16,17 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from pilosa_tpu.ops.bitwise import popcount_rows
+from pilosa_tpu.ops.bsi import EXISTS_ROW, OFFSET_ROW, SIGN_ROW
 
 # Upper bound, in [S, W] planes, of the temporaries XLA allocates beside
-# the arguments and outputs of ANY program of a GroupBy (the three below and
-# the grouped sum, executor.Executor._grouped_sum_program), whatever the
-# number of groups: what the executor's transient ledger adds to the masks
-# a GroupBy holds. tests/test_tpu_compile.py compiles them for a described
-# v5e at taxi-128g's shapes and holds memory_analysis() to it.
+# the arguments and outputs of ANY program of a GroupBy (the four below),
+# whatever the number of groups: what the executor's transient ledger adds
+# to the masks a GroupBy holds. tests/test_tpu_compile.py compiles them for
+# a described v5e at taxi-128g's and ssb-24's shapes and holds
+# memory_analysis() to it.
 TEMP_PLANES = 1
 
 
@@ -52,6 +54,10 @@ def _groups(masks: jax.Array) -> jax.Array:
 # every row re-reads every mask from HBM), 21 ms in blocks of 8 with the
 # masks kept and the rows going by, 13.7 ms this way; blocks of 16: 18 ms.
 SHARD_BLOCK = 8
+# The most masks and candidate rows of one tile of the count pass (a block
+# of shards of each): 64 x 32 is taxi-128g's largest level, whose rows stay
+# in fast memory; a larger level goes tile by tile (ssb-24's 1,024 brands)
+MASK_BLOCK, ROW_BLOCK = 64, 32
 
 
 def _rows_block(matrix: jax.Array, rows: jax.Array, start) -> jax.Array:
@@ -63,32 +69,124 @@ def _rows_block(matrix: jax.Array, rows: jax.Array, start) -> jax.Array:
     return jnp.take(x, rows, axis=0, mode="fill", fill_value=0)
 
 
-def level_counts(masks: jax.Array, matrix: jax.Array, rows: jax.Array) -> jax.Array:
+def _gathered_block(matrix: jax.Array, rows: jax.Array, start) -> jax.Array:
+    """``_rows_block`` as one gather of ``[K, block, W]``: for a stack too
+    tall for its whole block of shards to be sliced out first (XLA lifts
+    that slice out of the tiles' loop and keeps it)."""
+    rr = jnp.clip(rows, 0, matrix.shape[0] - 1).astype(jnp.int32)
+    x = jax.vmap(
+        lambda r: jax.lax.dynamic_slice(
+            matrix, (r, start, jnp.int32(0)), (1, SHARD_BLOCK, matrix.shape[2])
+        )[0]
+    )(rr)
+    return jnp.where(((rows >= 0) & (rows < matrix.shape[0]))[:, None, None], x, jnp.uint32(0))
+
+
+def _tile(masks: jax.Array, matrix: jax.Array, rows: jax.Array, start, gather=_rows_block,
+          within=None) -> jax.Array:
+    """The ``[G, block, W]`` masks of the block of shards from ``start`` x
+    the ``rows`` of the stack -> int32 ``[G, K]``: the rows stay put while
+    the masks go by. ``within(start, size)``, where given, is a
+    ``[size, W]`` plane ANDed into every mask."""
+    m = jax.lax.dynamic_slice_in_dim(masks, start, SHARD_BLOCK, axis=1)
+    x = gather(matrix, rows, start)
+    f = None if within is None else within(start, SHARD_BLOCK)
+    return jax.lax.map(
+        lambda mg: jnp.sum(
+            popcount_rows(x & (mg if f is None else mg & f)[None]), axis=1, dtype=jnp.int32
+        ),
+        m,
+    )
+
+
+def level_counts(
+    masks: jax.Array, matrix: jax.Array, rows: jax.Array, within=None
+) -> jax.Array:
     """``[G, S, W]`` group masks x the K candidate ``rows`` (ids into the
     ``[R, S, W]`` stack, -1 padding) -> int64 ``[G, K]`` columns in each
     (group, row) pair. Popcounts accumulate in int32 along the word axis
     and inside a block of shards (at most 2**23 bits); only the small
-    partials widen."""
+    partials widen. ``within(start, size)``, where given, is the
+    ``[size, W]`` plane of the shards from ``start`` that every mask is
+    ANDed with first (``grouped_sums``' sign planes)."""
     masks = _groups(masks)
-    n_shards = masks.shape[1]
+    n_groups, n_shards = masks.shape[:2]
     if n_shards % SHARD_BLOCK:
         # whole planes, one row at a time: the transient is one plane
+        f = None if within is None else within(0, n_shards)
+
         def per_row(r):
-            return jnp.sum(
-                popcount_rows(masks & plane(matrix, r)[None]).astype(jnp.int64), axis=1
-            )
+            p = plane(matrix, r) if f is None else plane(matrix, r) & f
+            return jnp.sum(popcount_rows(masks & p[None]).astype(jnp.int64), axis=1)
 
         return jax.lax.map(per_row, rows).T
 
+    k = rows.shape[0]
+    gc, kc = min(n_groups, MASK_BLOCK), min(k, ROW_BLOCK)
+
     def block(start):
-        m = jax.lax.dynamic_slice_in_dim(masks, start, SHARD_BLOCK, axis=1)
-        x = _rows_block(matrix, rows, start)
-        return jax.lax.map(
-            lambda mg: jnp.sum(popcount_rows(x & mg[None]), axis=1, dtype=jnp.int32), m
-        )  # [G, K]
+        if (gc, kc) == (n_groups, k):
+            return _tile(masks, matrix, rows, start, within=within)
+
+        # tiles of at most MASK_BLOCK masks by ROW_BLOCK rows (the walks pad
+        # both to powers of two). Whole, the block of a 1,024-row level is
+        # 1 GiB, which no fast memory holds: compiled for a v5e at 24
+        # shards XLA copied it out twice, 683 planes of temporaries that
+        # the transient ledger did not count, and 128 masks' block once
+        def row_chunk(rc):
+            def mask_chunk(g0):
+                return _tile(
+                    jax.lax.dynamic_slice_in_dim(masks, g0, gc, axis=0), matrix, rc, start,
+                    _gathered_block, within,
+                )
+
+            return jax.lax.map(mask_chunk, jnp.arange(n_groups // gc, dtype=jnp.int32) * gc)
+
+        tiles = jax.lax.map(row_chunk, rows.reshape(k // kc, kc))  # [K/kc, G/gc, gc, kc]
+        return jnp.transpose(tiles, (1, 2, 0, 3)).reshape(n_groups, k)
 
     starts = jnp.arange(n_shards // SHARD_BLOCK, dtype=jnp.int32) * SHARD_BLOCK
     return jnp.sum(jax.lax.map(block, starts).astype(jnp.int64), axis=0)
+
+
+def grouped_sums(stack: jax.Array, masks: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """GroupBy's ``aggregate=Sum``: the BSI ``stack [R, S, W]`` (existence,
+    sign and magnitude planes as ``ops.bsi`` lays them, cut to the field's
+    depth) under ``[G, S, W]`` group masks -> (pos int64 ``[G, D]``, neg
+    int64 ``[G, D]``, n int64 ``[G]``): ``ops.bsi.sum_counts`` of every
+    group, D the magnitude planes the stack holds.
+
+    The count pass with the magnitude planes and the existence plane for
+    rows and the sign ANDed into every mask: inside a block of shards the
+    planes stay put while the masks go by, where a group at a time
+    re-read the whole block from HBM. The negative counts are a second
+    such pass, run only where the stack holds a negative value at all;
+    otherwise they are zeros, as counted."""
+    depth = stack.shape[0] - OFFSET_ROW
+    k = depth + 1  # the magnitudes, then the existence plane
+    k_pad = k if k <= ROW_BLOCK else -(-k // ROW_BLOCK) * ROW_BLOCK
+    rows = np.full(k_pad, -1, dtype=np.int32)
+    rows[:depth] = np.arange(OFFSET_ROW, OFFSET_ROW + depth)
+    rows[depth] = EXISTS_ROW
+
+    def signed(neg: bool):
+        def within(start, size):
+            exists, sign = (
+                jax.lax.dynamic_slice_in_dim(stack[r], start, size, axis=0)
+                for r in (EXISTS_ROW, SIGN_ROW)
+            )
+            return exists & (sign if neg else ~sign)
+
+        return within
+
+    pos = level_counts(masks, stack, rows, signed(False))
+    has_neg = jnp.any((stack[EXISTS_ROW] & stack[SIGN_ROW]) != 0)
+    neg = jax.lax.cond(
+        has_neg,
+        lambda: level_counts(masks, stack, rows, signed(True)),
+        lambda: jnp.zeros_like(pos),
+    )
+    return pos[:, :depth], neg[:, :depth], pos[:, depth] + neg[:, depth]
 
 
 def chain_counts(

@@ -115,6 +115,16 @@ def test_bsi_sum(rng):
     assert int(s_dev) == sum(selected.values()) and int(n_dev) == len(selected)
 
 
+@pytest.mark.parametrize("depth,top", [(24, 1 << 25), (40, 1 << 25), (3, 5), (0, 9)])
+def test_bsi_weigh_sums_equals_weigh_sum_a_row(rng, depth, top):
+    """The grouped sums' weighing: every row as ``weigh_sum`` weighs it,
+    in int64 where no sum can overflow (24 planes of 2**25 counts) and
+    in Python ints where one could (40 planes)."""
+    pos = rng.integers(0, top, (7, depth))
+    neg = rng.integers(0, top, (7, depth))
+    assert ops.bsi.weigh_sums(pos, neg) == [ops.bsi.weigh_sum(p, q) for p, q in zip(pos, neg)]
+
+
 @pytest.mark.parametrize("lo,hi", [(-1000, 1000), (5, 900), (-900, -5), (7, 7)])
 def test_bsi_min_max(rng, lo, hi):
     slices, oracle = make_bsi(rng, lo=lo, hi=hi)

@@ -298,6 +298,45 @@ def test_groupby_programs_at_the_groupby_cells_shapes(rig, one_chip, groups):
     assert (1 + 16 + 64 + ops.groupby.TEMP_PLANES) * plane <= budget
 
 
+@pytest.mark.parametrize(
+    "groups,stack,rows",
+    [(32, 1024, 1024), (8, 1024, 1024), (16, 256, 256), (1, 256, 256), (128, 8, 8), (8, 32, 32)],
+    ids=["q4_3", "q2_x", "q3_2", "q3_root", "q3_2_years", "q3_1"],
+)
+def test_count_pass_at_the_ssb_cells_shapes(one_chip, groups, stack, rows):
+    """The cell ssb-24.flights: 24 shards, the level walk's counts launches
+    (Q4.3's 32 (year, city) masks against the 1,024 brands, Q2.x's 8 years
+    against them, Q3.2's 16 cities against 256, 128 (city, city) masks
+    against the 8 years). Temporaries inside ``TEMP_PLANES`` whatever the
+    level's rows and masks: whole, the 1,024 rows' block of shards was
+    683 planes (2 GiB) that the transient ledger did not count."""
+    shards = 24
+    plane = shards * W * 4
+    sds = shapes_on(one_chip)
+    masks = sds((groups, shards, W) if groups > 1 else (shards, W), np.uint32)
+    counts = compile_and_fit(
+        executor_mod._gb_counts, (masks, sds((stack, shards, W), np.uint32), sds((rows,), np.int32))
+    )
+    assert counts.memory_analysis().temp_size_in_bytes <= ops.groupby.TEMP_PLANES * plane
+
+
+@pytest.mark.parametrize("groups,planes", [(128, 26), (256, 26), (1, 27)], ids=["q3_q4", "q2_x", "one"])
+def test_grouped_sum_at_the_ssb_cells_shapes(one_chip, groups, planes):
+    """The cell ssb-24.flights' grouped sums: a level walk's chunk of 128
+    (Q3.x, Q4.x) or 256 (Q2.x) group masks against lo_revenue's 26
+    planes at 24 shards, and one mask against lo_profit's 27. The count
+    pass with the planes for rows, its temporaries inside ``TEMP_PLANES``
+    whatever the groups."""
+    shards = 24
+    plane = shards * W * 4
+    sds = shapes_on(one_chip)
+    sums = compile_and_fit(
+        executor_mod.named_jit("pilosa_sum_groups", ops.groupby.grouped_sums),
+        (sds((planes, shards, W), np.uint32), sds((groups, shards, W), np.uint32)),
+    )
+    assert sums.memory_analysis().temp_size_in_bytes <= ops.groupby.TEMP_PLANES * plane
+
+
 G2 = "GroupBy(Rows(riders), filter=Row(amount > 40), aggregate=Sum(field=amount))"
 G3 = "GroupBy(Rows(riders), Rows(year), filter=Row(amount > 40))"
 G4 = "GroupBy(Rows(riders), Rows(year), Rows(miles), filter=Row(amount > 40))"
