@@ -373,6 +373,7 @@ class Executor:
         for family in (
             "groupby_level_readbacks_total", "groupby_chunk_waits_total",
             "groupby_chain_queries_total", "groupby_groups_summed_total",
+            "groupby_streamed_launches_total",
         ):
             self._gb_stats.declare(family)
         for stage in ("counted", "kept"):
@@ -1739,8 +1740,12 @@ class Executor:
     def _gb_launch(self, what: str, prog, *args):
         """Issue one device program of a GroupBy under its span
         (``executor.groupby.filter|counts|masks|chains|sums``), counted in
-        ``groupby_launches_total``."""
+        ``groupby_launches_total``; a counts launch (masks, matrix, rows)
+        that is one pass over the whole stack also in
+        ``groupby_streamed_launches_total``."""
         self._gb_stats.count("groupby_launches_total")
+        if what == "counts" and ops.groupby.whole_stack(*args):
+            self._gb_stats.count("groupby_streamed_launches_total")
         with GLOBAL_TRACER.span(f"executor.groupby.{what}"):
             return prog(*args)
 

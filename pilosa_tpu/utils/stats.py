@@ -77,6 +77,7 @@ _METRIC_HELP = {
     "groupby_chunks_total": "pair chunks a level-synchronous GroupBy expanded",
     "groupby_level_pairs_total": "real (parent, row) pairs of a level-synchronous GroupBy's counts launches, the filter against the rows of the levels below the first included (stage=counted), and those with a count above zero that the walk went on with (stage=kept)",
     "groupby_groups_summed_total": "real (unpadded) groups of every GroupBy sums launch, on both walks",
+    "groupby_streamed_launches_total": "GroupBy counts launches of one mask that are one pass over the whole stack (ops.groupby.whole_stack)",
     "groupby_transient_high_water_bytes": "most device bytes GroupBys in flight have held beside the stacks",
     "queries_partial": "queries answered with partial results",
     "queries_rejected": "requests shed by admission control",

@@ -354,23 +354,57 @@ def test_kept_never_passes_counted_over_a_deck(holder, want):
     assert 0 < kept < _family(client, "groupby_level_pairs_total", stage="counted")
 
 
-@pytest.mark.parametrize("groups,rows", [(128, 64), (1, 256), (64, 64), (128, 32)])
-def test_count_pass_in_tiles_equals_numpy(groups, rows):
+def test_whole_stack_launches_are_counted_by_their_arguments(counted, lineorder):
+    """``groupby_streamed_launches_total`` counts the counts launches that
+    ``ops.groupby.whole_stack`` makes one pass over the whole stack, from
+    the launch's own arguments: by year and month (36 rows, 64 padded) on
+    the level walk, the first read's filter against the months is one;
+    the filter against the 3 years (4 padded, one tile) and the three
+    years' masks against the months are not. A deck's templates, whose
+    levels hold at most 12 rows here, launch none."""
+    api, client = counted["levels"]
+    assert "groupby_streamed_launches_total" in stats_mod._METRIC_HELP
+    text = _gb(["d_year", "d_yearmonthnum"], "Row(c_region=0), Row(s_region=1)", "lo_revenue")
+    before = _family(client, "groupby_streamed_launches_total")
+    launches = _family(client, "groupby_launches_total")
+    assert api.query("ssb", text)["results"][0] == brute(lineorder[0], text)
+    assert _family(client, "groupby_streamed_launches_total") - before == 1
+    assert _family(client, "groupby_launches_total") - launches > 2
+    before = _family(client, "groupby_streamed_launches_total")
+    for name in ("q2_1", "q3_2", "q4_3"):
+        api.query("ssb", TEMPLATES[name](_Draw(np.random.default_rng(5))))
+    assert _family(client, "groupby_streamed_launches_total") == before
+
+
+@pytest.mark.parametrize(
+    "groups,rows,consecutive",
+    [(128, 64, False), (1, 256, False), (64, 64, False), (128, 32, False),
+     (8, 64, False), (32, 64, False), (1, 128, True)],
+)
+def test_count_pass_in_tiles_equals_numpy(groups, rows, consecutive):
     """``ops.groupby.level_counts`` over more masks than ``MASK_BLOCK`` or
-    more rows than ``ROW_BLOCK`` goes tile by tile (the cell's 1,024 brands
-    and 256 cities): every (group, row) count as numpy counts it, padding
-    rows (-1) and rows past the stack 0."""
+    more rows than ``ROW_BLOCK`` (Q2.x's 8 and Q4.3's 32 masks by 64
+    brands) goes tile by tile, and one mask against more rows than a tile
+    (the first read's filter against the cell's 1,024 brands and 256
+    cities) is one pass over the whole stack: every (group, row) count as
+    numpy counts it, padding rows (-1) and rows past the stack 0.
+    ``consecutive``: ids 0, 1, ... as the level walk's first read gives
+    them."""
     rng = np.random.default_rng(groups * 1000 + rows)
     w = WORDS_PER_SHARD
     masks = rng.integers(0, 1 << 32, (groups, N_SHARDS, w), dtype=np.uint32)
     stack = rng.integers(0, 1 << 32, (rows - 3, N_SHARDS, w), dtype=np.uint32)
-    ids = rng.permutation(rows).astype(np.int32) - 2  # -2, -1 and rows - 3 .. rows - 2: no row
+    if consecutive:  # 0 .. rows - 3, the last past the stack, then -1 -1
+        ids = np.where(np.arange(rows) < rows - 2, np.arange(rows), -1).astype(np.int32)
+    else:  # -2, -1 and rows - 3 .. rows - 2: no row
+        ids = rng.permutation(rows).astype(np.int32) - 2
     got = np.asarray(jax.jit(ops.groupby.level_counts)(masks[0] if groups == 1 else masks, stack, ids))
     want = np.zeros((groups, rows), dtype=np.int64)
     for k, r in enumerate(ids.tolist()):
         if 0 <= r < stack.shape[0]:
             want[:, k] = np.bitwise_count(masks & stack[r][None]).sum(axis=(1, 2))
     assert (groups > ops.groupby.MASK_BLOCK or rows > ops.groupby.ROW_BLOCK)
+    assert ops.groupby.whole_stack(masks[0] if groups == 1 else masks, stack, ids) == (groups == 1)
     assert np.array_equal(got, want)
 
 

@@ -300,14 +300,17 @@ def test_groupby_programs_at_the_groupby_cells_shapes(rig, one_chip, groups):
 
 @pytest.mark.parametrize(
     "groups,stack,rows",
-    [(32, 1024, 1024), (8, 1024, 1024), (16, 256, 256), (1, 256, 256), (128, 8, 8), (8, 32, 32)],
-    ids=["q4_3", "q2_x", "q3_2", "q3_root", "q3_2_years", "q3_1"],
+    [(32, 1024, 1024), (8, 1024, 1024), (16, 256, 256), (1, 256, 256), (128, 8, 8), (8, 32, 32),
+     (8, 1024, 64), (32, 1024, 64), (1, 1024, 1024)],
+    ids=["q4_3", "q2_x", "q3_2", "q3_root", "q3_2_years", "q3_1", "q2_x_held", "q4_3_held",
+         "brands_root"],
 )
 def test_count_pass_at_the_ssb_cells_shapes(one_chip, groups, stack, rows):
     """The cell ssb-24.flights: 24 shards, the level walk's counts launches
     (Q4.3's 32 (year, city) masks against the 1,024 brands, Q2.x's 8 years
     against them, Q3.2's 16 cities against 256, 128 (city, city) masks
-    against the 8 years). Temporaries inside ``TEMP_PLANES`` whatever the
+    against the 8 years; the 64 brands the filter holds of Q2.x and Q4.3;
+    the first read's filter against the 1,024 brands). Temporaries inside ``TEMP_PLANES`` whatever the
     level's rows and masks: whole, the 1,024 rows' block of shards was
     683 planes (2 GiB) that the transient ledger did not count."""
     shards = 24
@@ -318,6 +321,38 @@ def test_count_pass_at_the_ssb_cells_shapes(one_chip, groups, stack, rows):
         executor_mod._gb_counts, (masks, sds((stack, shards, W), np.uint32), sds((rows,), np.int32))
     )
     assert counts.memory_analysis().temp_size_in_bytes <= ops.groupby.TEMP_PLANES * plane
+
+
+@pytest.mark.parametrize("stack", [1024, 256], ids=["brands", "cities"])
+def test_first_read_is_one_pass_over_the_whole_stack(one_chip, stack):
+    """ssb-24.flights' first read, the filter's one mask against every row
+    of the 1,024 brands or the 256 cities (``ops.groupby.whole_stack``),
+    compiles to one reduction over the stack: no loop of tiles, no block
+    of rows gathered into a buffer (a dynamic-update-slice), and no
+    temporaries beyond a plane."""
+    shards = 24
+    sds = shapes_on(one_chip)
+    args = (sds((shards, W), np.uint32), sds((stack, shards, W), np.uint32), sds((stack,), np.int32))
+    assert ops.groupby.whole_stack(*args)
+    compiled = compile_and_fit(executor_mod._gb_counts, args)
+    text = compiled.as_text()
+    assert " while(" not in text and "dynamic-update-slice(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= ops.groupby.TEMP_PLANES * shards * W * 4
+
+
+def test_taxi_128g_shapes_keep_their_tiles():
+    """Every counts launch of taxi-128g.groupby_fare at 128 shards (a
+    ``g1``'s filter against 4 cab types, a ``g2``'s against 16 passenger
+    counts, the level walk's root against 8 or 32 rows and its 16 and 64
+    masks against 32 distances) stays in the one tile that holds its rows:
+    ``whole_stack`` is false, so its programs are the ones they were. Its
+    grouped sums pass a sign plane (``within``) and never take it."""
+    sds = lambda shape, dtype=np.uint32: jax.ShapeDtypeStruct(shape, dtype)
+    shards = 128
+    for groups, stack, k in [(1, 8, 4), (1, 16, 16), (1, 8, 8), (1, 32, 32), (16, 32, 32),
+                             (64, 32, 32), (16, 16, 16)]:
+        masks = sds((shards, W)) if groups == 1 else sds((groups, shards, W))
+        assert not ops.groupby.whole_stack(masks, sds((stack, shards, W)), sds((k,), np.int32))
 
 
 @pytest.mark.parametrize("groups,planes", [(128, 26), (256, 26), (1, 27)], ids=["q3_q4", "q2_x", "one"])
@@ -499,20 +534,24 @@ def test_mesh_count_and_topn(rig, mesh):
 S_CELL = 512
 
 
-@pytest.mark.parametrize("program", ["topn", "sum", "count", "chains"])
+@pytest.mark.parametrize("program", ["topn", "sum", "count", "chains", "counts"])
 def test_mesh_programs_at_the_four_chip_cells_shapes(rig, mesh, program):
     """Q4's TopN over [32, 512, W] under a two-row filter, Q2's Sum over
     [16, 512, W] under a one-row filter, Q3's Count of an Intersect and a
     g4-shaped GroupBy's chain count ([128, 2] chains of the 16 and 8 row
-    stacks against 32 rows, temporaries inside ``TEMP_PLANES`` of a
-    chip's plane) compile for the 4 x 1 v5e mesh, 128 shards a chip, and
-    their psum trees carry the scope the device trace is read by."""
+    stacks against 32 rows) compile for the 4 x 1 v5e mesh, 128 shards a
+    chip, and so does a level walk's first read at 32 shards a chip (one
+    mask against 1,024 rows, tile by tile: the stack alone is 4 GiB a
+    chip); the two GroupBy programs keep their temporaries inside
+    ``TEMP_PLANES`` of a chip's plane. Their psum trees carry the scope
+    the device trace is read by."""
     engine = MeshQueryEngine(mesh)
     placed = placed_on(mesh)
     plan = lambda pql: mesh_plan(rig, placed, pql, S_CELL)
+    shards = S_CELL if program != "counts" else S_CELL // 4
 
     def stack(rows):
-        shape = (rows, S_CELL, W)
+        shape = (rows, shards, W)
         return jax.ShapeDtypeStruct(shape, np.uint32, sharding=placed(shape))
 
     if program == "topn":
@@ -528,13 +567,17 @@ def test_mesh_programs_at_the_four_chip_cells_shapes(rig, mesh, program):
         run, args = plan("Intersect(Row(cab_type=1), Row(passenger_count=2))")
         prog = engine.count_tree(run, "grid")
     else:
-        prog = engine.groupby_chains_tree("grid")
         scalars = lambda shape: jax.ShapeDtypeStruct(shape, np.int32, sharding=placed(()))
-        args = (stack(1), (stack(16), stack(8)), (scalars((16,)), scalars((8,))),
-                scalars((128, 2)), scalars(()), stack(32), scalars((32,)))
+        if program == "chains":
+            prog = engine.groupby_chains_tree("grid")
+            args = (stack(1), (stack(16), stack(8)), (scalars((16,)), scalars((8,))),
+                    scalars((128, 2)), scalars(()), stack(32), scalars((32,)))
+        else:
+            prog = engine.groupby_counts_tree("grid")
+            args = (stack(1), stack(1024), scalars((1024,)))
     compiled = compile_and_fit(prog, args, devices=4)
-    if program == "chains":
-        chip_plane = S_CELL // 4 * W * 4
+    if program in ("chains", "counts"):
+        chip_plane = shards // 4 * W * 4
         assert compiled.memory_analysis().temp_size_in_bytes <= ops.groupby.TEMP_PLANES * chip_plane
     text = compiled.as_text()
     assert "all-reduce" in text and "pilosa.mesh_psum" in text
