@@ -23,6 +23,7 @@ abstracted out; jax.jit's own shape cache handles S/R/W changes.
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Any, Callable
 
@@ -258,7 +259,8 @@ class StackCache:
     MAX_ENTRIES = 64
     MAX_DELTA_ROWS = 1024  # beyond this a full restack is cheaper
 
-    # device-bytes cap for any one dense stack; larger fields take the
+    # one device's bytes cap for any one dense stack (on a mesh, the
+    # device's share of it: see the byte ledger); larger fields take the
     # hot-row path. Resolution order: PILOSA_TPU_STACK_BUDGET env →
     # 70% of the device's reported HBM limit (a 16 GiB chip serves a
     # 10 GiB pod-scale stack out of the box); 2 GiB on the CPU backend,
@@ -315,15 +317,41 @@ class StackCache:
         self.cold_uploads = 0
         self.evictions = {"dense": 0, "hot": 0, "tiered": 0}
         self._container_bytes = {"dense": 0, "sparse": 0, "run": 0}
+        if stats is not None:
+            # a scrape tells "no eviction" from a program without the family
+            for tier in self.evictions:
+                stats.declare("stack_evictions_total", tags={"tier": tier})
 
     # ----------------------------------------------------- byte ledger
+    # The ledger counts what ONE device holds: the whole array without a
+    # mesh, a device's block of it on one (every stack is split evenly
+    # over the mesh, or replicated), so the sum is what the fullest
+    # device holds and the budget is one device's.
+    def _device_bytes(self, shape: tuple) -> int:
+        """Bytes on one device of a uint32 stack of ``shape`` [R, S, W]
+        once placed — the projection before a build."""
+        if self.mesh_ctx is None:
+            return math.prod(shape) * 4
+        return self.mesh_ctx.block_bytes(tuple(shape))
+
+    @staticmethod
+    def _placed_bytes(arr) -> int:
+        """Bytes of a placed array's block on one device."""
+        return math.prod(arr.sharding.shard_shape(arr.shape)) * arr.dtype.itemsize
+
     # callers hold self._lock
     def _account(self, key: tuple, nbytes: int) -> None:
         self.resident_bytes += nbytes - self._bytes.get(key, 0)
         self._bytes[key] = nbytes
+        self._push_ledger_gauge()
 
     def _forget(self, key: tuple) -> None:
         self.resident_bytes -= self._bytes.pop(key, 0)
+        self._push_ledger_gauge()
+
+    def _push_ledger_gauge(self) -> None:
+        if self.stats is not None:
+            self.stats.gauge("stack_device_bytes", float(self.resident_bytes))
 
     def _evict_for(self, need: int, keep: tuple | None = None) -> None:
         """Evict LRU entries (dense stacks first, then hot slot stacks,
@@ -397,7 +425,7 @@ class StackCache:
                 self._cache.move_to_end(key)
                 return cached[1], cached[2]
         r_pad = self._projected_rows(view, shards)
-        need = len(shards) * r_pad * WORDS_PER_SHARD * 4
+        need = self._device_bytes((r_pad, len(shards), WORDS_PER_SHARD))
         if need > self.STACK_BYTES_BUDGET:
             raise StackOverBudget(
                 field.name, r_pad, need, self.STACK_BYTES_BUDGET
@@ -438,6 +466,7 @@ class StackCache:
                 with GLOBAL_TRACER.span(
                     "stack.upload",
                     devices=self.mesh_ctx.n_devices if self.mesh_ctx else 1,
+                    device_bytes=self._device_bytes(stacked.shape),
                     **packed.tags,
                 ) as uploaded:
                     if self.mesh_ctx is not None:
@@ -461,7 +490,7 @@ class StackCache:
             # last-writer-wins install is self-healing: if a concurrent
             # builder installed a different entry, the next call re-reads
             # fragment versions and reconciles via the delta path
-            nbytes = int(entry[1].nbytes)
+            nbytes = self._placed_bytes(entry[1])
             self._evict_for(nbytes - self._bytes.get(key, 0), keep=key)
             self._cache[key] = entry
             self._account(key, nbytes)
@@ -592,6 +621,7 @@ class StackCache:
             self._tiered.clear()
             self._container_bytes = {"dense": 0, "sparse": 0, "run": 0}
             self._push_residency_gauges()
+            self._push_ledger_gauge()
 
     # ----------------------------------------------------- hot-row stacks
     # High-cardinality fields (dense stack over STACK_BYTES_BUDGET) keep
@@ -633,7 +663,7 @@ class StackCache:
             from collections import OrderedDict
 
             zeros = np.zeros((h, len(shards), WORDS_PER_SHARD), dtype=np.uint32)
-            self._evict_for(int(zeros.nbytes) - self._bytes.get(key, 0), keep=key)
+            self._evict_for(self._device_bytes(zeros.shape) - self._bytes.get(key, 0), keep=key)
             dev = (
                 self.mesh_ctx.place_stack(zeros)
                 if self.mesh_ctx is not None
@@ -647,7 +677,7 @@ class StackCache:
                 "view_ver": view_ver,
             }
             self._hot[key] = entry
-            self._account(key, int(zeros.nbytes))
+            self._account(key, self._placed_bytes(dev))
             self._hot.move_to_end(key)
             while len(self._hot) > self.MAX_HOT_ENTRIES:
                 victim, _ = self._hot.popitem(last=False)
@@ -790,8 +820,7 @@ class StackCache:
             r_pad = _pad_rows(view.max_rows())
         else:
             r_pad = self._projected_rows(view, shards)
-        need = len(shards) * r_pad * WORDS_PER_SHARD * 4
-        return need > self.STACK_BYTES_BUDGET
+        return self._device_bytes((r_pad, len(shards), WORDS_PER_SHARD)) > self.STACK_BYTES_BUDGET
 
     def _pack_plane(self, view, shards: list[int], row_id) -> np.ndarray:
         """Host-packed [S, W] plane of one row, straight from fragments."""
@@ -885,7 +914,9 @@ class StackCache:
             host = np.zeros((h, RUN_MAX_INTERVALS, 2), dtype=np.int32)
         else:
             raise ValueError(f"unknown container kind {kind!r}")
-        nbytes = int(host.nbytes)
+        # a dense store splits over a mesh like a stack; payload stores
+        # are replicated, whole on every device
+        nbytes = self._device_bytes(host.shape) if kind == "dense" else int(host.nbytes)
         self._evict_for(nbytes, keep=key)
         if self.mesh_ctx is not None:
             dev = (

@@ -240,14 +240,16 @@ class GroupByLedger:
         self.high_water = 0
         self._in_flight: list[_Held] = []
 
-    def admit(self, nbytes: int, budget: int) -> _Held:
+    def admit(self, nbytes: int, budget: int, **tags) -> _Held:
+        """Reserve ``nbytes`` of one device against ``budget``; ``tags``
+        (the route's devices, the planes reserved) label the wait's span."""
         token = _Held(int(nbytes))
         with self._cond:
             while self.held and self.held + token.nbytes > budget:
                 # a span only where the call waits (the leader's thread,
                 # under the scheduler): what fits at once pays nothing
                 with GLOBAL_TRACER.span(
-                    "executor.groupby.admit", bytes=token.nbytes
+                    "executor.groupby.admit", bytes=token.nbytes, **tags
                 ):
                     if self._in_flight:
                         oldest = self._in_flight[0]
@@ -338,7 +340,9 @@ class Executor:
     GROUPBY_MASK_BUDGET = None
 
     def _gb_budget(self, resolve: bool = True) -> int | None:
-        """GroupBy transient-mask budget: a pinned GROUPBY_MASK_BUDGET
+        """GroupBy transient-mask budget, of ONE device (on the mesh route
+        a GroupBy reserves a device's share of its planes, as the stack
+        ledger does of its stacks): a pinned GROUPBY_MASK_BUDGET
         wins; else PILOSA_TPU_GROUPBY_BUDGET env; else 1/8 of the stack
         budget (~70% of HBM), floored at 256 MiB. Sized so a realistic
         GroupBy expands in one or two chunks on a real chip, with one
@@ -1767,6 +1771,18 @@ class Executor:
         with GLOBAL_TRACER.span("executor.groupby.wait"):
             done.block_until_ready()
 
+    def _gb_device_bytes(self, n_shards: int, mesh_mode: str | None) -> tuple[int, int]:
+        """(a plane, the programs' temporaries) of a GroupBy on ONE device,
+        the transient ledger's units. A plane is the whole [S, W] plane on
+        the device route and a device's block of it on the mesh route; the
+        temporaries are ``TEMP_PLANES`` planes, and on the mesh route the
+        rows of one tile of the count pass too (``mesh_tile_bytes``)."""
+        if mesh_mode is None:
+            plane = n_shards * WORDS_PER_SHARD * 4
+            return plane, ops.groupby.TEMP_PLANES * plane
+        s, w = self.compiler.mesh_engine.block_shape(n_shards, WORDS_PER_SHARD, mesh_mode)
+        return s * w * 4, ops.groupby.TEMP_PLANES * s * w * 4 + ops.groupby.mesh_tile_bytes(s, w)
+
     def _gb_make_masks(self, gb_masks_call, masks, matrix, parents, row_sel, plane_bytes):
         """One masks launch: pair p is parent ``parents[p]`` of ``masks``
         AND row ``row_sel[p]`` of ``matrix``. Padded to a power of two of
@@ -1864,7 +1880,8 @@ class Executor:
         # more masks than its padded pairs (``fold[l]``), so the small
         # first levels of a deep GroupBy leave the budget to the last.
         n_shards = len(shards)
-        plane_bytes = n_shards * WORDS_PER_SHARD * 4
+        mesh_mode = self.compiler.mesh_mode(n_shards) if mesh else None
+        plane_bytes, temp_bytes = self._gb_device_bytes(n_shards, mesh_mode)
         budget = self._gb_budget()
         kp = [_pow2(len(r)) for r in row_lists]
         fold = list(itertools.accumulate(kp, operator.mul))  # pairs down to each level
@@ -1878,7 +1895,7 @@ class Executor:
         def need(cap: int) -> int:
             """Bytes held when no level holds more than ``cap`` masks."""
             planes = 1 + sum(min(cap, g) for g in mask_levels) + min(cap, streamed)
-            return (planes + ops.groupby.TEMP_PLANES) * plane_bytes
+            return planes * plane_bytes + temp_bytes
 
         # the largest power of two of masks a level that fits beside the
         # rest: padded chunks never exceed it, and pow2 shapes keep XLA
@@ -1903,12 +1920,13 @@ class Executor:
         # inside the program, so it holds the filter's plane and the
         # temporaries only
         chain = deferred and len(fields) > 1 and agg_field is None
+        reserved = plane_bytes + temp_bytes if chain else need(chunk_cap)
         held = self.gb_ledger.admit(
-            (1 + ops.groupby.TEMP_PLANES) * plane_bytes if chain else need(chunk_cap),
-            budget,
+            reserved, budget,
+            devices=self.compiler.mesh_engine.n_devices if mesh_mode is not None else 1,
+            planes=reserved // plane_bytes,
         )
         try:
-            mesh_mode = self.compiler.mesh_mode(n_shards) if mesh else None
             gb_counts_call, gb_masks_call, gb_chains_call = self._gb_programs(mesh_mode)
             sum_prog = (
                 self._gb_sum_program(agg_field, n_shards, mesh_mode)
@@ -1945,7 +1963,7 @@ class Executor:
                 held = None  # the pending result frees it
                 return pend if lazy else pend.resolve_now()
             out = self._groupby_levels(
-                fields, row_lists, matrices, base_mask, limit, shards,
+                fields, row_lists, matrices, base_mask, limit, shards, plane_bytes,
                 chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
                 held, route="mesh" if mesh_mode is not None else "device",
             )
@@ -1956,7 +1974,7 @@ class Executor:
                 self.gb_ledger.release(held)
 
     def _groupby_levels(
-        self, fields, row_lists, matrices, base_mask, limit, shards,
+        self, fields, row_lists, matrices, base_mask, limit, shards, plane_bytes,
         chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
         held, route: str,
     ) -> "list[dict] | _Pending":
@@ -1983,9 +2001,9 @@ class Executor:
         a ``_Pending``, and before a chunk's masks are made the walk waits
         for the device to finish the last sums (``_gb_wait``), so a level
         still holds one chunk of masks, which is what ``held`` reserved.
-        Without sums the walk releases ``held`` and returns the groups."""
+        Without sums the walk releases ``held`` and returns the groups.
+        ``plane_bytes`` is the ledger's plane on the query's route."""
         n_shards = len(shards)
-        plane_bytes = n_shards * WORDS_PER_SHARD * 4
         results: list[dict] = []
         row_lists = list(row_lists)
         arrays: list = []  # the pending result's: (pos, neg) pairs
@@ -2029,6 +2047,9 @@ class Executor:
         from collections import OrderedDict
 
         pack_cache: OrderedDict[tuple[int, int], np.ndarray] = OrderedDict()
+        # a streamed level's rows split over the mesh as a stack does, so
+        # that each device holds the share of them the ledger reserved
+        upload = self.compiler.stacks.mesh_ctx.place_stack if route == "mesh" else jnp.asarray
 
         def _pack_rows(level: int, frags: list, rows: list[int], k_pad: int) -> np.ndarray:
             """Host-pack [k_pad, S, W] (row-major, like resident stacks)
@@ -2079,7 +2100,7 @@ class Executor:
                 parts.append(
                     self._gb_read(
                         self._gb_launch(
-                            "counts", gb_counts_call, masks, jnp.asarray(host),
+                            "counts", gb_counts_call, masks, upload(host),
                             np.arange(k_pad, dtype=np.int32),
                         )
                     )[:n_groups, : len(sub)]
@@ -2095,7 +2116,7 @@ class Executor:
             m = matrices[level]
             if m is None:
                 uniq_k = np.unique(chunk[:, 1])
-                m = jnp.asarray(
+                m = upload(
                     _pack_rows(
                         level,
                         _level_frags(level),

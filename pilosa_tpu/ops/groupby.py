@@ -67,6 +67,20 @@ SHARD_BLOCK = 8
 MASK_BLOCK, ROW_BLOCK = 64, 32
 
 
+def mesh_tile_bytes(shards: int, words: int) -> int:
+    """What a mesh of several chips adds to ``TEMP_PLANES`` on one device
+    whose block of a plane is ``shards`` x ``words``. Compiled for a 2x2
+    v5e, the count pass of more masks than a tile holds (the grouped sums
+    of 128 or 256 groups) keeps the rows of a tile's block of shards
+    (``_gathered_block``, hoisted out of the loop over chunks of masks) in
+    HBM where one chip's program keeps them in fast memory: 8.7-9.1 planes
+    at ssb-96x4's 24 shards a chip against one chip's 0.4-0.6
+    (tests/test_tpu_compile.py). At most ROW_BLOCK rows of SHARD_BLOCK
+    shards; 0 where the shards do not divide into blocks and the pass goes
+    by whole planes."""
+    return 0 if shards % SHARD_BLOCK else ROW_BLOCK * SHARD_BLOCK * words * 4
+
+
 def _rows_block(matrix: jax.Array, rows: jax.Array, start) -> jax.Array:
     """The ``rows`` of the stack ``matrix [R, S, W]`` in the block of
     shards from ``start`` -> ``[K, block, W]``; zeros for a -1 (``take``

@@ -28,6 +28,7 @@ merge collapses into one collective).
 from __future__ import annotations
 
 import functools
+import math
 import threading
 
 import numpy as np
@@ -211,6 +212,16 @@ class MeshContext:
                 NamedSharding(self.mesh, spec), arr, global_shape
             )
         return jax.device_put(arr, NamedSharding(self.mesh, self._spec(s, w, lead_dims)))
+
+    def block_bytes(self, shape: tuple) -> int:
+        """Bytes of one device's block of a uint32 stack of ``shape``
+        [R, S, W] placed as ``place_stack`` places it (multi-host: S is
+        this process's shards): what the fullest device holds of it. No
+        device is touched — the stack ledger's projection before a build."""
+        r, s, w = shape
+        s *= jax.process_count() if self.multihost else 1
+        spec = self._spec(s, w, 1)
+        return math.prod(NamedSharding(self.mesh, spec).shard_shape((r, s, w))) * 4
 
     def place_stack(self, stacked):
         """uint32[R, S, W] (or [D, S, W] BSI block) → sharded device array.

@@ -250,11 +250,12 @@ def fold_to_columns(bm: Bitmap, shard_width: int = SHARD_WIDTH) -> Bitmap:
     """Fragment positions → the shard-relative COLUMN bitmap (positions
     mod shard_width), container-wise: when the shard width is a multiple
     of the container span (the ≥2^16 production widths), every row's
-    containers fold onto the column space by key arithmetic + a
-    container OR chain — O(containers), never a sort over the value
-    vector. This is the existence-marking fast path (docs/ingest.md):
-    the adopt delta's column set comes straight off its containers.
-    Narrow test widths fall back to the value-vector mod."""
+    containers fold onto the column space by key arithmetic + one
+    union of the containers of each column key — O(containers), never a
+    sort over the value vector. This is the existence-marking fast path
+    (docs/ingest.md): the adopt delta's column set comes straight off
+    its containers. Narrow test widths fall back to the value-vector
+    mod."""
     out = Bitmap()
     if not bm._containers:
         return out
@@ -262,9 +263,11 @@ def fold_to_columns(bm: Bitmap, shard_width: int = SHARD_WIDTH) -> Bitmap:
     if keys_per_row * ct.CONTAINER_BITS != shard_width or keys_per_row < 1:
         out.add_many(bm.values() % np.uint64(shard_width))
         return out
-    oc = out._containers
+    groups: dict[int, list[ct.Container]] = {}
     for key, c in bm._containers.items():
-        k = key % keys_per_row
-        existing = oc.get(k)
-        oc[k] = c if existing is None else ct.container_or(existing, c)
+        groups.setdefault(key % keys_per_row, []).append(c)
+    oc = out._containers
+    for k in sorted(groups):
+        cs = groups[k]
+        oc[k] = cs[0] if len(cs) == 1 else ct.container_union(cs)
     return out

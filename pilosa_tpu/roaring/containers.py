@@ -317,6 +317,24 @@ def container_or(a: Container, b: Container) -> Container:
     return _binary_op(a, b, "or")
 
 
+def container_union(conts: list[Container]) -> Container:
+    """The union of many containers in one pass: the array containers'
+    values stored into one bit vector together, the others' words ORed
+    in, the result an array or a bitmap by its cardinality. Pairwise,
+    every array operand would be turned into words on its own: a
+    thousand-row field's fold onto the column space is 16,000 operands
+    a shard (``roaring.build.fold_to_columns``)."""
+    arrays = [c.data for c in conts if c.type == TYPE_ARRAY]
+    bits = np.zeros(CONTAINER_BITS, dtype=bool)
+    if arrays:
+        bits[np.concatenate(arrays)] = True
+    words = np.packbits(bits, bitorder="little").view(np.uint64)
+    for c in conts:
+        if c.type != TYPE_ARRAY:
+            words |= as_words(c)
+    return optimize(bitmap_container(words), runs=False)
+
+
 def container_xor(a: Container, b: Container) -> Container:
     return _binary_op(a, b, "xor")
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from pilosa_tpu.roaring import containers as ct
 from pilosa_tpu.roaring.bitmap import Bitmap
 from pilosa_tpu.shardwidth import BITS_PER_WORD
 
@@ -25,8 +26,27 @@ def pack_range(bitmap: Bitmap, start: int, stop: int) -> np.ndarray:
     width = stop - start
     if width % BITS_PER_WORD:
         raise ValueError("range width must be a multiple of 32")
-    positions = (bitmap.range_values(start, stop) - np.uint64(start)).astype(np.int64)
-    return pack_positions(positions, width)
+    if start % ct.CONTAINER_BITS or width % ct.CONTAINER_BITS:
+        positions = (bitmap.range_values(start, stop) - np.uint64(start)).astype(np.int64)
+        return pack_positions(positions, width)
+    # container-aligned (every row of a fragment at the production
+    # widths): a bitmap or run container is its own 2,048 words, copied
+    # in place, with no unpacking to positions and packing again; only
+    # the array containers' values are scattered, in one pass
+    words = np.zeros(width // BITS_PER_WORD, dtype=np.uint32)
+    span = ct.CONTAINER_BITS // BITS_PER_WORD
+    arrays = []
+    for key in bitmap._range_keys(start, stop):
+        c = bitmap._containers[key]
+        rel = (key << 16) - start
+        if c.type == ct.TYPE_ARRAY:
+            arrays.append(c.data.astype(np.int64) + rel)
+        else:
+            at = rel // BITS_PER_WORD
+            words[at : at + span] = ct.as_words(c).view(np.uint32)
+    if arrays:
+        words |= pack_positions(np.concatenate(arrays), width)
+    return words
 
 
 def pack_positions(positions: np.ndarray, width: int) -> np.ndarray:

@@ -124,6 +124,7 @@ _METRIC_HELP = {
     "xla_cache_lookups": "persistent compilation cache lookups by result (hit / miss)",
     "stack_pack_seconds": "host time packing one dense stack from fragment matrices",
     "stack_upload_seconds": "host-to-device placement of one packed stack",
+    "stack_device_bytes": "bytes the stack ledger holds on its fullest device: a device's share of every resident stack on a mesh, the whole stacks on one device",
 }
 
 

@@ -162,6 +162,59 @@ def test_pack_range_offset(rng):
     assert roaring.words_count(roaring.pack_range(b, 0, 1 << 16)) == 0
 
 
+def _kinds_bitmap(rng, kinds, width):
+    """A bitmap of four rows of ``width`` columns whose containers are of
+    the ``kinds`` named: sparse arrays, dense bitmaps, runs, or all."""
+    parts = []
+    for r in range(4):
+        if "array" in kinds:
+            parts.append(rng.integers(0, width, 40 * (r + 1)))
+        if "bitmap" in kinds:
+            parts.append(rng.integers(0, width, width // 8))
+        if "run" in kinds:
+            s = int(rng.integers(0, width - 70_000))
+            parts.append(np.arange(s, s + 70_000))
+        parts[-1] = parts[-1].astype(np.uint64) + np.uint64(r * width)
+    b = roaring.Bitmap.from_values(np.unique(np.concatenate(parts)))
+    if "run" in kinds:
+        b._containers = {k: ct.optimize(c, runs=True) for k, c in b._containers.items()}
+    return b
+
+
+KINDS = [("array",), ("bitmap",), ("run",), ("array", "bitmap", "run")]
+
+
+@pytest.mark.parametrize("kinds", KINDS, ids=["+".join(k) for k in KINDS])
+def test_pack_range_of_aligned_rows_equals_the_value_path(rng, kinds):
+    """A container-aligned row packs container by container (a bitmap or
+    run container's words copied, the arrays' values scattered in one
+    pass) to exactly the words its values pack to."""
+    width = 1 << 20
+    b = _kinds_bitmap(rng, kinds, width)
+    for r in range(5):  # row 4 is empty
+        lo = r * width
+        want = roaring.pack_positions((b.range_values(lo, lo + width) - np.uint64(lo)).astype(np.int64), width)
+        got = roaring.pack_range(b, lo, lo + width)
+        assert got.dtype == np.uint32 and np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("kinds", KINDS, ids=["+".join(k) for k in KINDS])
+def test_fold_to_columns_is_the_union_of_the_rows(rng, kinds):
+    """Every row's containers folded onto the column space, one union a
+    column key: the columns of any row, each container an array at
+    ARRAY_MAX values or under and a bitmap above."""
+    from pilosa_tpu.roaring.build import fold_to_columns
+
+    width = 1 << 20
+    b = _kinds_bitmap(rng, kinds, width)
+    folded = fold_to_columns(b, width)
+    assert np.array_equal(folded.values(), np.unique(b.values() % np.uint64(width)))
+    for c in folded._containers.values():
+        n = ct.container_count(c)
+        assert c.type == ct.TYPE_RUN or (c.type == ct.TYPE_ARRAY) == (n <= ct.ARRAY_MAX)
+    assert ct.as_values(ct.container_union([ct.from_values(np.arange(3, dtype=np.uint16))] * 2)).tolist() == [0, 1, 2]
+
+
 # ------------------------------------------------------- regression findings
 def test_high_key_range_ops_no_overflow():
     # values >= 2^63 must work through range_count/range_values/pack_range

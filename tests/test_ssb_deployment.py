@@ -212,14 +212,19 @@ def pinned_budget() -> int:
 
 
 def _api(holder, route: str, stats=None) -> API:
+    """``levels``: the device route under ``pinned_budget()``;
+    ``mesh_levels``: the mesh route under the same planes, a device's
+    share of each (the mesh ledger's unit), so that its walk runs too."""
     mesh_ctx = None
-    if route == "mesh":
+    if route.startswith("mesh"):
         assert len(jax.devices()) == 8, "conftest must provide 8 virtual devices"
         mesh_ctx = MeshContext(make_mesh(jax.devices(), words_axis=1))
-    api = API(holder, stats=stats, mesh_ctx=mesh_ctx,
-              router=QueryRouter(mode="device" if route == "levels" else route, stats=stats))
+    mode = {"levels": "device", "mesh_levels": "mesh"}.get(route, route)
+    api = API(holder, stats=stats, mesh_ctx=mesh_ctx, router=QueryRouter(mode=mode, stats=stats))
     if route == "levels":
         api.executor.GROUPBY_MASK_BUDGET = pinned_budget()
+    elif route == "mesh_levels":
+        api.executor.GROUPBY_MASK_BUDGET = pinned_budget() // len(jax.devices())
     return api
 
 
@@ -227,7 +232,7 @@ def _api(holder, route: str, stats=None) -> API:
 def counted(holder):
     """A server a route, each with a registry of its own behind it."""
     out = {}
-    for route in ("device", "levels", "host", "mesh"):
+    for route in ("device", "levels", "host", "mesh", "mesh_levels"):
         client = StatsClient()
         out[route] = (_api(holder, route, stats=client), client)
     return out
@@ -241,7 +246,7 @@ def _family(client: StatsClient, name: str, **tags) -> float:
 
 
 # ------------------------------------------------------------------- cases
-@pytest.mark.parametrize("route", ["device", "levels", "host", "mesh"])
+@pytest.mark.parametrize("route", ["device", "levels", "host", "mesh", "mesh_levels"])
 @pytest.mark.parametrize("name", list(TEMPLATES))
 def test_served_reply_equals_the_brute_force_pass(counted, want, name, route):
     api, _client = counted[route]
@@ -374,6 +379,48 @@ def test_whole_stack_launches_are_counted_by_their_arguments(counted, lineorder)
     for name in ("q2_1", "q3_2", "q4_3"):
         api.query("ssb", TEMPLATES[name](_Draw(np.random.default_rng(5))))
     assert _family(client, "groupby_streamed_launches_total") == before
+
+
+def test_the_mesh_level_walk_runs_as_mesh_programs(holder, want):
+    """Under the mesh route's pinned budget every GroupBy of a deck but
+    Q4.1's walks the levels, each launch a mesh program, and none of the
+    deck's reads leaves the mesh route."""
+    client = StatsClient()
+    api = _api(holder, "mesh_levels", stats=client)
+    for name in TEMPLATES:
+        text = next(t for n, t in TEXTS if n == name)
+        assert api.query("ssb", text)["results"][0] == want[text]
+    assert _family(client, "groupby_queries_total", path="levels") == len(TEMPLATES) - 4
+    assert _family(client, "groupby_queries_total", path="fused") == 1
+    assert _family(client, "queries_routed", path="mesh") == len(TEMPLATES)
+    assert _family(client, "mesh_fallbacks_total") == 0
+    # every launch but each GroupBy's filter (a bitmap program) is a GroupBy program
+    group_bys = len(TEMPLATES) - 3
+    assert _family(client, "mesh_program_calls_total", program="groupby") + group_bys \
+        == _family(client, "groupby_launches_total")
+
+
+def test_the_mesh_ledger_reserves_a_devices_share_of_a_plane(holder, want):
+    """Q2.1 (3 years by 12 brands) under ``pinned_budget()``: on the
+    device route a plane is the whole plane, a level holds 16 masks and
+    the 36 pairs take three chunks, so the GroupBy walks the levels; on
+    the mesh route a plane is a device's share of it, the 64 padded pairs
+    fit one chunk and the same GroupBy is fused. The answer is the same,
+    and the mesh ledger's mark is the filter's plane, the levels' masks
+    and the temporaries in a device's share of a plane."""
+    text = next(t for n, t in TEXTS if n == "q2_1")
+    devices = len(jax.devices())
+    paths, marks = {}, {}
+    for route in ("levels", "mesh"):
+        client = StatsClient()
+        api = _api(holder, route, stats=client)
+        api.executor.GROUPBY_MASK_BUDGET = pinned_budget()
+        assert api.query("ssb", text)["results"][0] == want[text]
+        paths[route] = {p: _family(client, "groupby_queries_total", path=p) for p in ("fused", "levels")}
+        marks[route] = api.executor.gb_ledger.high_water
+    assert paths == {"levels": {"fused": 0, "levels": 1}, "mesh": {"fused": 1, "levels": 0}}
+    assert marks["mesh"] == (1 + 4 + 64 + ops.groupby.TEMP_PLANES) * (PLANE // devices)
+    assert marks["levels"] <= pinned_budget() < marks["mesh"] * devices
 
 
 @pytest.mark.parametrize(

@@ -28,6 +28,8 @@ from benchmark.datasets import taxi  # noqa: E402
 from benchmark.harness import pql as bench_pql, traffic  # noqa: E402
 from benchmark.harness.server import Client, parse_metrics  # noqa: E402
 from pilosa_tpu.executor import compile as query_compile  # noqa: E402
+from pilosa_tpu.executor.router import QueryRouter  # noqa: E402
+from pilosa_tpu.server.api import API  # noqa: E402
 from pilosa_tpu.server import Server  # noqa: E402
 from pilosa_tpu.utils.config import Config  # noqa: E402
 from pilosa_tpu.utils.tracing import GLOBAL_TRACER  # noqa: E402
@@ -197,9 +199,10 @@ def test_a_tiered_field_on_a_pinned_mesh_route_is_a_counted_fallback(rig, tmp_pa
     shard_map block cannot decode: the pinned mesh route hands such reads
     to the device path. The answers stay exact and every one is counted."""
     cfg = rig["cfg"]
-    # room for the eight padded rows of cab_type or pickup_year, not for
-    # passenger_count's 16, dist_miles's 32 or the amount's slices
-    plane = cfg["scale"]["shards"] * query_compile.WORDS_PER_SHARD * 4
+    # room on each device for its share of the eight padded rows of
+    # cab_type or pickup_year, not of passenger_count's 16, dist_miles's 32
+    # or the amount's slices: the stack ledger counts a device's share
+    plane = cfg["scale"]["shards"] // rig["devices"] * query_compile.WORDS_PER_SHARD * 4
     srv = boot(tmp_path, "mesh", device_stack_budget_bytes=8 * plane)
     try:
         ref = load(srv, cfg, cfg["index"])
@@ -215,6 +218,54 @@ def test_a_tiered_field_on_a_pinned_mesh_route_is_a_counted_fallback(rig, tmp_pa
         assert delta("mesh_fallbacks_total") == 3
         assert delta("queries_routed", 'path="mesh"') + delta("queries_routed", 'path="device"') == len(texts)
         assert delta("queries_routed", 'path="host"') == 0
+    finally:
+        srv.close()
+        query_compile.set_stack_budget(None)
+
+
+def test_a_stack_over_one_devices_budget_stays_dense_on_the_mesh_route(rig, tmp_path):
+    """On a mesh the stack ledger counts a device's share of a stack.
+    With the budget between dist_miles's share a device (32 padded rows
+    of two shards) and its whole stack, every field stays a dense stack:
+    the reads are mesh programs, none falls back, nothing is evicted, and
+    the ledger's gauge holds a device's share of them all. An executor
+    with no mesh, whose one device would hold the whole stack, serves the
+    same field without a dense stack under the same budget."""
+    cfg = rig["cfg"]
+    share = cfg["scale"]["shards"] // rig["devices"] * query_compile.WORDS_PER_SHARD * 4
+    # a device's share of every field's padded rows is under 128 planes;
+    # dist_miles's whole stack is 32 x devices of them
+    budget = 128 * share
+    assert 32 * share <= budget < 32 * rig["devices"] * share
+    texts = [text for _ti, text in traffic.Generator(SPEC, [SEED, 5]).warmup()]
+    srv = boot(tmp_path, "mesh", device_stack_budget_bytes=budget)
+    try:
+        ref = load(srv, cfg, cfg["index"])
+        before = metrics(srv)
+        c = Client(f"http://127.0.0.1:{srv.port}")
+        for text in texts:
+            assert ask(c, cfg["index"], text) == ref.answer(bench_pql.parse(text)), text
+        c.close()
+        after = metrics(srv)
+        delta = change(before, after)
+        assert delta("queries_routed", 'path="mesh"') == len(texts)
+        assert delta("mesh_fallbacks_total") == 0
+        assert delta("stack_evictions_total") == 0 and "stack_evictions_total" in after
+        holder = srv.api.holder
+        idx = holder.index(cfg["index"])
+        dist, scope = idx.field("dist_miles"), list(idx.shard_scope())
+        stacks = srv.api.executor.compiler.stacks
+        assert not stacks.is_over_budget(idx, dist, "standard", scope)
+        assert stacks.residency_snapshot()["entries"] == 0 and "dist_miles" in {key[1] for key in stacks._cache}
+        held = family(after, "stack_device_bytes")
+        assert held == stacks.resident_bytes and 32 * share < held <= budget
+
+        one = API(holder, router=QueryRouter(mode="device"))
+        for text in texts:
+            assert one.query(cfg["index"], text)["results"][0] == ref.answer(bench_pql.parse(text)), text
+        alone = one.executor.compiler.stacks
+        assert alone.is_over_budget(idx, dist, "standard", scope)
+        assert "dist_miles" not in {key[1] for key in alone._cache} and alone.resident_bytes <= budget
     finally:
         srv.close()
         query_compile.set_stack_budget(None)

@@ -412,9 +412,11 @@ def test_deferred_groupby_launches_at_the_groupby_cells_shapes(rig, one_chip, mo
             launches.append((what, prog, args))
         return original(self, what, prog, *args)
 
-    def admitted(self, nbytes, budget):
+    def admitted(self, nbytes, budget, **tags):
         reserved.append(nbytes // (S_TINY * WORDS_PER_SHARD * 4))
-        return admit(self, nbytes, budget)
+        # the admission span's labels: the device route, the planes reserved
+        assert tags == {"devices": 1, "planes": reserved[-1]}
+        return admit(self, nbytes, budget, **tags)
 
     monkeypatch.setattr(Executor, "_gb_launch", launch)
     monkeypatch.setattr(executor_mod.GroupByLedger, "admit", admitted)
@@ -582,3 +584,60 @@ def test_mesh_programs_at_the_four_chip_cells_shapes(rig, mesh, program):
     text = compiled.as_text()
     assert "all-reduce" in text and "pilosa.mesh_psum" in text
     assert "u32[19," not in text
+
+
+# the cell ssb-96x4.flights (benchmark/configs/ssb-96x4.json): 96 shards
+# over the four chips, 24 a chip, ssb-24's one chip's share; a chip holds
+# a quarter of every stack, 2,008 padded planes of 24 shards
+S_SSB = 96
+SSB_CHIP_STACKS = 2008 * (S_SSB // 4) * W * 4
+
+
+@pytest.mark.parametrize(
+    "program,groups,rows",
+    [("counts", 32, 1024), ("counts", 8, 1024), ("counts", 16, 256), ("counts", 1, 1024),
+     ("counts", 1, 256), ("masks", 32, 1024), ("sums", 128, 26), ("sums", 256, 26), ("sums", 1, 27)],
+    ids=["q4_3", "q2_x", "q3_2", "brands_root", "cities_root", "q4_3_masks", "q3_q4_sums",
+         "q2_x_sums", "one_sum"],
+)
+def test_mesh_groupby_programs_at_the_ssb_four_chip_cells_shapes(mesh, program, groups, rows):
+    """The level walk's and the deferred walk's mesh programs at
+    ssb-96x4.flights' shapes compile for the 4 x 1 v5e mesh: the counts
+    of 32 (year, city) masks against the 1,024 brands, of 8 years against
+    them, of 16 cities against 256, the first read's one mask against the
+    1,024 brands or the 256 cities (one pass over the whole stack, as on
+    one chip); a chunk of 128 pair masks from 32 parents and the brands;
+    the grouped sums of 128 or 256 masks against lo_revenue's 26 planes
+    and of one against lo_profit's 27. Temporaries inside ``TEMP_PLANES``
+    of a chip's plane and the rows of one tile of the count pass
+    (``ops.groupby.mesh_tile_bytes``), what the transient ledger reserves
+    a device on the mesh route, and a chip's share of the stacks beside
+    each program fits its memory."""
+    engine = MeshQueryEngine(mesh)
+    placed = placed_on(mesh)
+
+    def planes(n):
+        shape = (n, S_SSB, W)
+        return jax.ShapeDtypeStruct(shape, np.uint32, sharding=placed(shape))
+
+    def ids(n):
+        return jax.ShapeDtypeStruct((n,), np.int32, sharding=placed(()))
+
+    if program == "counts":
+        prog = engine.groupby_counts_tree("grid")
+        args = (planes(groups), planes(rows), ids(rows))
+    elif program == "masks":
+        prog = engine.groupby_masks_tree("grid")
+        args = (planes(groups), planes(rows), ids(128), ids(128))
+    else:
+        prog = engine.grouped_sum_tree(ops.groupby.grouped_sums, "grid")
+        args = (planes(rows), planes(groups))
+    compiled = compile_and_fit(prog, args, devices=4)
+    mem = compiled.memory_analysis()
+    chip_plane = S_SSB // 4 * W * 4
+    tile = ops.groupby.mesh_tile_bytes(S_SSB // 4, W)
+    assert mem.temp_size_in_bytes <= ops.groupby.TEMP_PLANES * chip_plane + tile
+    assert SSB_CHIP_STACKS + mem.temp_size_in_bytes + mem.output_size_in_bytes < HBM_BYTES * 0.7
+    if program != "masks":
+        text = compiled.as_text()
+        assert "all-reduce" in text and "pilosa.mesh_psum" in text
