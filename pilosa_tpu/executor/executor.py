@@ -191,7 +191,9 @@ _gb_chains = named_jit("pilosa_groupby_chains", ops.groupby.chain_counts)
 
 # The most chunks of ``chunk_cap`` pairs that a GroupBy expands UNPRUNED,
 # every (parent, real row) pair with no level read back
-# (``Executor._groupby_deferred``). An unpruned chunk costs the device at
+# (``Executor._groupby_deferred``), from its row lists as they are or, on
+# the level walk, from the rows its first read found the filter to hold
+# (``path="pruned"``). An unpruned chunk costs the device at
 # most what a full chunk of the level path costs (7.1 ms of masks and
 # 13.7 ms of counts for 64 masks by 32 rows at 128 shards; my chip runs,
 # PR 34), and a level's round trip costs 5-8 ms of an idle device and the
@@ -276,6 +278,16 @@ class GroupByLedger:
         with self._cond:
             token.done = done
             self._in_flight.append(token)
+
+    def shrink(self, token: _Held, nbytes: int) -> None:
+        """Hand back what ``token`` holds over ``nbytes``, waking the
+        requests that wait for room; a reservation never grows."""
+        with self._cond:
+            surplus = token.nbytes - int(nbytes)
+            if surplus > 0:
+                token.nbytes -= surplus
+                self.held -= surplus
+                self._cond.notify_all()
 
     def release(self, token: _Held) -> None:
         with self._cond:
@@ -1883,44 +1895,57 @@ class Executor:
         mesh_mode = self.compiler.mesh_mode(n_shards) if mesh else None
         plane_bytes, temp_bytes = self._gb_device_bytes(n_shards, mesh_mode)
         budget = self._gb_budget()
-        kp = [_pow2(len(r)) for r in row_lists]
-        fold = list(itertools.accumulate(kp, operator.mul))  # pairs down to each level
-        mask_levels = fold if agg_field is not None else fold[:-1]
-        # a level without a resident stack streams its rows host→device,
-        # never more of them at a time than a level holds masks
-        streamed = max(
-            (k for k, m in zip(kp, matrices) if m is None), default=0
-        )
 
-        def need(cap: int) -> int:
-            """Bytes held when no level holds more than ``cap`` masks."""
-            planes = 1 + sum(min(cap, g) for g in mask_levels) + min(cap, streamed)
-            return planes * plane_bytes + temp_bytes
+        def plan(lists: list[list[int]], ceiling: int) -> tuple[int, bool, bool, int]:
+            """The walk of row lists ``lists`` within ``ceiling`` bytes:
+            (chunk_cap, deferred, chain, the bytes it holds)."""
+            kp = [_pow2(len(r)) for r in lists]
+            fold = list(itertools.accumulate(kp, operator.mul))  # pairs down to each level
+            mask_levels = fold if agg_field is not None else fold[:-1]
+            # a level without a resident stack streams its rows host→device,
+            # never more of them at a time than a level holds masks
+            streamed = max(
+                (k for k, m in zip(kp, matrices) if m is None), default=0
+            )
 
-        # the largest power of two of masks a level that fits beside the
-        # rest: padded chunks never exceed it, and pow2 shapes keep XLA
-        # retraces to one compile per bucket
-        chunk_cap = _pow2(max(mask_levels + [1]))
-        while chunk_cap > 1 and need(chunk_cap) > budget:
-            chunk_cap //= 2
-        # Which walk, from the shapes in hand: the (parent, real row)
-        # pairs of the deepest level that materialises masks, in chunks of
-        # chunk_cap. Few chunks over resident stacks: all pairs, nothing
-        # read back (DEFERRED_CHUNKS). Under a limit only where one chunk
-        # holds them all, since the level path stops early.
-        pairs = math.prod(len(r) for r in row_lists[: len(mask_levels)])
-        chunks = -(-pairs // chunk_cap)
-        deferred = (
-            not streamed
-            and chunks <= DEFERRED_CHUNKS
-            and (limit is None or chunks == 1)
+            def need(cap: int) -> int:
+                """Bytes held when no level holds more than ``cap`` masks."""
+                planes = 1 + sum(min(cap, g) for g in mask_levels) + min(cap, streamed)
+                return planes * plane_bytes + temp_bytes
+
+            # the largest power of two of masks a level that fits beside the
+            # rest: padded chunks never exceed it, and pow2 shapes keep XLA
+            # retraces to one compile per bucket
+            chunk_cap = _pow2(max(mask_levels + [1]))
+            while chunk_cap > 1 and need(chunk_cap) > ceiling:
+                chunk_cap //= 2
+            # Which walk, from the shapes in hand: the (parent, real row)
+            # pairs of the deepest level that materialises masks, in chunks
+            # of chunk_cap. Few chunks over resident stacks: all pairs,
+            # nothing read back (DEFERRED_CHUNKS). Under a limit only where
+            # one chunk holds them all, since the level path stops early.
+            pairs = math.prod(len(r) for r in lists[: len(mask_levels)])
+            chunks = -(-pairs // chunk_cap)
+            deferred = (
+                not streamed
+                and chunks <= DEFERRED_CHUNKS
+                and (limit is None or chunks == 1)
+            )
+            # Of those, a GroupBy of several levels without an aggregate
+            # needs no mask at all: one chain count ANDs the levels above
+            # the last inside the program, so it holds the filter's plane
+            # and the temporaries only
+            chain = deferred and len(fields) > 1 and agg_field is None
+            return chunk_cap, deferred, chain, (
+                plane_bytes + temp_bytes if chain else need(chunk_cap)
+            )
+
+        chunk_cap, deferred, chain, reserved = plan(row_lists, budget)
+        # the level walk over resident stacks reads the filter's counts
+        # against every level's rows first, and chooses again from them
+        first_read = (
+            not deferred and len(fields) > 1 and all(m is not None for m in matrices)
         )
-        # Of those, a GroupBy of several levels without an aggregate needs
-        # no mask at all: one chain count ANDs the levels above the last
-        # inside the program, so it holds the filter's plane and the
-        # temporaries only
-        chain = deferred and len(fields) > 1 and agg_field is None
-        reserved = plane_bytes + temp_bytes if chain else need(chunk_cap)
         held = self.gb_ledger.admit(
             reserved, budget,
             devices=self.compiler.mesh_engine.n_devices if mesh_mode is not None else 1,
@@ -1947,10 +1972,21 @@ class Executor:
                 )
             if mesh_mode is not None:
                 base_mask = base_mask[None]  # the mesh trees' specs are [G, S, W]
-            self._gb_stats.count(
-                "groupby_queries_total",
-                tags={"path": "fused" if deferred else "levels"},
-            )
+            path, root = ("fused" if deferred else "levels"), None
+            if first_read:
+                root, row_lists = self._gb_first_read(
+                    gb_counts_call, base_mask, matrices, row_lists
+                )
+                # the walk again, on the rows the filter holds and within
+                # what was admitted: where their pairs fit the deferred
+                # walk, nothing more is read and the rest goes back
+                cap, deferred, chain, nbytes = plan(row_lists, reserved)
+                if deferred:
+                    path, chunk_cap = "pruned", cap
+                    self.gb_ledger.shrink(held, nbytes)
+            self._gb_stats.count("groupby_queries_total", tags={"path": path})
+            if not all(row_lists):
+                return []  # the filter holds no row of a level: no group
             if chain:
                 self._gb_stats.count("groupby_chain_queries_total")
             if deferred:
@@ -1963,9 +1999,9 @@ class Executor:
                 held = None  # the pending result frees it
                 return pend if lazy else pend.resolve_now()
             out = self._groupby_levels(
-                fields, row_lists, matrices, base_mask, limit, shards, plane_bytes,
-                chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
-                held, route="mesh" if mesh_mode is not None else "device",
+                fields, row_lists, root, matrices, base_mask, limit, shards,
+                plane_bytes, chunk_cap, sum_prog, agg_slices, gb_counts_call,
+                gb_masks_call, held, route="mesh" if mesh_mode is not None else "device",
             )
             held = None  # the walk released it, or its pending result does
             return out.resolve_now() if isinstance(out, _Pending) and not lazy else out
@@ -1973,10 +2009,32 @@ class Executor:
             if held is not None:
                 self.gb_ledger.release(held)
 
+    def _gb_first_read(self, gb_counts_call, base_mask, matrices, row_lists):
+        """The level walk's first read over resident stacks: the filter's
+        counts against the rows of EVERY level, one counts launch a level
+        and one read. A group lies inside the filter, so a row the filter
+        does not hold is in none, and each level keeps the rows it holds
+        (``groupby_level_pairs_total``: all counted, those kept).
+        -> (level 0's counts of its kept rows [1, k], the kept row lists)."""
+        got = self._gb_read([
+            self._gb_launch(
+                "counts", gb_counts_call, base_mask, m, _pad_row_ids(rows, _pow2(len(rows)))
+            )
+            for m, rows in zip(matrices, row_lists)
+        ])
+        kept = []
+        for counts, rows in zip(got, row_lists):
+            holds = counts[0, : len(rows)] > 0
+            self._gb_stats.count("groupby_level_pairs_total", len(rows), tags={"stage": "counted"})
+            self._gb_stats.count("groupby_level_pairs_total", int(holds.sum()), tags={"stage": "kept"})
+            kept.append([r for r, h in zip(rows, holds.tolist()) if h])
+        c0 = got[0][0, : len(row_lists[0])]
+        return c0[c0 > 0][None], kept
+
     def _groupby_levels(
-        self, fields, row_lists, matrices, base_mask, limit, shards, plane_bytes,
-        chunk_cap, sum_prog, agg_slices, gb_counts_call, gb_masks_call,
-        held, route: str,
+        self, fields, row_lists, root, matrices, base_mask, limit, shards,
+        plane_bytes, chunk_cap, sum_prog, agg_slices, gb_counts_call,
+        gb_masks_call, held, route: str,
     ) -> "list[dict] | _Pending":
         """Level-synchronous evaluation: a whole nesting level runs in TWO
         device dispatches — (1) counts of every (surviving group ×
@@ -1989,14 +2047,15 @@ class Executor:
         chunks depth-first (order — and therefore limit semantics — is
         preserved because chunks run in pair order). Shapes pad to
         powers of two so recompiles stay rare. The walk of streamed
-        levels, of expansions over ``DEFERRED_CHUNKS`` chunks and of
-        chunked ones under a ``limit``; the rest never read a level back
-        (``_groupby_deferred``).
+        levels, and of expansions over ``DEFERRED_CHUNKS`` chunks or
+        chunked under a ``limit`` even on the rows the filter holds; the
+        rest never read a level back (``_groupby_deferred``).
 
-        Over resident stacks the first read holds the filter's counts
-        against the rows of EVERY level: a parent lies inside the filter,
-        so a row the filter does not hold pairs with none, and the levels
-        below the first count only the rows it holds. The grouped sums
+        Over resident stacks the first read (``_gb_first_read``) has
+        already counted the filter against the rows of EVERY level:
+        ``row_lists`` are the rows it holds and ``root`` level 0's counts
+        of them, so the walk reads again from level 1 on; elsewhere
+        ``root`` is None and level 0 is read like the rest. The grouped sums
         are never read inside the walk: they ride the wave's readback as
         a ``_Pending``, and before a chunk's masks are made the walk waits
         for the device to finish the last sums (``_gb_wait``), so a level
@@ -2005,7 +2064,6 @@ class Executor:
         ``plane_bytes`` is the ledger's plane on the query's route."""
         n_shards = len(shards)
         results: list[dict] = []
-        row_lists = list(row_lists)
         arrays: list = []  # the pending result's: (pos, neg) pairs
         sums: list[tuple[int, int, int]] = []  # (first result, groups, pos slot)
 
@@ -2135,15 +2193,16 @@ class Executor:
             if limit is not None and len(results) >= limit:
                 return
             rows_l = row_lists[level]
-            cnp = root.pop() if root else _level_counts(level, masks, len(groups))
-            pairs = np.argwhere(cnp > 0)  # (g-major, k-minor) = lexicographic
             last = level == len(fields) - 1
+            cnp = root if level == 0 and root is not None else _level_counts(level, masks, len(groups))
+            pairs = np.argwhere(cnp > 0)  # (g-major, k-minor) = lexicographic
             if last and limit is not None:
                 pairs = pairs[: limit - len(results)]
-            # how much the read prunes: the real pairs the launch counted,
-            # and those the walk goes on with
-            self._gb_stats.count("groupby_level_pairs_total", cnp.size, tags={"stage": "counted"})
-            self._gb_stats.count("groupby_level_pairs_total", len(pairs), tags={"stage": "kept"})
+            if cnp is not root:  # the first read counted its own
+                # how much the read prunes: the real pairs the launch
+                # counted, and those the walk goes on with
+                self._gb_stats.count("groupby_level_pairs_total", cnp.size, tags={"stage": "counted"})
+                self._gb_stats.count("groupby_level_pairs_total", len(pairs), tags={"stage": "kept"})
             for lo in range(0, pairs.shape[0], chunk_cap):
                 chunk = pairs[lo : lo + chunk_cap]
                 self._gb_stats.count("groupby_chunks_total")
@@ -2168,20 +2227,6 @@ class Executor:
                 if limit is not None and len(results) >= limit:
                     return
 
-        root: list[np.ndarray] = []  # level 0's counts, where the first read has them
-        if len(fields) > 1 and all(m is not None for m in matrices):
-            got = self._gb_read([
-                self._gb_launch(
-                    "counts", gb_counts_call, base_mask, m, _pad_row_ids(rows, _pow2(len(rows)))
-                )
-                for m, rows in zip(matrices, row_lists)
-            ])
-            root.append(got[0][:1, : len(row_lists[0])])
-            for level in range(1, len(fields)):
-                holds = (got[level][0, : len(row_lists[level])] > 0).tolist()
-                self._gb_stats.count("groupby_level_pairs_total", len(holds), tags={"stage": "counted"})
-                self._gb_stats.count("groupby_level_pairs_total", sum(holds), tags={"stage": "kept"})
-                row_lists[level] = [r for r, h in zip(row_lists[level], holds) if h]
         expand(0, base_mask, [()])
         if not arrays:
             self.gb_ledger.release(held)

@@ -68,14 +68,14 @@ _METRIC_HELP = {
     "shard_scope_rebuilds_total": "rebuilds of an index's memoized shard tuple (its mutation stamp moved)",
     "bsi_condition_leaves_total": "BSI comparison leaves planned, by operator",
     "device_scalar_uploads_total": "misses of the device operand-vector cache (one small upload each)",
-    "groupby_queries_total": "GroupBy calls by path: fused (all pairs, one deferred readback), levels (a read a level), host",
+    "groupby_queries_total": "GroupBy calls by path: fused (all pairs, one deferred readback), pruned (the first read, then all pairs of the rows the filter holds), levels (a read a level), host",
     "groupby_launches_total": "device programs issued for GroupBys (filter, counts, masks, sums)",
     "groupby_level_readbacks_total": "synchronous device-to-host reads inside a level-synchronous GroupBy's dispatch",
     "groupby_chunk_waits_total": "waits of a GroupBy for its own last program (counts or sums) before its next chunk of masks",
     "groupby_chain_queries_total": "GroupBys answered by one chain count launch (several levels, no aggregate, no mask made)",
     "groupby_mask_bytes_total": "bytes of group masks materialised on the device",
     "groupby_chunks_total": "pair chunks a level-synchronous GroupBy expanded",
-    "groupby_level_pairs_total": "real (parent, row) pairs of a level-synchronous GroupBy's counts launches, the filter against the rows of the levels below the first included (stage=counted), and those with a count above zero that the walk went on with (stage=kept)",
+    "groupby_level_pairs_total": "real (parent, row) pairs of a level-synchronous GroupBy's counts launches, the first read's filter against the rows of every level included (stage=counted), and those with a count above zero that the walk went on with (stage=kept)",
     "groupby_groups_summed_total": "real (unpadded) groups of every GroupBy sums launch, on both walks",
     "groupby_streamed_launches_total": "GroupBy counts launches of one mask that are one pass over the whole stack (ops.groupby.whole_stack)",
     "groupby_transient_high_water_bytes": "most device bytes GroupBys in flight have held beside the stacks",

@@ -7,11 +7,15 @@ expands the survivors. Entry for entry, on the device and mesh routes,
 against each other and against a brute-force table written here.
 
 Also the rule that picks the walk (``DEFERRED_CHUNKS``): from the row
-lists, the resident stacks and the chunk cap alone; and, of the deferred
+lists, the resident stacks and the chunk cap, and again after the level
+walk's first read from the rows the filter holds (``path="pruned"``),
+within the reservation already admitted; and, of the deferred
 ``GroupBy``s, the ones of several levels without an aggregate as ONE
 chain count (``ops.groupby.chain_counts``), held to the masks and counts
 it replaces.
 """
+
+import threading
 
 import numpy as np
 import pytest
@@ -20,7 +24,9 @@ import jax
 
 from pilosa_tpu import ops
 from pilosa_tpu.core import FieldOptions, Holder
+from pilosa_tpu.executor import executor as executor_mod
 from pilosa_tpu.executor.compile import StackCache
+from pilosa_tpu.executor.executor import GroupByLedger
 from pilosa_tpu.executor.router import QueryRouter
 from pilosa_tpu.parallel.mesh import MeshContext, make_mesh
 from pilosa_tpu.server.api import API
@@ -40,9 +46,11 @@ def budget(cap: int) -> int:
 
 
 # one chunk a level whatever the query (None: the default budget), the 30
-# real (a, b) pairs in 16 + 14, and one mask at a time: over two chunks
-# wherever a level has masks, so the level-synchronous walk
-BUDGETS = {"one_chunk": None, "two_chunks": budget(16), "levels": 0}
+# real (a, b) pairs in 16 + 14, in four chunks of 8 (the level walk's
+# first read, then the deferred walk where the pairs the filter holds fit
+# two), and one mask at a time: over two chunks wherever a level has
+# masks before the first read
+BUDGETS = {"one_chunk": None, "two_chunks": budget(16), "four_chunks": budget(8), "levels": 0}
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +108,15 @@ def counter(client: StatsClient, name: str) -> float:
         return sum(v for (n, _tags), v in client._counters.items() if n == name)
 
 
+def level_walk(api: API, pql: str, monkeypatch) -> list[dict]:
+    """``pql`` answered by the level walk whatever its pairs: under one
+    mask a level the pairs a filter holds can still fit two chunks, so
+    for the reference no walk is deferred."""
+    with monkeypatch.context() as m:
+        m.setattr(executor_mod, "DEFERRED_CHUNKS", 0)
+        return api.query("s", pql)["results"][0]
+
+
 def brute(table, fields, where=None, first=None, agg=False, limit=None) -> list[dict]:
     """The joint table of ``fields`` over the columns ``where`` keeps, a
     level's rows from ``first[field]`` on, cells in nested ascending order."""
@@ -139,20 +156,23 @@ QUERIES = {
                             lambda t: brute(t, ["a"], agg=True, limit=3)),
     "wide_last_level": ("GroupBy(Rows(b), Rows(wide), filter=Row(a=2))",
                         lambda t: brute(t, ["b", "wide"], where=t["a"] == 2)),
+    "sum_filter_prunes": (
+        "GroupBy(Rows(a), Rows(b), filter=Union(Row(c=3), Row(c=4)), aggregate=Sum(field=v))",
+        lambda t: brute(t, ["a", "b"], where=(t["c"] == 3) | (t["c"] == 4), agg=True)),
 }
 
 
 @pytest.mark.parametrize("route", ["device", "mesh"])
-@pytest.mark.parametrize("chunks", ["one_chunk", "two_chunks"])
+@pytest.mark.parametrize("chunks", ["one_chunk", "two_chunks", "four_chunks"])
 @pytest.mark.parametrize("query", list(QUERIES))
-def test_deferred_and_level_walks_agree_on_sparse_data(apis, table, query, chunks, route):
+def test_deferred_and_level_walks_agree_on_sparse_data(apis, table, monkeypatch, query, chunks, route):
     pql, truth = QUERIES[query]
     walk, walk_stats = apis[(route, chunks)]
     levels, level_stats = apis[(route, "levels")]
     fused0 = paths(walk_stats).get("fused", 0)
     levels0 = paths(level_stats).get("levels", 0)
     got = walk.query("s", pql)["results"][0]
-    assert got == levels.query("s", pql)["results"][0]
+    assert got == level_walk(levels, pql, monkeypatch)
     assert got == truth(table)
     assert 0 < len(got) <= 80  # of 200 or 210 groups: most are empty, none is answered
     # the reference really was the other walk; under the default budget
@@ -164,9 +184,15 @@ def test_deferred_and_level_walks_agree_on_sparse_data(apis, table, query, chunk
         assert counter(walk_stats, "groupby_level_readbacks_total") == 0
 
 
+# filters that the first read prunes to a (1, 3) x (1, 2, 3) and to five
+# rows of a by all five of b
+C34 = "Union(Row(c=3), Row(c=4))"
+C0156 = "Union(Row(c=0), Row(c=1), Row(c=5), Row(c=6))"
+
 # (pql, budget, stack budget in rows or None, path, chunk waits, chain
 # counts): a deferred GroupBy of several levels without an aggregate is
-# one chain count, so it never waits for a chunk of masks
+# one chain count, so it never waits for a chunk of masks; nor is a
+# pruned one, whose pairs the first read has found to fit two chunks
 RULE = {
     "one_chunk": ("GroupBy(Rows(a), Rows(b), Rows(c))", None, None, "fused", 0, 1),
     "two_chunks": ("GroupBy(Rows(a), Rows(b), Rows(c))", budget(16), None, "fused", 0, 1),
@@ -179,6 +205,18 @@ RULE = {
                             budget(16), None, "levels", 0, 0),
     "a_streamed_level": ("GroupBy(Rows(a), Rows(wide))", None, 16, "levels", 0, 0),
     "one_level": ("GroupBy(Rows(a), filter=Row(c=3))", None, None, "fused", 0, 0),
+    "pruned_to_one_chunk_of_sums": (
+        f"GroupBy(Rows(a), Rows(b), filter={C34}, aggregate=Sum(field=v))",
+        budget(8), None, "pruned", 0, 0),
+    "pruned_to_a_chain": (f"GroupBy(Rows(a), Rows(b), Rows(c), filter={C34})",
+                          budget(8), None, "pruned", 0, 1),
+    "pruned_under_a_limit": (f"GroupBy(Rows(a), Rows(b), Rows(c), filter={C34}, limit=2)",
+                             budget(8), None, "pruned", 0, 1),
+    "pruned_still_four_chunks": (
+        f"GroupBy(Rows(a), Rows(b), filter={C0156}, aggregate=Sum(field=v))",
+        budget(8), None, "levels", 0, 0),
+    "pruned_empty": ("GroupBy(Rows(a), Rows(b), filter=Intersect(Row(c=3), Row(c=4)), "
+                     "aggregate=Sum(field=v))", budget(8), None, "pruned", 0, 0),
 }
 
 
@@ -192,9 +230,12 @@ def test_the_walk_is_chosen_from_pairs_stacks_and_cap(holder, apis, monkeypatch,
     assert paths(client) == {path: 1}
     assert counter(client, "groupby_chunk_waits_total") == waits
     assert counter(client, "groupby_chain_queries_total") == chained
-    assert (counter(client, "groupby_level_readbacks_total") > 0) == (path == "levels")
+    # nothing read back on the deferred walk, the first read alone on the
+    # pruned one, a level more on the level walk
+    reads = counter(client, "groupby_level_readbacks_total")
+    assert reads > 1 if path == "levels" else reads == {"fused": 0, "pruned": 1}[path]
     if stack_rows is None:
-        assert got == apis[("device", "levels")][0].query("s", pql)["results"][0]
+        assert got == level_walk(apis[("device", "levels")][0], pql, monkeypatch)
     else:
         monkeypatch.undo()
         assert got == apis[("device", "one_chunk")][0].query("s", pql)["results"][0]
@@ -263,3 +304,53 @@ def test_chain_counts_equal_masks_then_counts(levels, shards):
     fewer = np.asarray(ops.groupby.chain_counts(
         filt, tuple(uppers), upper_rows, table, np.int32(n - 3), last, rows))
     assert (fewer[: n - 3] == want[: n - 3]).all() and not fewer[n - 3:].any()
+
+
+def test_the_pruned_walk_hands_back_what_it_no_longer_holds(holder, monkeypatch):
+    """``budget(8)`` admits the filter's plane, a's 8 masks, a chunk of 8
+    (a, b) masks and the temporaries. The first read finds the filter in
+    a's rows 1 and 3 and b's 1 to 3: 2 + 8 padded masks, in one chunk.
+    The reservation shrinks to them before the deferred walk, and never
+    past what was admitted."""
+    api, client = _api(holder, "device", budget(8))
+    ledger = api.executor.gb_ledger
+    seen = []
+    shrink = ledger.shrink
+
+    def spy(token, nbytes):
+        before = token.nbytes
+        shrink(token, nbytes)
+        seen.append((before, token.nbytes, ledger.held))
+
+    monkeypatch.setattr(ledger, "shrink", spy)
+    pql = RULE["pruned_to_one_chunk_of_sums"][0]
+    assert api.query("s", pql)["results"][0] == level_walk(api, pql, monkeypatch)
+    pruned = (1 + 2 + 8 + ops.groupby.TEMP_PLANES) * PLANE
+    assert seen == [(budget(8), pruned, pruned)]
+    assert ledger.snapshot() == {"heldBytes": 0, "highWaterBytes": budget(8), "fusedInFlight": 0}
+
+
+def test_a_shrunk_reservation_lets_a_waiting_admit_in():
+    """An admit that does not fit waits; a shrink that does not make room
+    (or would grow the reservation) leaves it waiting, and one that does
+    lets it in."""
+    ledger = GroupByLedger(StatsClient())
+    first = ledger.admit(8 * PLANE, 10 * PLANE)
+    got = []
+    waiter = threading.Thread(
+        target=lambda: got.append(ledger.admit(4 * PLANE, 10 * PLANE)), daemon=True)
+    waiter.start()
+    waiter.join(0.3)
+    assert waiter.is_alive() and not got
+    ledger.shrink(first, 12 * PLANE)  # a reservation never grows
+    assert first.nbytes == ledger.held == 8 * PLANE
+    ledger.shrink(first, 7 * PLANE)  # 11 planes: still no room
+    waiter.join(0.3)
+    assert waiter.is_alive() and not got
+    ledger.shrink(first, 5 * PLANE)
+    waiter.join(10)
+    assert not waiter.is_alive() and got
+    assert ledger.held == 9 * PLANE
+    ledger.release(first)
+    ledger.release(got[0])
+    assert ledger.held == 0 and ledger.high_water == 9 * PLANE

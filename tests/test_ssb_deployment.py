@@ -1,7 +1,9 @@
 """The deployment ``ssb-24`` at a CPU size: the Star Schema Benchmark's
 thirteen queries over the flattened ``lineorder``, served through
 ``API.query`` and the wave scheduler on the device, host and mesh routes
-and on the device under a budget that forces the level walk, against a
+and on the device under a budget that forces the level walk's first read
+(after which most GroupBys fit the deferred walk on the rows the filter
+holds, and Q3.2 and Q4.3 walk on), against a
 brute-force numpy pass over the seeded columns written here (a mask a row
 call, ``np.add.at`` into the joint table; nothing of the program's
 planner or ops, nor of the benchmark's reference).
@@ -37,7 +39,7 @@ from pilosa_tpu.utils.stats import StatsClient
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N_SHARDS = 8  # one a virtual device on the mesh route
 PLANE = N_SHARDS * WORDS_PER_SHARD * 4
-CAP = 8  # masks a level under the pinned budget: every GroupBy but Q4.1 walks the levels
+CAP = 8  # masks a level under the pinned budget: every GroupBy but Q4.1 reads the filter's rows first
 SEED = 3900
 ROWS = {"d_year": 3, "d_yearmonthnum": 36, "d_weeknuminyear": 53, "c_region": 2, "c_nation": 4,
         "c_city": 12, "s_region": 2, "s_nation": 4, "s_city": 12, "p_mfgr": 2, "p_category": 4,
@@ -205,10 +207,11 @@ def want(lineorder):
 
 
 # ----------------------------------------------------------------- servers
-def pinned_budget() -> int:
-    """The filter's plane, three levels of ``CAP`` masks and the
-    temporaries: every three-level GroupBy's pairs need over two chunks."""
-    return (1 + 3 * CAP + ops.groupby.TEMP_PLANES) * PLANE
+def pinned_budget(cap: int = CAP) -> int:
+    """The filter's plane, three levels of ``cap`` masks and the
+    temporaries: under ``CAP`` every three-level GroupBy's pairs need
+    over two chunks before its first read."""
+    return (1 + 3 * cap + ops.groupby.TEMP_PLANES) * PLANE
 
 
 def _api(holder, route: str, stats=None) -> API:
@@ -284,13 +287,18 @@ def test_the_reference_after_a_lost_part_mismatches_every_group_by(cfg, lineorde
             assert lost.answer(call) != want[text], text
 
 
-def test_level_walk_counts_its_pairs_and_sums_its_groups(counted, lineorder):
+def test_level_walk_counts_its_pairs_and_sums_its_groups(holder, lineorder):
     """docs/observability.md: on the level walk every counts launch adds
     its real (parent, row) pairs to ``counted`` and those with a count
     above zero to ``kept``, the first read's filter against the rows of
     the levels below the first included, and those levels count only
-    the rows the filter holds; every sums launch adds its real groups."""
-    api, client = counted["levels"]
+    the rows the filter holds; every sums launch adds its real groups.
+    Under ``CAP`` the 2 x 2 x 4 pairs that Q4.2's filter holds fit two
+    chunks (the deferred walk after the first read); under 4 masks a
+    level they need four, and the walk goes on level by level."""
+    client = StatsClient()
+    api = _api(holder, "levels", stats=client)
+    api.executor.GROUPBY_MASK_BUDGET = pinned_budget(4)
     assert "groupby_level_pairs_total" in stats_mod._METRIC_HELP
     assert "groupby_groups_summed_total" in stats_mod._METRIC_HELP
     text = TEMPLATES["q4_2"](_Draw(np.random.default_rng(5)))
@@ -313,6 +321,7 @@ def test_level_walk_counts_its_pairs_and_sums_its_groups(counted, lineorder):
     assert _family(client, "groupby_level_pairs_total", stage="kept") - before["kept"] \
         == held[1] + held[2] + sum(map(len, levels))
     assert _family(client, "groupby_groups_summed_total") - summed == len(table)
+    assert _family(client, "groupby_queries_total", path="levels") == 1
 
 
 def test_a_filter_that_empties_the_first_level_keeps_no_pair(counted):
@@ -383,14 +392,17 @@ def test_whole_stack_launches_are_counted_by_their_arguments(counted, lineorder)
 
 def test_the_mesh_level_walk_runs_as_mesh_programs(holder, want):
     """Under the mesh route's pinned budget every GroupBy of a deck but
-    Q4.1's walks the levels, each launch a mesh program, and none of the
-    deck's reads leaves the mesh route."""
+    Q4.1's takes the level walk's first read, each launch a mesh program,
+    and none of the deck's reads leaves the mesh route. After it the
+    pairs the filter holds fit two chunks but for Q3.2's and Q4.3's,
+    which walk on level by level."""
     client = StatsClient()
     api = _api(holder, "mesh_levels", stats=client)
     for name in TEMPLATES:
         text = next(t for n, t in TEXTS if n == name)
         assert api.query("ssb", text)["results"][0] == want[text]
-    assert _family(client, "groupby_queries_total", path="levels") == len(TEMPLATES) - 4
+    assert _family(client, "groupby_queries_total", path="levels") == 2
+    assert _family(client, "groupby_queries_total", path="pruned") == len(TEMPLATES) - 6
     assert _family(client, "groupby_queries_total", path="fused") == 1
     assert _family(client, "queries_routed", path="mesh") == len(TEMPLATES)
     assert _family(client, "mesh_fallbacks_total") == 0
@@ -403,11 +415,12 @@ def test_the_mesh_level_walk_runs_as_mesh_programs(holder, want):
 def test_the_mesh_ledger_reserves_a_devices_share_of_a_plane(holder, want):
     """Q2.1 (3 years by 12 brands) under ``pinned_budget()``: on the
     device route a plane is the whole plane, a level holds 16 masks and
-    the 36 pairs take three chunks, so the GroupBy walks the levels; on
-    the mesh route a plane is a device's share of it, the 64 padded pairs
-    fit one chunk and the same GroupBy is fused. The answer is the same,
-    and the mesh ledger's mark is the filter's plane, the levels' masks
-    and the temporaries in a device's share of a plane."""
+    the 36 pairs take three chunks, so the GroupBy reads the filter's
+    rows first, and the 3 x 3 pairs it holds then fit one chunk (pruned);
+    on the mesh route a plane is a device's share of it, the 64 padded
+    pairs fit one chunk and the same GroupBy is fused. The answer is the
+    same, and the mesh ledger's mark is the filter's plane, the levels'
+    masks and the temporaries in a device's share of a plane."""
     text = next(t for n, t in TEXTS if n == "q2_1")
     devices = len(jax.devices())
     paths, marks = {}, {}
@@ -416,9 +429,11 @@ def test_the_mesh_ledger_reserves_a_devices_share_of_a_plane(holder, want):
         api = _api(holder, route, stats=client)
         api.executor.GROUPBY_MASK_BUDGET = pinned_budget()
         assert api.query("ssb", text)["results"][0] == want[text]
-        paths[route] = {p: _family(client, "groupby_queries_total", path=p) for p in ("fused", "levels")}
+        paths[route] = {p: _family(client, "groupby_queries_total", path=p)
+                        for p in ("fused", "levels", "pruned")}
         marks[route] = api.executor.gb_ledger.high_water
-    assert paths == {"levels": {"fused": 0, "levels": 1}, "mesh": {"fused": 1, "levels": 0}}
+    assert paths == {"levels": {"fused": 0, "levels": 0, "pruned": 1},
+                     "mesh": {"fused": 1, "levels": 0, "pruned": 0}}
     assert marks["mesh"] == (1 + 4 + 64 + ops.groupby.TEMP_PLANES) * (PLANE // devices)
     assert marks["levels"] <= pinned_budget() < marks["mesh"] * devices
 

@@ -301,16 +301,19 @@ def test_groupby_programs_at_the_groupby_cells_shapes(rig, one_chip, groups):
 @pytest.mark.parametrize(
     "groups,stack,rows",
     [(32, 1024, 1024), (8, 1024, 1024), (16, 256, 256), (1, 256, 256), (128, 8, 8), (8, 32, 32),
-     (8, 1024, 64), (32, 1024, 64), (1, 1024, 1024)],
+     (8, 1024, 64), (32, 1024, 64), (1, 1024, 1024), (32, 8, 8), (16, 32, 16)],
     ids=["q4_3", "q2_x", "q3_2", "q3_root", "q3_2_years", "q3_1", "q2_x_held", "q4_3_held",
-         "brands_root"],
+         "brands_root", "q3_1_pruned", "q4_2_pruned"],
 )
 def test_count_pass_at_the_ssb_cells_shapes(one_chip, groups, stack, rows):
     """The cell ssb-24.flights: 24 shards, the level walk's counts launches
     (Q4.3's 32 (year, city) masks against the 1,024 brands, Q2.x's 8 years
     against them, Q3.2's 16 cities against 256, 128 (city, city) masks
     against the 8 years; the 64 brands the filter holds of Q2.x and Q4.3;
-    the first read's filter against the 1,024 brands). Temporaries inside ``TEMP_PLANES`` whatever the
+    the first read's filter against the 1,024 brands) and the deferred
+    walk's on the rows the first read found the filter to hold (Q3.1's 25
+    (nation, nation) masks against 6 years, Q4.2's 10 (year, nation)
+    masks against 10 categories). Temporaries inside ``TEMP_PLANES`` whatever the
     level's rows and masks: whole, the 1,024 rows' block of shards was
     683 planes (2 GiB) that the transient ledger did not count."""
     shards = 24
@@ -355,11 +358,13 @@ def test_taxi_128g_shapes_keep_their_tiles():
         assert not ops.groupby.whole_stack(masks, sds((stack, shards, W)), sds((k,), np.int32))
 
 
-@pytest.mark.parametrize("groups,planes", [(128, 26), (256, 26), (1, 27)], ids=["q3_q4", "q2_x", "one"])
+@pytest.mark.parametrize("groups,planes", [(128, 26), (256, 26), (1, 27), (128, 27)],
+                         ids=["q3_q4", "q2_x", "one", "q4_2_pruned"])
 def test_grouped_sum_at_the_ssb_cells_shapes(one_chip, groups, planes):
     """The cell ssb-24.flights' grouped sums: a level walk's chunk of 128
     (Q3.x, Q4.x) or 256 (Q2.x) group masks against lo_revenue's 26
-    planes at 24 shards, and one mask against lo_profit's 27. The count
+    planes at 24 shards, one mask against lo_profit's 27, and the
+    deferred walk's one chunk of Q4.2's 100 pruned groups against it. The count
     pass with the planes for rows, its temporaries inside ``TEMP_PLANES``
     whatever the groups."""
     shards = 24
@@ -596,9 +601,10 @@ SSB_CHIP_STACKS = 2008 * (S_SSB // 4) * W * 4
 @pytest.mark.parametrize(
     "program,groups,rows",
     [("counts", 32, 1024), ("counts", 8, 1024), ("counts", 16, 256), ("counts", 1, 1024),
-     ("counts", 1, 256), ("masks", 32, 1024), ("sums", 128, 26), ("sums", 256, 26), ("sums", 1, 27)],
+     ("counts", 1, 256), ("masks", 32, 1024), ("sums", 128, 26), ("sums", 256, 26), ("sums", 1, 27),
+     ("counts", 32, 8), ("counts", 16, 32)],
     ids=["q4_3", "q2_x", "q3_2", "brands_root", "cities_root", "q4_3_masks", "q3_q4_sums",
-         "q2_x_sums", "one_sum"],
+         "q2_x_sums", "one_sum", "q3_1_pruned", "q4_2_pruned"],
 )
 def test_mesh_groupby_programs_at_the_ssb_four_chip_cells_shapes(mesh, program, groups, rows):
     """The level walk's and the deferred walk's mesh programs at
@@ -606,7 +612,9 @@ def test_mesh_groupby_programs_at_the_ssb_four_chip_cells_shapes(mesh, program, 
     of 32 (year, city) masks against the 1,024 brands, of 8 years against
     them, of 16 cities against 256, the first read's one mask against the
     1,024 brands or the 256 cities (one pass over the whole stack, as on
-    one chip); a chunk of 128 pair masks from 32 parents and the brands;
+    one chip), the deferred walk's on the rows the first read found the
+    filter to hold (Q3.1's 32 padded masks against the years, Q4.2's 16
+    against the categories); a chunk of 128 pair masks from 32 parents and the brands;
     the grouped sums of 128 or 256 masks against lo_revenue's 26 planes
     and of one against lo_profit's 27. Temporaries inside ``TEMP_PLANES``
     of a chip's plane and the rows of one tile of the count pass
